@@ -6,9 +6,8 @@ Two datapaths at the paper's lg-2400 scale (B=1024, F=16, T=200, m=2400):
 * float: every bit is a float32 — thermometer -> one-hot-matmul LUT eval
   -> popcount, staged through HBM, plus the float fused kernel;
 * packed: every bit lives in uint32 words (32/word) — packed encode ->
-  shift/AND LUT eval -> SWAR popcount, plus the fused packed kernel that
-  keeps the words VMEM-resident end-to-end, in both its ``packed``
-  (full bit tensor) and ``batch-major`` (direct-wire) variants.
+  shift/AND LUT eval -> SWAR popcount — plus the served fused kernel
+  (``fused_dwn_batch_major``), which never writes a bit tensor out.
 
 Timings (warmed, so compile time is excluded) and the packed-vs-float
 speedups are written to ``BENCH_kernels.json`` at the repo root (one
@@ -57,7 +56,7 @@ def smoke_bm():
             x, th, [mapping], [tables], C)
         counts, idx = f_ops.forward_packed(
             x, th, mapping, tables, C, interpret=True,
-            config=FusedConfig(variant="batch-major", block_b=64))
+            config=FusedConfig(block_b=64))
         np.testing.assert_array_equal(np.asarray(counts),
                                       np.asarray(ref_counts))
         np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))
@@ -69,7 +68,6 @@ def run():
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from repro.kernels.autotune import FusedConfig
     from repro.kernels.thermometer import ops as th_ops
     from repro.kernels.lut_eval import ops as lut_ops
     from repro.kernels.popcount import ops as pc_ops
@@ -109,14 +107,6 @@ def run():
         x, th, mapping, tables_i, C, interpret=True)[0])
     np.testing.assert_array_equal(np.asarray(fused_p), np.asarray(counts))
 
-    # batch-major variant at the same scale (rows-per-step = 256, the
-    # default the autotuner sweeps around)
-    fwd_bm = f_ops.make_forward_packed(
-        th, mapping, tables_i, C, interpret=True,
-        config=FusedConfig(variant="batch-major", block_b=256))
-    t_fused_bm, fused_bm = _timed(lambda: fwd_bm(x)[0])
-    np.testing.assert_array_equal(np.asarray(fused_bm), np.asarray(counts))
-
     # ---- HBM traffic model ----------------------------------------------
     # float staged writes + re-reads the unary blow-up at 4 B/bit; packed
     # moves the identical bits at 1/32 B/bit; fused keeps them in VMEM.
@@ -144,9 +134,6 @@ def run():
     csv_row("kernels/fused_packed", t_fused_p,
             f"vs_float_staged={staged_total_f / t_fused_p:.1f}x;"
             f"vs_float_fused={t_fused_f / t_fused_p:.1f}x")
-    csv_row("kernels/fused_batch_major", t_fused_bm,
-            f"vs_packed={t_fused_p / t_fused_bm:.1f}x;"
-            f"vs_float_fused={t_fused_f / t_fused_bm:.1f}x")
 
     record = {
         "scale": {"B": B, "F": F, "T": T, "m": m, "classes": C},
@@ -157,13 +144,11 @@ def run():
         "packed_us": {"encode": round(t_enc_p, 1),
                       "lut_eval": round(t_lut_p, 1),
                       "popcount": round(t_pop_p, 1),
-                      "fused": round(t_fused_p, 1),
-                      "fused_batch_major": round(t_fused_bm, 1)},
+                      "fused": round(t_fused_p, 1)},
         "speedup": {
             "fused_packed_vs_float_staged":
                 round(staged_total_f / t_fused_p, 2),
             "fused_packed_vs_float_fused": round(t_fused_f / t_fused_p, 2),
-            "fused_batch_major_vs_packed": round(t_fused_p / t_fused_bm, 2),
             "encode_packed_vs_float": round(t_enc / t_enc_p, 2),
         },
         "hbm_model_bytes": {"float_staged": staged_f,
@@ -175,8 +160,7 @@ def run():
     print(f"\npacked fused vs float staged pipeline: "
           f"{staged_total_f / t_fused_p:.1f}x wall-clock "
           f"({staged_total_f / 1e3:.1f} ms -> {t_fused_p / 1e3:.2f} ms per "
-          f"{B}-sample batch); batch-major fused {t_fused_bm / 1e3:.2f} ms; "
-          f"bit widths: {bits_f32 / 1e6:.1f} MB float "
+          f"{B}-sample batch); bit widths: {bits_f32 / 1e6:.1f} MB float "
           f"-> {bits_pack / 1e6:.2f} MB packed; written {BENCH_JSON.name}")
 
 

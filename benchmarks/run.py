@@ -15,6 +15,8 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (table1_hardware, table2_literature, table3_quantization,
                    cosim_smoke, fig2_encoding, fig5_breakdown, fig6_pareto,
                    roofline_report, kernels_bench, load_harness, serve_bench,

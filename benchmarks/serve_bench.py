@@ -13,8 +13,8 @@ closed-loop rows here never shed, so their ``shed_rate`` is 0 by
 construction).
 
 The engine starts with ``backend="auto"`` and autotuning on, so the
-fused-packed rows run the *tuned* kernel config for each bucket (variant
-+ rows-per-step from the persistent autotune cache, docs/autotune.md);
+fused-packed rows run the *tuned* kernel config for each bucket (rows
+and LUTs per step from the persistent autotune cache, docs/autotune.md);
 the chosen config is recorded per cell.
 
 Per backend the engine first serves one warmup request so the

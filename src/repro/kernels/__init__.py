@@ -9,7 +9,7 @@ Every stage also has a *packed* variant operating on uint32 bitplanes
 ``encode_packed`` emits packed words straight from the compare,
 ``evaluate_packed`` forms LUT addresses with shift/AND on the words,
 ``classify_packed`` popcounts masked words (SWAR), and
-``fused.ops.forward_packed`` runs the whole model in one pallas_call.
-``autotune`` picks the fused kernel variant + block shapes per
-(model, batch bucket, device) and persists winners (docs/autotune.md)."""
+``fused.ops.forward_packed`` runs the whole model in one pallas_call
+(the served kernel).  ``autotune`` picks its block shapes per (model,
+batch bucket, device) and persists winners (docs/autotune.md)."""
 from . import thermometer, lut_eval, popcount, fused, flash_attn, autotune

@@ -1,10 +1,8 @@
 """Persistent per-(arch, bucket, device) autotuner for the fused kernels.
 
-The fused DWN datapath has real shape knobs — which kernel variant
-(``packed`` full-bit-tensor vs ``batch-major`` direct-wire) and how many
-sample rows one grid step processes — and the winner is *size dependent*:
-``BENCH_serve.json`` history shows the packed layout winning at lg-2400
-while small presets drown in per-bit overhead.  Instead of hardcoding,
+The fused DWN kernel has a real shape knob — how many sample rows one
+grid step processes — and the best choice depends on the model's size
+and the batch bucket.  Instead of hardcoding,
 this module times the candidate configs on probe rows and persists the
 winner in a JSON cache, keyed exactly like the sweep result cache
 (``repro.sweep.cache``): a content fingerprint of the thing being tuned
@@ -15,7 +13,9 @@ invalidates stale configs instead of silently serving them.
 Cache location: ``$REPRO_AUTOTUNE_CACHE`` if set, else
 ``~/.cache/repro/autotune/fused_configs.json`` (next to where the sweep
 compile cache lives by convention).  A corrupt or absent cache file is a
-miss, never an error — consumers fall back to the default blocks.
+miss, never an error.  A candidate that fails to compile or run is an
+error: the tuner raises rather than serve something else under the
+kernel's name.
 
 The timing loop is deliberately tiny and injectable (``timer=``) so the
 tuner is deterministic under a stubbed clock in tests.
@@ -34,45 +34,36 @@ import jax
 import jax.numpy as jnp
 
 
-#: kernel variants the tuner may select (see ``fused/ops.py``).
-VARIANTS = ("packed", "batch-major")
-
-
 @dataclasses.dataclass(frozen=True)
 class FusedConfig:
     """One point in the fused-kernel tuning space.
 
     Attributes:
-      variant: "packed" (full bit tensor in uint32 words) or
-        "batch-major" (direct-wire first layer, grid over sample tiles).
-      block_b: sample rows processed per grid step.
-      block_m: m-tile width — used only by the *float* fused kernel
-        (``ops.forward``); the packed variants keep the whole model
-        state resident per step.
+      block_b: sample rows ``kernel.fused_dwn_batch_major`` processes per
+        grid step.  Its first-layer LUT tile is fixed
+        (``fused.ops.BLOCK_M``).
     """
 
-    variant: str = "packed"
     block_b: int = 256
-    block_m: int = 128
-
-    def __post_init__(self):
-        assert self.variant in VARIANTS, self.variant
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 
     @classmethod
     def from_dict(cls, d: dict) -> "FusedConfig":
-        return cls(**{k: d[k] for k in ("variant", "block_b", "block_m")
-                      if k in d})
+        return cls(block_b=d["block_b"])
 
     @property
     def label(self) -> str:
-        return f"{self.variant}/b{self.block_b}"
+        return f"b{self.block_b}"
 
 
-#: what an untuned model serves with — the historical hardcoded blocks.
+#: what an untuned model serves with.
 DEFAULT_CONFIG = FusedConfig()
+
+#: most sample rows one grid step may hold: a (rows, LUT tile) block has
+#: several live int32/f32 temporaries in VMEM.
+MAX_BLOCK_B = 512
 
 
 # ---------------------------------------------------------------------------
@@ -106,10 +97,10 @@ def kernel_fingerprint() -> str:
 
 
 def device_kind() -> str:
-    """Platform string the timings are valid on (tunings don't transfer
-    between a real TPU and the CPU interpret-mode emulation)."""
-    platform = jax.devices()[0].platform
-    return platform if platform == "tpu" else f"{platform}-interpret"
+    """The device the timings are valid on, as JAX names it (e.g.
+    ``TPU v5 lite``): tunings transfer neither between chip generations
+    nor to the CPU's interpret-mode emulation."""
+    return jax.devices()[0].device_kind
 
 
 def cache_key(spec_fingerprint: str, bucket: int,
@@ -136,12 +127,12 @@ class AutotuneCache:
 
         {"entries": {"<spec_fp>:<bucket>:<device>": {
             "code": "<kernel fingerprint at tune time>",
-            "config": {"variant": ..., "block_b": ..., "block_m": ...},
-            "timings_us": {"packed/b64": 812.3, ...}}}}
+            "config": {"block_b": ...},
+            "timings_us": {"b256": 412.3, "b128": 455.0}}}}
 
-    ``get`` misses (returns None) when the file is absent/corrupt or the
-    stored ``code`` no longer matches :func:`kernel_fingerprint` — the
-    caller re-tunes or falls back to :data:`DEFAULT_CONFIG`.
+    ``get`` misses (returns None) when the file is absent/corrupt, the
+    stored ``code`` no longer matches :func:`kernel_fingerprint`, or the
+    stored config does not parse — the caller then re-tunes.
     """
 
     def __init__(self, path: str | Path | None = None):
@@ -165,7 +156,7 @@ class AutotuneCache:
             return None
         try:
             return FusedConfig.from_dict(entry["config"])
-        except (KeyError, TypeError, AssertionError):
+        except (KeyError, TypeError):
             return None
 
     def put(self, spec_fingerprint: str, bucket: int, config: FusedConfig,
@@ -216,18 +207,16 @@ def time_step(fn, x, *, iters: int = 3, timer=time.perf_counter,
 
 
 def candidate_configs(bucket: int) -> list[FusedConfig]:
-    """The (variant, rows-per-step) sweep for one batch bucket.
+    """The rows-per-step sweep for one batch bucket.
 
-    Both variants at the full bucket (one grid step per call) and, when
-    the bucket is large enough to split, at half — kept deliberately
-    small so startup tuning stays cheap; the cache amortizes it to zero
-    on later runs.
+    Rows at the bucket and at half of it, both capped at
+    :data:`MAX_BLOCK_B` (the VMEM bound that ``tests/test_tpu_compile.py``
+    checks) — kept deliberately small so startup tuning stays cheap; the
+    cache amortizes it to zero on later runs.
     """
-    rows = [bucket]
-    if bucket >= 16:
-        rows.append(bucket // 2)
-    return [FusedConfig(variant=v, block_b=r)
-            for v in VARIANTS for r in rows]
+    rows = sorted({min(bucket, MAX_BLOCK_B),
+                   min(max(bucket // 2, 8), MAX_BLOCK_B)}, reverse=True)
+    return [FusedConfig(block_b=r) for r in rows]
 
 
 def tune_fused(thresholds, mappings, tables, num_classes: int, x_probe, *,
@@ -256,8 +245,8 @@ def tune_fused(thresholds, mappings, tables, num_classes: int, x_probe, *,
       force: re-tune even on a cache hit.
 
     Returns the winning config (cached or freshly timed).  A candidate
-    that fails to build/run is skipped, so a bad variant can never brick
-    startup; if every candidate fails, :data:`DEFAULT_CONFIG` wins.
+    that fails to build or run raises: a kernel the device refuses is a
+    fault to report, not a candidate to drop.
     """
     from .fused import ops as fused_ops
     from ..core.thermometer import quantize_fixed_point
@@ -276,25 +265,20 @@ def tune_fused(thresholds, mappings, tables, num_classes: int, x_probe, *,
     timings: dict[str, float] = {}
     best_cfg, best_t = None, float("inf")
     for cfg in cands:
-        try:
-            fwd = fused_ops.make_forward_packed(
-                thresholds, mappings, tables, num_classes,
-                interpret=interpret, config=cfg)
-            t = time_step(fwd, x, iters=iters, timer=timer,
-                          min_time_s=min_time_s)
-        except Exception:                      # noqa: BLE001 — skip, don't brick
-            continue
+        fwd = fused_ops.make_forward_packed(
+            thresholds, mappings, tables, num_classes,
+            interpret=interpret, config=cfg)
+        t = time_step(fwd, x, iters=iters, timer=timer,
+                      min_time_s=min_time_s)
         timings[cfg.label] = t * 1e6
         if t < best_t:
             best_cfg, best_t = cfg, t
-    if best_cfg is None:
-        return DEFAULT_CONFIG
     cache.put(spec_fingerprint, bucket, best_cfg, timings)
     return best_cfg
 
 
 __all__ = [
-    "AutotuneCache", "DEFAULT_CONFIG", "FusedConfig", "VARIANTS",
+    "AutotuneCache", "DEFAULT_CONFIG", "FusedConfig",
     "cache_key", "candidate_configs", "default_cache_path", "device_kind",
     "kernel_fingerprint", "time_step", "tune_fused",
 ]

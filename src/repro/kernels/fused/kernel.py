@@ -1,38 +1,23 @@
-"""Pallas TPU kernel: fused DWN accelerator (beyond-paper optimization).
+"""Pallas TPU kernels: fused DWN accelerator (beyond-paper optimization).
 
 The paper's central finding is that thermometer *encoding* dominates
 small-model hardware cost.  On TPU the same phenomenon appears as a
 memory-bound unary blow-up: encoding inflates a (B, 16) feature tile into
 a (B, 3200) bit tensor (x200 bytes) that a staged implementation writes
-to and re-reads from HBM.  These kernels keep the bits in VMEM for their
-entire life; three variants trade off how the bits are materialized:
+to and re-reads from HBM.  These kernels never write the bit tensor out:
 
 ``fused_dwn``
     float datapath: encode -> selection matmul (MXU) -> corner-product
     table eval (VPU) -> per-class popcount.  Grid (B/bb, m/bm); the m
     axis is the innermost (sequential) loop accumulating partial class
     counts, and the first-argmax prediction is emitted in-kernel on the
-    last m step.
-
-``fused_dwn_packed``
-    packed datapath: the encode compare packs straight to uint32 words
-    in VMEM, every LUT layer is gather + shift/AND addressing, and the
-    classifier is a masked SWAR popcount.  Grid over sample tiles only.
+    last m step.  Not served; kept as a second datapath for tests.
 
 ``fused_dwn_batch_major``
-    batch-major direct-wire datapath: the first LUT layer reads only
-    m*n of the F*T thermometer bits, so for small models materializing
-    (let alone packing) the full bit tensor is pure overhead.  This
-    variant gathers the *features and thresholds of the wired bits* and
-    compares exactly those — one grid step processes a whole
-    (rows x bucket) sample tile with the entire model state VMEM-
-    resident.  Single-layer models (all JSC presets) never touch a
-    packed word at all; deeper stacks pack the first layer's outputs
-    and continue on the packed datapath.
-
-Every wrapper pads the batch internally to a block multiple and masks /
-slices the tail, so any batch size works without caller-side bucket
-rounding (the old ``B % bb == 0`` hard asserts are gone).
+    the served datapath, laid out for the TPU's vector units: samples on
+    sublanes, LUTs on lanes.  Every step is a matmul, a compare, a
+    shift or a select; nothing gathers along lanes, which Mosaic cannot
+    lower.  See the function docstring for the encoding it relies on.
 """
 
 from __future__ import annotations
@@ -42,21 +27,13 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from ...core.bitpack import (WORD_BITS, select_packed_bits, lut_addresses,
-                             masked_group_counts)
-from ..thermometer.kernel import _pack_words
 from ..popcount.kernel import _first_argmax
 
 
 def _round_up(x: int, m: int) -> int:
     return (x + m - 1) // m * m
-
-
-def _row_mask(i, rows: int, total_b: int):
-    """(rows, 1) bool: which rows of grid step ``i`` are real samples."""
-    r = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
-    return (i * rows + r) < total_b
 
 
 def _fused_kernel(x_ref, th_ref, sel_ref, tab_ref, cls_ref, counts_ref,
@@ -145,200 +122,136 @@ def fused_dwn(x: jax.Array, thresholds: jax.Array, sel_onehot: jax.Array,
     return counts[:B], idx[:B, 0]
 
 
-def _fused_packed_kernel(x_ref, th_ref, *refs, num_layers: int,
-                         total_b: int):
-    # refs: per layer (widx, boff, tab), then class masks, then the two
-    # output refs appended by pallas_call (counts, idx).
-    #
-    # The whole accelerator on packed words: the encode compare produces
-    # the (B_blk, F, T) bool tile in VMEM, is immediately packed to
-    # (B_blk, F*T/32) uint32 — the only bit representation that persists —
-    # then every LUT layer is gather + shift/AND addressing + table read +
-    # repack, and the classifier is a masked SWAR popcount.  Bits never
-    # touch HBM in any dtype; only the (B, classes) counts leave.
+def _lut_layer(inp, sel_ref, rank_ref, words_ref):
+    """One LUT layer on a (rows, K) bf16 tile -> (rows, m_tile) int32 bits.
+
+    Wire ``i`` of every LUT is read with one MXU matmul against a one-hot
+    selection (``sel_ref[i]``, (K, m_tile)), so the gather happens on the
+    MXU instead of along lanes; the wire is set where the gathered value
+    exceeds ``rank_ref[i]``.  The 2^n-entry truth table is packed into
+    32-bit words (``words_ref``, (W, m_tile)): one select picks the word,
+    one shift picks the bit.
+    """
+    rank = rank_ref[...]
+    addr = None
+    for i in range(sel_ref.shape[0]):
+        g = jnp.dot(inp, sel_ref[i], preferred_element_type=jnp.float32)
+        bit = (g > rank[i:i + 1, :]).astype(jnp.int32)
+        addr = bit if addr is None else addr | (bit << i)
+    words = words_ref[...]
+    w = jnp.broadcast_to(words[0:1, :], addr.shape)
+    for k in range(1, words.shape[0]):
+        w = jnp.where((addr >> 5) == k, words[k:k + 1, :], w)
+    return (w >> (addr & 31)) & 1
+
+
+def _to_bf16(v):
+    return v.astype(jnp.float32).astype(jnp.bfloat16)
+
+
+def _fused_bm_kernel(lvl_ref, *refs, num_layers: int):
+    # refs: per layer (sel, rank, words), then the class map, then the two
+    # output refs (counts, idx).  Grid (row tiles, layer-0 LUT tiles); the
+    # LUT axis is sequential and accumulates counts into the output block.
     cls_ref = refs[3 * num_layers]
-    counts_ref = refs[3 * num_layers + 1]
-    idx_ref = refs[3 * num_layers + 2]
-    x = x_ref[...]                                   # (B_blk, F)
-    th = th_ref[...]                                 # (F, T)
-    B_blk = x.shape[0]
-    bits = (x[:, :, None] > th[None])                # bool, VMEM-resident
-    words = _pack_words(bits.reshape(B_blk, -1), B_blk)
-    for l in range(num_layers):
-        widx = refs[3 * l][...]                      # (m_l, n_l) i32
-        boff = refs[3 * l + 1][...]
-        tab = refs[3 * l + 2][...]                   # (m_l, 2^n_l) i32
-        sel = select_packed_bits(words, widx, boff)
-        addr = lut_addresses(sel)
-        out_bits = jnp.take_along_axis(
-            jnp.broadcast_to(tab[None], (B_blk,) + tab.shape),
-            addr[..., None], axis=-1)[..., 0]
-        words = _pack_words(out_bits, B_blk)
-    mask = cls_ref[...]                              # (classes, W)
-    counts = masked_group_counts(words, mask)
-    # masked popcount tail: internally-padded rows emit zero counts
-    # (and idx 0) instead of whatever the zero-padded features encode to
-    counts = jnp.where(_row_mask(pl.program_id(0), B_blk, total_b),
-                       counts, 0.0)
-    counts_ref[...] = counts
-    idx_ref[...] = _first_argmax(counts)[:, None]
+    counts_ref, idx_ref = refs[3 * num_layers + 1:]
+    j = pl.program_id(1)
+    bits = _lut_layer(_to_bf16(lvl_ref[...]), *refs[:3])
+    for l in range(1, num_layers):
+        bits = _lut_layer(_to_bf16(bits), *refs[3 * l:3 * l + 3])
+    partial = jnp.dot(_to_bf16(bits), cls_ref[...],
+                      preferred_element_type=jnp.float32)
+
+    @pl.when(j == 0)
+    def _init():
+        counts_ref[...] = partial
+
+    @pl.when(j > 0)
+    def _acc():
+        counts_ref[...] += partial
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _emit_idx():
+        idx_ref[...] = _first_argmax(counts_ref[...])[:, None]
 
 
-@functools.partial(jax.jit, static_argnames=("num_layers", "block_b",
-                                             "interpret"))
-def fused_dwn_packed(x: jax.Array, thresholds: jax.Array,
-                     layer_arrays: tuple, class_masks: jax.Array, *,
-                     num_layers: int, block_b: int = 256,
-                     interpret: bool = False):
-    """Whole-model packed inference in ONE pallas_call.
+@functools.partial(jax.jit, static_argnames=("num_classes", "block_b",
+                                             "block_m", "interpret"))
+def fused_dwn_batch_major(x: jax.Array, thresholds: jax.Array,
+                          layer_arrays: tuple, class_map: jax.Array, *,
+                          num_classes: int, block_b: int = 256,
+                          block_m: int = 512, interpret: bool = False):
+    """Whole-model DWN inference in one ``pallas_call``.
 
-    x (B, F); thresholds (F, T) with F*T a 32-multiple; layer_arrays a
-    flat tuple (widx_0, boff_0, tab_0, widx_1, ...) with every m_l a
-    32-multiple; class_masks (classes, W_last) uint32.
-    Returns (counts (B, classes) f32, idx (B,) i32).  Any B works: the
-    batch pads internally to a ``block_b`` multiple, padded rows popcount
-    to zero under the row mask, and the tail is sliced off.
+    Thermometer encoding as ranks: bit ``t`` of feature ``f`` is
+    ``x_f > th[f, t]``.  With ``q_f`` the number of thresholds of feature
+    ``f`` below ``x_f`` and ``r`` the position of ``th[f, t]`` in the
+    sorted thresholds of ``f``, that bit equals ``q_f > r`` — ties and
+    unsorted banks included.  So the wrapper reduces each feature to its
+    level ``q`` (one compare-and-sum, fused by XLA, no bit tensor in
+    HBM), and the kernel reads each first-layer wire as level-of-its-
+    feature > rank-of-its-threshold.  Levels are split into base-256
+    digits so the one-hot gather on the MXU is exact in bf16.
+
+    Args:
+      x: (B, F) features (already PEN-quantized where the model is PEN).
+      thresholds: (F, T) threshold bank.
+      layer_arrays: per layer ``(sel (n, K, m_p) bf16, rank (n, m_p) f32,
+        words (W, m_p) i32)`` from ``ops._batch_major_operands``; layer 0
+        has ``K = digits * F``, later layers ``K = m_p`` of the layer below.
+      class_map: (m_last_p, C_p) bf16 one-hot of each LUT's class (zero
+        rows for pad LUTs, zero columns for pad classes).
+      num_classes: real class count C.
+      block_b: sample rows per grid step.
+      block_m: layer-0 LUTs per grid step; must divide layer 0's ``m_p``.
+        Deeper stacks need every layer-0 bit at once, so they pass
+        ``block_m == m_p``.
+
+    Returns (counts (B, C) f32, idx (B,) i32 first-argmax); any B works
+    (internal pad, tail sliced).
     """
     B, F = x.shape
-    T = thresholds.shape[1]
-    assert (F * T) % WORD_BITS == 0, (F, T)
-    assert len(layer_arrays) == 3 * num_layers
-    classes, W_last = class_masks.shape
-    bb = min(block_b, B)
+    num_layers = len(layer_arrays) // 3
+    _, K0, m0p = layer_arrays[0].shape
+    digits = K0 // F
+    q = jnp.sum(x[:, :, None] > thresholds[None], axis=-1, dtype=jnp.int32)
+    lvl = jnp.concatenate([(q >> (8 * d)) & 255 for d in range(digits)],
+                          axis=1).astype(jnp.float32)   # (B, digits*F)
+    bb = min(block_b, _round_up(B, 8))
     Bp = _round_up(B, bb)
-    xp = jnp.pad(x, ((0, Bp - B), (0, 0)))
-    kernel = functools.partial(_fused_packed_kernel, num_layers=num_layers,
-                               total_b=B)
-    in_specs = [
-        pl.BlockSpec((bb, F), lambda i: (i, 0)),
-        pl.BlockSpec((F, T), lambda i: (0, 0)),
-    ]
-    for arr in layer_arrays:
-        in_specs.append(pl.BlockSpec(
-            arr.shape, lambda i, nd=arr.ndim: (0,) * nd))
-    in_specs.append(pl.BlockSpec((classes, W_last), lambda i: (0, 0)))
+    lvl = jnp.pad(lvl, ((0, Bp - B), (0, 0)))
+    bm = block_m
+    assert m0p % bm == 0 and (num_layers == 1 or bm == m0p), (m0p, bm)
+    Cp = class_map.shape[1]
+    in_specs = [pl.BlockSpec((bb, K0), lambda i, j: (i, 0))]
+    for l, arr in enumerate(layer_arrays):
+        if l < 3:       # layer 0 tiles over the LUT axis
+            shape = arr.shape[:-1] + (bm,)
+            in_specs.append(pl.BlockSpec(
+                shape, lambda i, j, nd=arr.ndim: (0,) * (nd - 1) + (j,)))
+        else:
+            in_specs.append(pl.BlockSpec(
+                arr.shape, lambda i, j, nd=arr.ndim: (0,) * nd))
+    # single-layer: class rows tile with layer 0's LUTs; deeper stacks
+    # (one LUT step) read the whole last-layer map
+    in_specs.append(pl.BlockSpec(
+        (bm if num_layers == 1 else class_map.shape[0], Cp),
+        lambda i, j: (j, 0)))
+    kernel = functools.partial(_fused_bm_kernel, num_layers=num_layers)
     counts, idx = pl.pallas_call(
         kernel,
-        grid=(Bp // bb,),
+        grid=(Bp // bb, m0p // bm),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((bb, classes), lambda i: (i, 0)),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bb, Cp), lambda i, j: (i, 0)),
+            pl.BlockSpec((bb, 1), lambda i, j: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((Bp, classes), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, Cp), jnp.float32),
             jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(xp, thresholds, *layer_arrays, class_masks)
-    return counts[:B], idx[:B, 0]
-
-
-def _fused_bm_kernel(x_ref, wf_ref, wth_ref, tab0_ref, *refs,
-                     num_layers: int, num_classes: int, total_b: int):
-    # refs: per *extra* layer (widx, boff, tab), then class masks (only
-    # when num_layers > 1), then counts_ref, idx_ref.
-    k = 3 * (num_layers - 1)
-    counts_ref = refs[k + (1 if num_layers > 1 else 0)]
-    idx_ref = refs[k + (2 if num_layers > 1 else 1)]
-    x = x_ref[...]                                   # (rows, F)
-    rows = x.shape[0]
-    wf = wf_ref[...]                                 # (m0, n) i32 feature
-    wth = wth_ref[...]                               # (m0, n) f32 threshold
-    m0, n = wf.shape
-    # direct-wire encode: gather the wired feature per LUT input and
-    # compare against that wire's threshold — m0*n compares instead of
-    # F*T compares + a full pack + word addressing
-    xg = jnp.take(x, wf.reshape(-1), axis=-1)        # (rows, m0*n)
-    sel = (xg > wth.reshape(-1)[None]).astype(jnp.int32)
-    addr = lut_addresses(sel.reshape(rows, m0, n))   # (rows, m0)
-    tab0 = tab0_ref[...]                             # (m0, 2^n) i32
-    out_bits = jnp.take_along_axis(
-        jnp.broadcast_to(tab0[None], (rows,) + tab0.shape),
-        addr[..., None], axis=-1)[..., 0]            # (rows, m0) i32
-    if num_layers == 1:
-        # contiguous class groups (group_masks semantics): plain VPU
-        # group-sum, no packed word ever materialized
-        g = m0 // num_classes
-        counts = out_bits.reshape(rows, num_classes, g).sum(
-            axis=-1).astype(jnp.float32)
-    else:
-        words = _pack_words(out_bits, rows)
-        for l in range(num_layers - 1):
-            widx = refs[3 * l][...]
-            boff = refs[3 * l + 1][...]
-            tab = refs[3 * l + 2][...]
-            s = select_packed_bits(words, widx, boff)
-            a = lut_addresses(s)
-            ob = jnp.take_along_axis(
-                jnp.broadcast_to(tab[None], (rows,) + tab.shape),
-                a[..., None], axis=-1)[..., 0]
-            words = _pack_words(ob, rows)
-        counts = masked_group_counts(words, refs[k][...])
-    counts = jnp.where(_row_mask(pl.program_id(0), rows, total_b),
-                       counts, 0.0)
-    counts_ref[...] = counts
-    idx_ref[...] = _first_argmax(counts)[:, None]
-
-
-@functools.partial(jax.jit, static_argnames=("num_layers", "num_classes",
-                                             "block_b", "interpret"))
-def fused_dwn_batch_major(x: jax.Array, wire_f: jax.Array,
-                          wire_th: jax.Array, table0: jax.Array,
-                          layer_arrays: tuple, class_masks, *,
-                          num_layers: int, num_classes: int,
-                          block_b: int = 256, interpret: bool = False):
-    """Batch-major direct-wire fused inference in ONE pallas_call.
-
-    x (B, F); wire_f / wire_th (m0, n): the feature index and threshold
-    value of every first-layer LUT input wire (``ops.py`` derives them
-    from ``mappings[0]`` and the threshold bank); table0 (m0, 2^n) i32.
-    ``layer_arrays`` holds (widx, boff, tab) triples for layers 1.. and
-    ``class_masks`` the (classes, W_last) uint32 masks — both empty/None
-    for single-layer models, where no packed word is ever built and no
-    32-multiple constraint exists.  Grid is over sample tiles only: one
-    step runs ``block_b`` samples through the whole model.  Returns
-    (counts (B, classes) f32, idx (B,) i32); any B works (internal pad +
-    row-masked popcount).
-    """
-    B, F = x.shape
-    m0, n = wire_f.shape
-    assert len(layer_arrays) == 3 * (num_layers - 1)
-    if num_layers == 1:
-        assert m0 % num_classes == 0, (m0, num_classes)
-    bb = min(block_b, B)
-    Bp = _round_up(B, bb)
-    xp = jnp.pad(x, ((0, Bp - B), (0, 0)))
-    kernel = functools.partial(_fused_bm_kernel, num_layers=num_layers,
-                               num_classes=num_classes, total_b=B)
-    A = table0.shape[1]
-    in_specs = [
-        pl.BlockSpec((bb, F), lambda i: (i, 0)),
-        pl.BlockSpec((m0, n), lambda i: (0, 0)),
-        pl.BlockSpec((m0, n), lambda i: (0, 0)),
-        pl.BlockSpec((m0, A), lambda i: (0, 0)),
-    ]
-    operands = [xp, wire_f, wire_th, table0]
-    for arr in layer_arrays:
-        in_specs.append(pl.BlockSpec(
-            arr.shape, lambda i, nd=arr.ndim: (0,) * nd))
-        operands.append(arr)
-    if num_layers > 1:
-        classes, W_last = class_masks.shape
-        in_specs.append(pl.BlockSpec((classes, W_last), lambda i: (0, 0)))
-        operands.append(class_masks)
-    counts, idx = pl.pallas_call(
-        kernel,
-        grid=(Bp // bb,),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((bb, num_classes), lambda i: (i, 0)),
-            pl.BlockSpec((bb, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((Bp, num_classes), jnp.float32),
-            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return counts[:B], idx[:B, 0]
+    )(lvl, *layer_arrays, class_map)
+    return counts[:B, :num_classes], idx[:B, 0]

@@ -20,11 +20,15 @@ from ...core.bitpack import masked_group_counts
 
 
 def _first_argmax(counts):
-    """First index achieving the max (ties -> lower class index),
-    max-then-first-index idiom; shared by all classifier kernels."""
+    """First index achieving the max (ties -> lower class index); shared
+    by all classifier kernels.  (B, C) f32 -> (B,) i32.  The index is a
+    min over a float lane iota: Mosaic reduces only float32."""
     best = jnp.max(counts, axis=-1, keepdims=True)
-    is_best = counts >= best
-    return jnp.argmax(is_best.astype(jnp.int32), axis=-1).astype(jnp.int32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, counts.shape,
+                                    counts.ndim - 1).astype(jnp.float32)
+    first = jnp.min(jnp.where(counts >= best, lane, float(counts.shape[-1])),
+                    axis=-1)
+    return first.astype(jnp.int32)
 
 
 def _popcount_kernel(bits_ref, counts_ref, idx_ref, *, num_classes: int):

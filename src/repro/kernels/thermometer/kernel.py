@@ -24,12 +24,15 @@ def _pack_words(bits, rows: int):
     """In-kernel bitpack: (rows, N) {0,1} -> (rows, N/32) uint32, LSB-first.
 
     N must be a 32-multiple (the op wrappers guarantee it); the whole pack
-    is a VPU multiply-reduce, no gathers.
+    is a VPU multiply-reduce, no gathers.  The sum runs in int32 (Mosaic
+    has no unsigned reductions): the distinct powers of two wrap to the
+    same 32 bits, which the bitcast reads back as uint32.
     """
-    w = bits.reshape(rows, -1, WORD_BITS).astype(jnp.uint32)
-    weights = jnp.left_shift(jnp.uint32(1),
-                             jnp.arange(WORD_BITS, dtype=jnp.uint32))
-    return jnp.sum(w * weights, axis=-1, dtype=jnp.uint32)
+    w = bits.reshape(rows, -1, WORD_BITS).astype(jnp.int32)
+    weights = jnp.left_shift(jnp.int32(1),
+                             jnp.arange(WORD_BITS, dtype=jnp.int32))
+    return jax.lax.bitcast_convert_type(
+        jnp.sum(w * weights, axis=-1, dtype=jnp.int32), jnp.uint32)
 
 
 def _thermometer_kernel(x_ref, th_ref, out_ref):

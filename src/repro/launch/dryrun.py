@@ -248,6 +248,8 @@ def main(argv=None):
                          "(logits_sharded,serve_tp_only)")
     ap.add_argument("--tag", default="")
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.list:
         for a in assigned_archs():

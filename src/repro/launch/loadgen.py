@@ -337,6 +337,8 @@ def main(argv=None):
     ap.add_argument("--backend", default="auto")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     presets = args.preset or ["dwn-jsc-sm"]
     engines = {p: ServingEngine(p, backend=args.backend,

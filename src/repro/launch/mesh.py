@@ -16,19 +16,28 @@ shards).  "model" carries TP/EP and stays inside the pod's dense ICI.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with Auto axes: the code places arrays with
+    in/out shardings and ``shard_map`` and lets the compiler propagate the
+    rest, which ``make_mesh``'s Explicit default refuses."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
     """Small mesh over whatever devices exist (tests / examples on CPU)."""
     n = len(jax.devices())
     mp = max(1, min(model_parallel, n))
-    return jax.make_mesh((n // mp, mp), ("data", "model"))
+    return make_mesh((n // mp, mp), ("data", "model"))
 
 
 def make_data_mesh():
@@ -39,7 +48,7 @@ def make_data_mesh():
     shards only the batch axis; ``ServingEngine`` lays batch buckets over
     this mesh with ``shard_map``.
     """
-    return jax.make_mesh((len(jax.devices()),), ("data",))
+    return make_mesh((len(jax.devices()),), ("data",))
 
 
 # TPU v5e hardware constants for the roofline (per chip).

@@ -208,6 +208,8 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--greedy", action="store_true", default=True)
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     if args.spec:
         if args.arch:

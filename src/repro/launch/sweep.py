@@ -157,6 +157,8 @@ def main(argv=None):
     ap.add_argument("--cosim-backend", default="auto",
                     choices=["auto", "python", "iverilog"])
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     settings = SweepSettings(
         n_train=args.n_train, n_test=args.n_test, seed=args.seed,

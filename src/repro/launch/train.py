@@ -200,6 +200,8 @@ def main(argv=None):
                          "<dir>/seed<N>")
     ap.add_argument("--quiet", action="store_true")
     args = ap.parse_args(argv)
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     cfg = get_arch(args.arch)
     if cfg.family == "dwn":

@@ -5,9 +5,9 @@ A *backend* is one implementation of the serving datapath
 share the same hardware semantics (paper §IV); they differ in how the
 bits move:
 
-    fused-packed   one Pallas ``pallas_call``: encode -> LUT layer(s) ->
-                   masked popcount with every bit packed uint32 and
-                   VMEM-resident (the serving fast path from PR 1)
+    fused-packed   one Pallas ``pallas_call``: thermometer levels ->
+                   LUT layer(s) -> class counts + argmax, no bit tensor
+                   in HBM (``kernels/fused/kernel.py``)
     packed-xla     the same packed uint32 word format, but expressed as
                    plain XLA ops via ``core.bitpack`` /
                    ``apply_hard_packed`` — no ``pallas_call``, so it runs
@@ -142,7 +142,7 @@ def available_backends() -> list[str]:
 class FusedPackedBackend(Backend):
     """Fused Pallas kernel, bits VMEM-resident end-to-end.
 
-    The kernel variant and rows-per-grid-step come from the model's
+    The kernel's rows and LUTs per grid step come from the model's
     ``tuned_configs`` (per batch bucket, filled by
     :func:`autotune_model`); buckets without a tuned entry serve on
     ``autotune.DEFAULT_CONFIG``'s blocks.  The config is resolved at
@@ -412,8 +412,8 @@ class AutoSelector:
 
     Calibration consults the model's *tuned* fused configs, not just the
     backend choice: ``autotune_model`` runs first, so the fused-packed
-    candidate being timed at each bucket is the autotuned variant/blocks
-    for that bucket, and ``configs`` records what was actually timed.
+    candidate being timed at each bucket runs the autotuned rows per grid
+    step for that bucket, and ``configs`` records what was actually timed.
 
     Near-ties break toward ``fused-packed``: at small buckets the real
     spread between datapaths is a few microseconds — below the jitter of

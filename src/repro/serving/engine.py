@@ -50,7 +50,6 @@ from typing import Any, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 
 from ..configs import get_arch
 from ..configs.base import ArchConfig
@@ -90,7 +89,7 @@ class ServingEngine:
         registered non-oracle backend against the float oracle.
       autotune: run the fused-kernel autotuner over the bucket ladder at
         startup (``backends.autotune_model``): each bucket serves the
-        fastest (variant, rows-per-step) fused config, cache-hit from
+        fastest (rows, LUTs)-per-step fused config, cache-hit from
         the persistent config cache (docs/autotune.md) or timed once on
         miss.  ``None`` (default) resolves to True exactly when
         ``backend == "auto"``; ``REPRO_AUTOTUNE=0`` force-disables.
@@ -211,7 +210,7 @@ class ServingEngine:
             # tune BEFORE anything compiles: BoundBackend jits one entry
             # per bucket and each trace binds the tuned config it sees.
             # The startup verification below then cross-checks the tuned
-            # variant, not the default one.
+            # config, not the default one.
             from .backends import autotune_model
             self.tuned_configs = autotune_model(
                 self.model, self.scheduler.buckets, probe,
@@ -254,9 +253,9 @@ class ServingEngine:
         spec_counts = self._part.spec(("dwn_batch", None),
                                       name="dwn.serve.counts")
         spec_pred = self._part.spec(("dwn_batch",), name="dwn.serve.pred")
-        return shard_map(fn, mesh=self.mesh, in_specs=(spec_x,),
-                         out_specs=(spec_counts, spec_pred),
-                         check_rep=False)
+        return jax.shard_map(fn, mesh=self.mesh, in_specs=(spec_x,),
+                             out_specs=(spec_counts, spec_pred),
+                             check_vma=False)
 
     def use_backend(self, name: str) -> None:
         """Switch the active DWN datapath (compile caches are kept).

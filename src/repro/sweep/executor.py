@@ -40,19 +40,24 @@ Chaos modes (``ExecutorSettings.chaos``) make all of this testable:
 
 Workers are spawned (never forked — JAX state does not survive a fork)
 and lazily build their own :class:`~repro.sweep.pipeline.SweepRunner`
-(data + model memo).  On hosts with multiple accelerator devices each
-worker is pinned round-robin via ``CUDA_VISIBLE_DEVICES`` before its
-first JAX operation; on CPU the processes are plain multiprocessing.
+(data + model memo).  The dispatcher never touches JAX: a process that
+initialises it holds every TPU chip of the host, and a worker could then
+not open its own.  On a TPU host the run starts at most one worker per
+chip, and with several chips pins each worker to its own chip through
+libtpu's environment before its first JAX operation; on CPU the
+processes are plain multiprocessing.
 See docs/sweep_resilience.md for the full architecture.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import glob
 import logging
 import multiprocessing as mp
 import os
 import queue as queue_mod
+import socket
 import time
 
 from .cache import SweepCache, point_key
@@ -137,18 +142,29 @@ def _default_workers(n_points: int) -> int:
     return max(1, min(n_points, os.cpu_count() or 1, 4))
 
 
-def _device_hints(n_workers: int) -> list:
-    """Round-robin device pins for accelerator hosts; None entries on
-    CPU (plain multiprocessing)."""
-    try:
-        import jax
-        ndev = jax.local_device_count()
-        platform = jax.default_backend()
-    except Exception:                                 # pragma: no cover
-        return [None] * n_workers
-    if ndev > 1 and platform in ("gpu", "cuda", "rocm"):
-        return [str(i % ndev) for i in range(n_workers)]
-    return [None] * n_workers
+def _tpu_chips() -> int:
+    """TPU chips on this host, counted from its device files (0 when
+    ``JAX_PLATFORMS`` excludes the TPU).  Never asks JAX: see the module
+    docstring."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms:
+        return 0
+    accel = glob.glob("/dev/accel[0-9]*")
+    vfio = [p for p in glob.glob("/dev/vfio/*")
+            if os.path.basename(p).isdigit()]
+    return len(accel) or len(vfio)
+
+
+def _chip_env(chip: int) -> dict:
+    """libtpu environment that gives a process chip ``chip`` alone."""
+    with socket.socket() as sock:
+        sock.bind(("localhost", 0))
+        port = sock.getsockname()[1]
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(port),
+            "TPU_PROCESS_ADDRESSES": f"localhost:{port}"}
 
 
 # ---------------------------------------------------------------------------
@@ -158,12 +174,12 @@ def _device_hints(n_workers: int) -> list:
 def _worker_main(worker_id: int, task_q, result_q, settings_dict: dict,
                  cache_dir: str | None, artifact_dir: str | None,
                  chaos_text: str | None, max_restarts: int,
-                 backoff_s: float, device_hint: str | None) -> None:
+                 backoff_s: float, env: dict) -> None:
     """One worker: pull (index, point, attempt) tasks, run each point
     under a supervised retry loop, commit to the cache (and artifact
-    store), report on the result queue.  Runs in a *spawned* process."""
-    if device_hint is not None:
-        os.environ.setdefault("CUDA_VISIBLE_DEVICES", device_hint)
+    store), report on the result queue.  Runs in a *spawned* process;
+    ``env`` (its chip pin, if any) is applied before JAX loads."""
+    os.environ.update(env)
     # workers never own the preemption signal: the dispatcher drains the
     # run; a TERM'd worker is treated as a node loss and re-dispatched
     from ..runtime.fault import FaultInjector, RestartPolicy, Supervisor
@@ -274,6 +290,11 @@ class _Dispatcher:
                          "worker_cache_hits": 0}
         self.draining = False
         self._next_wid = 0
+        #: TPU host: one worker per chip at most (0 = CPU, no limit)
+        self.chips = _tpu_chips()
+        self.free_chips = list(range(self.chips))
+        self.chip_of: dict[int, int] = {}
+        self.n_workers = 0
         from ..runtime.straggler import StragglerMonitor
         self.monitor = StragglerMonitor(
             window=ex.straggler_window, z_threshold=ex.straggler_z,
@@ -281,21 +302,38 @@ class _Dispatcher:
 
     # -- lifecycle ------------------------------------------------------
 
-    def spawn_worker(self, device_hint=None):
+    def spawn_worker(self):
+        """Start a worker; on a TPU host only onto a free chip (returns
+        None when every chip is taken — its task waits in the queue)."""
+        env = {}
+        if self.chips:
+            if not self.free_chips:
+                return None
+            chip = self.free_chips.pop(0)
+            if self.chips > 1:
+                env = _chip_env(chip)
         wid = self._next_wid
         self._next_wid += 1
+        if self.chips:
+            self.chip_of[wid] = chip
         p = self.ctx.Process(
             target=_worker_main,
             args=(wid, self.task_q, self.result_q,
                   dataclasses.asdict(self.settings),
                   str(self.cache.root) if self.cache.root else None,
                   self.ex.artifact_dir, self.ex.chaos,
-                  self.ex.max_restarts, self.ex.backoff_s, device_hint),
+                  self.ex.max_restarts, self.ex.backoff_s, env),
             daemon=True)
         p.start()
         self.procs[wid] = p
         self.counters["workers_spawned"] += 1
         return wid
+
+    def _retire(self, wid: int):
+        """Forget a worker that exited and free its chip."""
+        if wid in self.chip_of:
+            self.free_chips.append(self.chip_of.pop(wid))
+        return self.procs.pop(wid, None)
 
     def dispatch(self, index: int):
         self.attempts[index] += 1
@@ -332,6 +370,8 @@ class _Dispatcher:
                      f"{self.points[index].label}: "
                      f"{self.results[index].total_luts} LUTs "
                      f"({wall:.1f}s, worker {wid}"
+                     + (f", chip {self.chip_of[wid]}"
+                        if wid in self.chip_of else "")
                      + (f", attempt {attempt}" if attempt > 1 else "") + ")")
         elif kind == "failed":
             _, wid, index, attempt, error, retries = msg
@@ -344,7 +384,7 @@ class _Dispatcher:
         elif kind == "bye":
             _, wid = msg
             self.in_flight.pop(wid, None)
-            p = self.procs.pop(wid, None)
+            p = self._retire(wid)
             if p is not None:
                 p.join(timeout=5)
 
@@ -352,7 +392,7 @@ class _Dispatcher:
         """A dead worker's in-flight point re-dispatches (bounded); a
         replacement worker spawns while work remains."""
         for wid in [w for w, p in self.procs.items() if not p.is_alive()]:
-            self.procs.pop(wid).join(timeout=1)
+            self._retire(wid).join(timeout=1)
             self.counters["worker_deaths"] += 1
             task = self.in_flight.pop(wid, None)
             if task is not None:
@@ -406,8 +446,11 @@ class _Dispatcher:
     def run(self) -> None:
         n_workers = self.ex.workers or _default_workers(len(self.todo))
         n_workers = max(1, min(n_workers, len(self.todo)))
-        for hint in _device_hints(n_workers):
-            self.spawn_worker(device_hint=hint)
+        if self.chips:
+            n_workers = min(n_workers, self.chips)
+        self.n_workers = n_workers
+        for _ in range(n_workers):
+            self.spawn_worker()
         for i in self.todo:
             self.dispatch(i)
         last_progress = time.perf_counter()
@@ -455,7 +498,7 @@ class _Dispatcher:
         # must not gate the run's exit — kill it, its result is moot
         for wid, (index, _, _) in list(self.in_flight.items()):
             if index in self.results or index in self.failed:
-                p = self.procs.pop(wid, None)
+                p = self._retire(wid)
                 if p is not None:
                     p.terminate()
                     p.join(timeout=2)
@@ -544,7 +587,7 @@ def run_grid_parallel(grid, settings: SweepSettings | None = None, *,
         "worker_cache_hits": 0}
     executor_block = {
         "mode": "parallel",
-        "workers": (ex.workers or _default_workers(max(len(todo), 1))),
+        "workers": disp.n_workers if disp else 0,
         "cache_hits": len(hits),
         "failed": [points[i].label for i in sorted(disp.failed)]
         if disp else [],

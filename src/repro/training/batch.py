@@ -66,12 +66,11 @@ def _batch_program(cfg: DWNConfig, n: int, num_bits: int, batch: int,
         mesh = make_data_mesh()
         ndev = mesh.shape["data"]
         if ndev > 1 and n_models % ndev == 0:
-            from jax.experimental.shard_map import shard_map
-            fn = shard_map(
+            fn = jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(P("data"), P("data"), P("data"), P(), P("data")),
                 out_specs=(P("data"), P("data"), P("data")),
-                check_rep=False)
+                check_vma=False)
         else:
             mesh = None
     prog = jax.jit(fn, donate_argnums=(0, 1))

@@ -273,7 +273,12 @@ def test_slo_invariant_under_saturation(engine):
     never silently late."""
     from repro.serving.continuous import SLOConfig as SLO
     rng = np.random.default_rng(7)
-    payloads = [(int(rng.integers(1, 33)), 3.0) for _ in range(120)]
+    # one request with a deadline no step loop can miss, submitted first
+    # into the empty queue: the loop provably runs a step however slow
+    # the host is to schedule it, while the 3 ms ones behind it may all
+    # expire first on a loaded machine
+    payloads = [(8, 60_000.0)]
+    payloads += [(int(rng.integers(1, 33)), 3.0) for _ in range(120)]
     # oversize requests (4 max_bucket chunks) against a 0.5 ms deadline
     # provably cannot finish in time whatever the machine speed: they are
     # shed at admission, expired in queue, or at worst marked late —

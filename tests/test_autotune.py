@@ -10,9 +10,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.kernels import autotune
-from repro.kernels.autotune import (AutotuneCache, DEFAULT_CONFIG,
-                                    FusedConfig, candidate_configs,
-                                    tune_fused)
+from repro.kernels.autotune import (AutotuneCache, FusedConfig,
+                                    candidate_configs, tune_fused)
 from repro.kernels.fused import ops as f_ops
 from repro.kernels.fused.ref import fused_dwn_packed_ref
 
@@ -56,11 +55,10 @@ def model():
     return x, th, mapping, tables
 
 
-CANDS = [FusedConfig(variant="packed", block_b=8),
-         FusedConfig(variant="batch-major", block_b=8)]
+CANDS = [FusedConfig(block_b=16), FusedConfig(block_b=8)]
 
 # per candidate (iters=1): t0, timed run, t1 -> measured = delta at t0's
-# index; scripted so batch-major (5us) beats packed (50us)
+# index; scripted so 8 rows per step (5us) beat 16 (50us)
 DELTAS = [50e-6, 1e-6, 5e-6, 1e-6]
 
 
@@ -129,14 +127,15 @@ def test_cold_start_absent_and_corrupt_cache(tmp_path, model):
     assert json.loads(bad.read_text())["entries"]
 
 
-def test_all_candidates_failing_falls_back_to_default(tmp_path, model,
-                                                      monkeypatch):
+def test_failing_candidate_raises(tmp_path, model, monkeypatch):
+    """A candidate that fails to build raises: the tuner never hides a
+    refused kernel behind a default."""
     def boom(*a, **kw):
         raise RuntimeError("no kernel for you")
     monkeypatch.setattr(f_ops, "make_forward_packed", boom)
     cache = AutotuneCache(tmp_path / "cache.json")
-    cfg = _tune(model, cache, FakeTimer(DELTAS))
-    assert cfg == DEFAULT_CONFIG
+    with pytest.raises(RuntimeError, match="no kernel for you"):
+        _tune(model, cache, FakeTimer(DELTAS))
     assert not cache.path.exists()      # nothing persisted for a non-race
 
 
@@ -152,12 +151,12 @@ def test_cache_entry_records_timings_and_roundtrips(tmp_path, model):
     assert FusedConfig.from_dict(entry["config"]) == CANDS[1]
 
 
-def test_candidate_configs_cover_both_variants():
-    cands = candidate_configs(64)
-    assert {c.variant for c in cands} == set(autotune.VARIANTS)
-    assert {c.block_b for c in cands} == {64, 32}
-    # tiny buckets don't split below themselves
+def test_candidate_configs_rows_per_bucket():
+    assert [c.block_b for c in candidate_configs(64)] == [64, 32]
+    # tiny buckets don't split below themselves; big ones cap the rows
     assert {c.block_b for c in candidate_configs(8)} == {8}
+    assert {c.block_b for c in candidate_configs(4096)} == \
+        {autotune.MAX_BLOCK_B}
 
 
 def test_tuned_configs_stay_bit_exact(model):

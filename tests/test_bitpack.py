@@ -187,18 +187,19 @@ def test_fused_packed_kernel_single_layer(B, m):
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))
 
 
-@pytest.mark.parametrize("B,m", [(8, 10), (37, 50), (64, 360)])
+@pytest.mark.parametrize("B,m", [(8, 10), (37, 50), (64, 360), (8, 1000)])
 @pytest.mark.parametrize("block_b", [256, 16])
 def test_fused_batch_major_variant(B, m, block_b):
     """Direct-wire batch-major variant: bit-exact vs the packed oracle
-    at every preset width, ragged batches, and with a grid of >1 step."""
+    at every preset width, ragged batches, and with a grid of >1 step
+    over rows and (m=1000: two ``BLOCK_M`` tiles) over LUTs."""
     from repro.kernels.autotune import FusedConfig
     from repro.kernels.fused import ops as f_ops
     from repro.kernels.fused.ref import fused_dwn_packed_ref
     x, th, mapping, tables = _rand_model(B, 16, 200, m, seed=m + 1)
     counts, idx = f_ops.forward_packed(
         x, th, mapping, tables, 5, interpret=True,
-        config=FusedConfig(variant="batch-major", block_b=block_b))
+        config=FusedConfig(block_b=block_b))
     ref_counts, ref_idx = fused_dwn_packed_ref(x, th, [mapping], [tables], 5)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))
@@ -220,7 +221,7 @@ def test_fused_batch_major_multilayer():
               jax.random.randint(k5, (50, 64), 0, 2)]
     counts, idx = f_ops.forward_packed(
         x, th, mappings, tables, 5, interpret=True,
-        config=FusedConfig(variant="batch-major", block_b=16))
+        config=FusedConfig(block_b=16))
     ref_counts, ref_idx = fused_dwn_packed_ref(x, th, mappings, tables, 5)
     np.testing.assert_array_equal(np.asarray(counts), np.asarray(ref_counts))
     np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_idx))

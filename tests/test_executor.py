@@ -246,3 +246,33 @@ def test_executor_persists_point_artifacts(tmp_path):
     art = load_artifact(subdirs[0])
     assert art.stage == "packed"
     assert art.spec.preset in ("sm-10", "sm-50")
+
+
+# ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+def test_chip_count_stays_off_jax_and_pins_one_chip(monkeypatch):
+    """The dispatcher counts chips from device files, never via JAX; a
+    CPU-only run counts none, and a pinned worker sees one chip."""
+    from repro.sweep import executor
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert executor._tpu_chips() == 0
+    env = executor._chip_env(3)
+    assert env["TPU_VISIBLE_CHIPS"] == "3"
+    assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+    assert env["TPU_PROCESS_ADDRESSES"] == \
+        f"localhost:{env['TPU_PROCESS_PORT']}"
+
+
+def test_tpu_host_runs_at_most_one_worker_per_chip(tmp_path, monkeypatch):
+    """Asked for four workers on a two-chip host, the run starts two and
+    still computes every point."""
+    from repro.sweep import executor
+    monkeypatch.setattr(executor, "_tpu_chips", lambda: 2)
+    res = run_grid_parallel(POINTS, FAST, cache_dir=tmp_path,
+                            executor=ExecutorSettings(workers=4))
+    assert res.executor["workers"] == 2
+    assert res.executor["workers_spawned"] == 2
+    assert res.executor["computed"] == len(POINTS)

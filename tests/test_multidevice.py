@@ -23,12 +23,13 @@ SCRIPT = textwrap.dedent("""
     from repro.models import api
     from repro.sharding.partition import Partitioner
     from repro.runtime import checkpoint as ckpt
+    from repro.launch.mesh import make_mesh
 
     cfg = get_arch("qwen3-8b").reduced()
     out = {}
 
     def train_on(mesh_shape, axes, ckpt_dir, restore):
-        mesh = jax.make_mesh(mesh_shape, axes)
+        mesh = make_mesh(mesh_shape, axes)
         tp = mesh.shape["model"]
         part = Partitioner(mesh)
         ap = api.abstract_params(cfg, tp)

@@ -23,6 +23,7 @@ from pathlib import Path
 import numpy as np
 import pytest
 
+from repro.kernels.autotune import FusedConfig, candidate_configs
 from repro.serving import (MicrobatchScheduler, ServingEngine,
                            available_backends, power_of_two_buckets)
 
@@ -234,8 +235,8 @@ def test_backend_auto_select_calibrates_and_serves():
     # startup; the chosen per-bucket configs surface in the report
     assert sorted(eng.tuned_configs) == sorted(eng.scheduler.buckets)
     assert set(rep["autotune"]) == set(eng.scheduler.buckets)
-    for cfg in rep["autotune"].values():
-        assert cfg["variant"] in ("packed", "batch-major")
+    for bucket, cfg in rep["autotune"].items():
+        assert FusedConfig.from_dict(cfg) in candidate_configs(bucket)
     # explicit --backend remains the override path, and switching back to
     # auto restores the startup-calibrated selector (no re-timing)
     auto_before = eng.auto
