@@ -1,5 +1,5 @@
 """Benchmark harness: every benchmark family behind one command —
-paper tables/figures, roofline, kernels, serving, and the sweep smoke.
+paper tables/figures, roofline, kernels, training, and the sweep smoke.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--only table1,fig5]
 Each benchmark prints ``name,us_per_call,derived`` CSV rows followed by its
@@ -19,8 +19,7 @@ def main(argv=None):
     enable_compile_cache()
     from . import (table1_hardware, table2_literature, table3_quantization,
                    cosim_smoke, fig2_encoding, fig5_breakdown, fig6_pareto,
-                   roofline_report, kernels_bench, load_harness, serve_bench,
-                   sweep_smoke, train_bench)
+                   roofline_report, kernels_bench, sweep_smoke, train_bench)
     benches = {
         "table1": table1_hardware.run,
         "table2": table2_literature.run,
@@ -30,8 +29,6 @@ def main(argv=None):
         "fig6": fig6_pareto.run,
         "roofline": roofline_report.run,
         "kernels": kernels_bench.run,
-        "serve": serve_bench.run,
-        "load": load_harness.run,
         "sweep": sweep_smoke.run,
         "cosim": cosim_smoke.run,
         "train": train_bench.run,

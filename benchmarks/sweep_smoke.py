@@ -1,7 +1,7 @@
 """Sweep smoke — the tiny encoding grid through the full sweep pipeline.
 
 Runs ``repro.sweep`` end-to-end (accuracy + hardware + fused-kernel axes;
-the serving axis is covered separately by ``serve_bench``) on the 6-point
+serving is measured by the on-chip benchmark in ``bench/``) on the 6-point
 tiny grid and prints the result table.  Asserts the two sweep invariants
 that the paper-tolerance tests also pin down: TEN rows within tolerance
 and encoder LUTs monotone in the PEN input width.

@@ -23,8 +23,7 @@ parallel sweep executor against the serial in-process grid runner on the
 tiny grid (``sweep_executor`` row — parallel-vs-serial wall-clock).
 
 Writes ``BENCH_train.json`` at the repo root (one record per run,
-overwritten) — the training-side companion of ``BENCH_kernels.json`` /
-``BENCH_serve.json``.
+overwritten) — the training-side companion of ``BENCH_kernels.json``.
 """
 
 import json

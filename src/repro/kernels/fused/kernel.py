@@ -253,5 +253,6 @@ def fused_dwn_batch_major(x: jax.Array, thresholds: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="dwn_fused_forward",
     )(lvl, *layer_arrays, class_map)
     return counts[:B, :num_classes], idx[:B, 0]

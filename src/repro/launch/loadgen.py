@@ -27,9 +27,6 @@ CLI::
 
     PYTHONPATH=src python -m repro.launch.loadgen --preset dwn-jsc-sm \
         --levels 0.5,1.0,1.3 --duration 2 --mode both --out curve.json
-
-``benchmarks/load_harness.py`` wraps this to record the per-preset
-latency–throughput curve into ``BENCH_serve.json``.
 """
 
 from __future__ import annotations

@@ -13,6 +13,8 @@ Layering (each importable on its own):
     continuous.py  continuous-batching loop: scheduler thread, futures,
                    SLO-aware admission + deadline shedding, bounded-queue
                    backpressure
+    steplog.py     phase spans of each served step on the profiler's
+                   clock + a process-wide ring of per-step records
     engine.py      ServingEngine: sync submit/drain AND async
                    serve()/submit_async over either family, DWN batches
                    sharded data-parallel across the host mesh

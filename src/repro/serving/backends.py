@@ -253,7 +253,8 @@ class BoundBackend:
             def traced(x, _bucket=bucket):
                 # the python body runs once per XLA trace: count them
                 self.compiles[_bucket] += 1
-                return inner(x)
+                with jax.named_scope("dwn_forward"):
+                    return inner(x)
 
             fn = traced
             if self._wrap is not None:
@@ -398,9 +399,9 @@ def autotune_model(model: DWNModelBundle, buckets, x_probe, *,
 class AutoSelector:
     """Per-(arch, bucket) fastest-bit-exact-backend chooser.
 
-    The serving benchmarks show the fastest datapath is *size dependent*
-    (e.g. ``BENCH_serve.json``: on dwn-jsc-sm the float oracle outruns the
-    packed paths, on md/lg the packed paths win).  Instead of hardcoding,
+    The fastest datapath is *size dependent* (in CPU runs the float
+    oracle outran the packed paths on dwn-jsc-sm, while on md/lg the
+    packed paths won).  Instead of hardcoding,
     the selector times every backend that passed the startup bit-exactness
     gate (the oracle is exact by definition) on probe rows at each bucket
     size and serves that bucket on the winner.  Calibration runs once per

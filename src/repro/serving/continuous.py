@@ -54,6 +54,7 @@ from typing import Any, Callable
 
 import numpy as np
 
+from . import steplog
 from .scheduler import Request, bucket_for, power_of_two_buckets
 
 #: shed reasons (the typed result's ``shed`` field)
@@ -152,8 +153,8 @@ class ContinuousScheduler:
         None disables admission-time shedding (expiry and late marking
         still apply: those need no estimate).
       monitor: optional ``runtime.straggler.StragglerMonitor``; every
-        step's wall time is reported, anomalies surface in
-        ``counters()``.
+        step's wall time is reported to it (the engine's report shows
+        its anomalies).
       timer: injectable clock (tests use a deterministic one).
     """
 
@@ -369,28 +370,42 @@ class ContinuousScheduler:
         Returns the number of samples launched (0 if the queue was empty
         after waiting ``wait_s``).  The thread loop is just this method
         on repeat; tests call it directly for deterministic ordering.
+        The step's phases are ``steplog`` spans and one ring record.
         """
         with self._cond:
             if not self._pending and wait_s > 0:
-                self._cond.wait(wait_s)
-            now = self._timer()
-            batch, expired = self._form_batch_locked(now)
-        for r in expired:
-            self._finish(r, shed=SHED_EXPIRED)
-        if not batch:
-            return 0
-        t_start = self._timer()
-        for r, _, _ in batch:
-            if r.t_start == 0.0:      # first launch only: no clock restart
-                r.t_start = t_start
-        xs = [np.asarray(r.payload)[lo:hi] for r, lo, hi in batch]
-        total = sum(x.shape[0] for x in xs)
-        bucket = bucket_for(total, self.buckets)
-        x = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
-        if bucket > total:
-            pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
-            x = np.concatenate([x, pad], axis=0)
-        outs = self._step(x)
+                with steplog.span("serve.wait"):
+                    self._cond.wait(wait_s)
+            if not self._pending:
+                return 0
+        with steplog.step() as rec:
+            with steplog.phase("batch"):
+                with self._cond:
+                    batch, expired = self._form_batch_locked(self._timer())
+                for r in expired:
+                    self._finish(r, shed=SHED_EXPIRED)
+                if not batch:
+                    return 0
+                t_start = self._timer()
+                for r, _, _ in batch:
+                    if r.t_start == 0.0:  # first launch only: no restart
+                        r.t_start = t_start
+                xs = [np.asarray(r.payload)[lo:hi] for r, lo, hi in batch]
+                total = sum(x.shape[0] for x in xs)
+                bucket = bucket_for(total, self.buckets)
+                x = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
+                if bucket > total:
+                    pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
+                    x = np.concatenate([x, pad], axis=0)
+                rec.rows, rec.bucket, rec.requests = total, bucket, len(batch)
+            outs = self._step(x)
+            with steplog.phase("resolve"):
+                self._resolve(batch, bucket, outs, t_start)
+        return total
+
+    def _resolve(self, batch, bucket: int, outs, t_start: float) -> None:
+        """Count the step, then hand each request its rows of ``outs``
+        and resolve the futures of those fully served."""
         t_done = self._timer()
         step_s = t_done - t_start
         self.steps += 1
@@ -415,8 +430,7 @@ class ContinuousScheduler:
                 r.result = result
                 late = r.deadline is not None and t_done > r.deadline
                 self._finish(r, shed=SHED_LATE if late else None,
-                                    value=result)
-        return total
+                             value=result)
 
     # ------------------------------------------------------------------
     # lifecycle
@@ -475,7 +489,7 @@ class ContinuousScheduler:
         """JSON-able loop counters for ``ServingEngine.report()``."""
         served = [r for r in self.completed if r.shed is None]
         shed = len(self.completed) - len(served)
-        out = {
+        return {
             "steps": self.steps,
             "busy_s": round(self.busy_s, 4),
             "session_wall_s": round(
@@ -491,14 +505,6 @@ class ContinuousScheduler:
             "queue_depth_max_samples": self.max_depth_samples,
             "queue_depth_max_requests": self.max_depth_requests,
         }
-        if self.monitor is not None:
-            out["straggler"] = {
-                "window": len(self.monitor.times),
-                "events": len(self.monitor.events),
-                "last_z": round(self.monitor.events[-1].z, 2)
-                if self.monitor.events else None,
-            }
-        return out
 
 
 __all__ = [

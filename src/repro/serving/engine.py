@@ -61,6 +61,7 @@ from .backends import (AutoSelector, BoundBackend, DWNModelBundle,
                        StepTimeEstimator, available_backends,
                        estimator_from_calibration, get_backend,
                        time_backend_step, verify_backends)
+from . import steplog
 from .continuous import AsyncRequest, ContinuousScheduler, SLOConfig
 from .scheduler import MicrobatchScheduler, Request, latency_stats
 
@@ -152,6 +153,8 @@ class ServingEngine:
         self.head_artifact = None
         self.head_bit_exact: bool | None = None
         self._head_served = 0
+        #: report()'s "steps" block covers the steps recorded from here on
+        self._step_mark = steplog.mark()
         if self.family == "dwn":
             assert dwn_head is None, \
                 "dwn_head attaches to an LM engine (the head rides the " \
@@ -295,12 +298,20 @@ class ServingEngine:
         self._dwn_step(np.asarray(self.data.x_test[:bucket]))
 
     def _dwn_step(self, x: np.ndarray):
-        xd = jnp.asarray(x)
-        backend = (self.auto.backend_for(xd) if self.auto is not None
-                   else self.backend)
-        counts, pred = backend.step_for(x.shape[0])(xd)
-        pred.block_until_ready()             # compute timing is this call
-        return np.asarray(counts), np.asarray(pred)
+        bucket = x.shape[0]
+        with steplog.phase("h2d"):
+            xd = jnp.asarray(x)
+        with steplog.phase("dispatch"):
+            backend = (self.auto.backend_for(xd) if self.auto is not None
+                       else self.backend)
+            step = backend.step_for(bucket)
+            before = backend.compiles[bucket]
+            counts, pred = step(xd)
+            steplog.add_compiles(backend.compiles[bucket] - before)
+        with steplog.phase("device"):
+            pred.block_until_ready()         # compute timing is this call
+        with steplog.phase("d2h"):
+            return np.asarray(counts), np.asarray(pred)
 
     # ------------------------------------------------------------------
     # LM prefill/decode path
@@ -594,6 +605,12 @@ class ServingEngine:
         return {name: dict(b.compiles)
                 for name, b in self.backends.items() if b.compiles}
 
+    def _steps_summary(self) -> dict:
+        """``steplog``'s summary of the steps served in this process since
+        the engine was built (as many of them as its ring still holds)."""
+        n = min(steplog.mark() - self._step_mark, steplog.RING.capacity)
+        return steplog.last(n).summary()
+
     def report(self) -> dict:
         """JSON-able serving report over everything served so far.
 
@@ -603,7 +620,10 @@ class ServingEngine:
         percentiles (p50/p99/p999) over *served* requests — shed requests
         are excluded from latency and counted in ``shed``;
         ``queue_depth`` / ``shed`` / ``straggler`` cover both serving
-        modes; LM ``prefill_s`` / ``decode_s_per_tok`` are seconds.
+        modes; LM ``prefill_s`` / ``decode_s_per_tok`` are seconds.  DWN
+        ``steps`` summarises the per-step phase record (``steplog``): mean
+        and p99 ms of each phase, occupancy in %, compiles, and the count
+        of steps it covers.
         """
         async_all = list(self._async_done)
         async_counters = dict(self._async_counters)
@@ -669,6 +689,7 @@ class ServingEngine:
                 "spec": self.spec.to_dict(),
                 "spec_fingerprint": self.spec.fingerprint(),
                 "artifact_stage": self.artifact.stage,
+                "steps": self._steps_summary(),
             })
             if self.tuned_configs:
                 out["autotune"] = {int(b): cfg.to_dict()

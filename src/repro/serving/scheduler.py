@@ -35,6 +35,8 @@ from typing import Any, Callable
 
 import numpy as np
 
+from . import steplog
+
 
 def next_pow2(n: int) -> int:
     """Smallest power of two >= n (bucket math lives in this module)."""
@@ -154,14 +156,20 @@ class MicrobatchScheduler:
 
     def _run_chunk(self, step: Callable, xs: list[np.ndarray],
                    total: int):
-        """Pad a coalesced chunk to its bucket and run one step."""
-        bucket = self.bucket_for(total)
-        x = np.concatenate(xs, axis=0) if len(xs) > 1 else np.asarray(xs[0])
-        if bucket > total:
-            pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
-            x = np.concatenate([x, pad], axis=0)
-        out = step(x)
-        return bucket, [np.asarray(o)[:total] for o in out]
+        """Pad a coalesced chunk to its bucket and run one step, recorded
+        in ``steplog`` like the continuous loop's."""
+        with steplog.step() as rec:
+            with steplog.phase("batch"):
+                bucket = self.bucket_for(total)
+                x = (np.concatenate(xs, axis=0) if len(xs) > 1
+                     else np.asarray(xs[0]))
+                if bucket > total:
+                    pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
+                    x = np.concatenate([x, pad], axis=0)
+                rec.rows, rec.bucket, rec.requests = total, bucket, len(xs)
+            out = step(x)
+            with steplog.phase("resolve"):
+                return bucket, [np.asarray(o)[:total] for o in out]
 
     def drain_batched(self, step: Callable) -> list[Request]:
         """Serve every queued request; returns them in completion order.

@@ -1,0 +1,244 @@
+"""Phase times of every served DWN step: profiler spans and an in-memory ring.
+
+A served step runs in six phases, in this order, and together they
+partition it:
+
+==================  ======================================================
+span                covers
+==================  ======================================================
+``serve.batch``     batch formation under the scheduler's lock, row
+                    slicing, concatenation, padding to the bucket
+``serve.h2d``       the host-to-device copy of the padded batch
+``serve.dispatch``  backend selection and the jitted call (a compile
+                    lands here)
+``serve.device``    waiting for the device (``block_until_ready``)
+``serve.d2h``       the device-to-host copies of counts and predictions
+``serve.resolve``   splitting the outputs per request, setting futures
+==================  ======================================================
+
+``serve.step`` is their parent, with the stats ``step`` (the record's
+sequence number), ``rows``, ``bucket`` and ``requests``; ``serve.wait``
+marks the continuous loop waiting on an empty queue, outside any step.
+While a profiler runs, each span is a ``jax.profiler.TraceAnnotation``
+and lands on the device trace's clock; otherwise a span costs one
+``is_enabled`` check.
+
+The same code records each step into one process-wide :class:`Ring` of
+preallocated arrays (:data:`CAPACITY` steps): the phase times from
+``time.perf_counter_ns``, the real rows launched, the bucket, the
+requests in the batch, and the compiles its dispatch triggered.
+``last(n)`` reads the newest ``n`` records; ``mark()`` / ``since(mark)``
+read what came after a point.  Recording is always on and writes nothing
+to disk.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import threading
+import time
+
+import numpy as np
+from jax.profiler import TraceAnnotation
+
+#: the phases of a step, in order; span ``serve.<phase>``
+PHASES = ("batch", "h2d", "dispatch", "device", "d2h", "resolve")
+#: steps the process-wide ring holds (about 5.8 MB)
+CAPACITY = 65536
+
+_INDEX = {p: i for i, p in enumerate(PHASES)}
+_RESOLVE = _INDEX["resolve"]
+_enabled = TraceAnnotation.is_enabled
+
+
+class _Local(threading.local):
+    step = None          # the step open on this thread
+
+
+_local = _Local()
+
+
+@dataclasses.dataclass
+class Records:
+    """Recorded steps, oldest first."""
+    phase_ns: np.ndarray      # (n, len(PHASES)) int64
+    step_ns: np.ndarray       # (n,) int64, the whole step
+    rows: np.ndarray          # real rows launched
+    bucket: np.ndarray        # rows of the padded batch
+    requests: np.ndarray      # request slices in the batch
+    compiles: np.ndarray      # XLA traces the dispatch took
+
+    def __len__(self) -> int:
+        return len(self.step_ns)
+
+    def phase_ms(self, *phases: str) -> np.ndarray:
+        """Per-step milliseconds of the named phases, summed."""
+        return self.phase_ns[:, [_INDEX[p] for p in phases]].sum(1) / 1e6
+
+    def occupancy_pct(self) -> float:
+        """Real rows over padded bucket rows, in percent."""
+        return float(self.rows.sum() / self.bucket.sum() * 100.0)
+
+    def summary(self) -> dict:
+        """Mean and p99 milliseconds of the step and of each phase, the
+        occupancy, the compiles and the count of steps covered."""
+        if not len(self):
+            return {"count": 0}
+        ms = {"step": self.step_ns / 1e6}
+        ms.update((p, self.phase_ms(p)) for p in PHASES)
+        return {
+            "count": len(self),
+            "ms": {k: {"mean": round(float(v.mean()), 4),
+                       "p99": round(float(np.percentile(v, 99)), 4)}
+                   for k, v in ms.items()},
+            "occupancy_pct": round(self.occupancy_pct(), 3),
+            "compiles": int(self.compiles.sum()),
+        }
+
+
+class Ring:
+    """Fixed-capacity record of steps; the oldest are overwritten."""
+
+    def __init__(self, capacity: int = CAPACITY):
+        self.capacity = capacity
+        #: per step: the phases' ns, the step's ns, rows, bucket,
+        #: requests, compiles
+        self._data = np.zeros((capacity, len(PHASES) + 5), np.int64)
+        self._n = 0
+        self._lock = threading.Lock()
+
+    def record(self, phase_ns, step_ns: int, rows: int, bucket: int,
+               requests: int, compiles: int) -> int:
+        """Store one step; returns its sequence number."""
+        with self._lock:
+            seq = self._n
+            self._data[seq % self.capacity] = (*phase_ns, step_ns, rows,
+                                               bucket, requests, compiles)
+            self._n = seq + 1
+        return seq
+
+    def mark(self) -> int:
+        """Steps recorded so far; a point for :meth:`since`."""
+        return self._n
+
+    def last(self, n: int) -> Records | None:
+        """The newest ``n`` records, or None where the ring does not
+        hold ``n`` (fewer recorded, or overwritten)."""
+        with self._lock:
+            return self._read(self._n - n)
+
+    def since(self, mark: int) -> Records | None:
+        """The records after ``mark``, or None where they are overwritten."""
+        with self._lock:
+            return self._read(mark)
+
+    def _read(self, start: int) -> Records | None:
+        if start > self._n or start < max(0, self._n - self.capacity):
+            return None
+        d = self._data[np.arange(start, self._n) % self.capacity]
+        k = len(PHASES)
+        return Records(d[:, :k], *d[:, k:].T)
+
+
+#: the process-wide ring every served step records into
+RING = Ring()
+
+
+def last(n: int) -> Records | None:
+    return RING.last(n)
+
+
+def mark() -> int:
+    return RING.mark()
+
+
+def since(mark: int) -> Records | None:
+    return RING.since(mark)
+
+
+class span:
+    """``with span(name):`` a profiler annotation while a profiler runs,
+    nothing otherwise."""
+
+    __slots__ = ("name", "_tm")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        self._tm = (TraceAnnotation(self.name).__enter__() if _enabled()
+                    else None)
+        return self
+
+    def __exit__(self, *exc):
+        if self._tm is not None:
+            self._tm.__exit__(*exc)
+
+
+class phase(span):
+    """One phase of the step open on this thread: the ``serve.<name>``
+    span, and the time since the previous phase ended added to the step's
+    record.  Outside a step (warm-up, a bare engine step) only the span."""
+
+    __slots__ = ("_i",)
+
+    def __init__(self, name: str):
+        self.name = "serve." + name
+        self._i = _INDEX[name]
+
+    def __exit__(self, *exc):
+        if self._tm is not None:
+            self._tm.__exit__(*exc)
+        rec = _local.step
+        if rec is not None:
+            rec.end_phase(self._i)
+
+
+class step(span):
+    """One served step: the ``serve.step`` span, and its record in the
+    ring.  The scheduler sets ``rows``, ``bucket`` and ``requests`` once
+    the batch is formed; a step that launched no rows, or raised, is not
+    recorded.  What runs after the last phase counts as resolution, so
+    the phases sum to the step."""
+
+    __slots__ = ("rows", "bucket", "requests", "compiles", "_ns", "_t0",
+                 "_t")
+
+    def __init__(self):
+        self.name = "serve.step"
+        self.rows = self.bucket = self.requests = self.compiles = 0
+
+    def __enter__(self):
+        super().__enter__()
+        self._ns = [0] * len(PHASES)
+        self._t0 = self._t = time.perf_counter_ns()
+        _local.step = self
+        return self
+
+    def end_phase(self, i: int) -> None:
+        t = time.perf_counter_ns()
+        self._ns[i] += t - self._t
+        self._t = t
+
+    def __exit__(self, exc_type, *rest):
+        _local.step = None
+        if exc_type is None and self.rows:
+            self.end_phase(_RESOLVE)
+            seq = RING.record(self._ns, self._t - self._t0, self.rows,
+                              self.bucket, self.requests, self.compiles)
+            if self._tm is not None:
+                self._tm.set_metadata(step=seq, rows=self.rows,
+                                      bucket=self.bucket,
+                                      requests=self.requests)
+        super().__exit__(exc_type, *rest)
+
+
+def add_compiles(n: int) -> None:
+    """Count ``n`` XLA traces against the step open on this thread."""
+    rec = _local.step
+    if rec is not None:
+        rec.compiles += n
+
+
+__all__ = ["CAPACITY", "PHASES", "RING", "Records", "Ring", "add_compiles",
+           "last", "mark", "phase", "since", "span", "step"]
