@@ -19,7 +19,11 @@ bits move:
 ``BoundBackend`` binds a backend to one model and owns the
 per-(arch, batch-bucket) compile cache: each bucket size gets exactly one
 ``jax.jit`` entry, and the number of XLA traces actually taken is counted
-so the scheduler's no-recompile guarantee is testable.
+so the scheduler's no-recompile guarantee is testable.  Every jitted step
+returns its answer packed into one 32-bit buffer (:func:`pack_answer`),
+whose device layout is the host's linear order, so that the engine
+fetches each step's answer in a single device-to-host transfer;
+``BoundBackend.unpack`` restores counts and predictions on the host.
 """
 
 from __future__ import annotations
@@ -30,6 +34,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..configs.base import ArchConfig
 from ..core.classifier import predict
@@ -105,9 +110,9 @@ class Backend:
     """One DWN serving datapath.  Subclass + :func:`register_backend`.
 
     ``make_step(model)`` returns ``fn(x) -> (counts, pred)`` for a feature
-    batch ``x (B, F)``; the callable must be pure and jit-able (it is
-    wrapped in ``jax.jit`` — and, data-parallel, in ``shard_map`` — by
-    :class:`BoundBackend`).
+    batch ``x (B, F)``, both of 32-bit dtypes; the callable must be pure
+    and jit-able (it is wrapped in ``jax.jit`` — and, data-parallel, in
+    ``shard_map`` — by :class:`BoundBackend`).
     """
 
     name: str = "?"
@@ -215,6 +220,38 @@ class FloatOracleBackend(Backend):
 
 
 # ---------------------------------------------------------------------------
+# the packed answer: one 32-bit buffer per step
+# ---------------------------------------------------------------------------
+
+def pack_answer(counts: Array, pred: Array) -> Array:
+    """``counts (B, C)`` and ``pred (B,)`` as one ``int32[(C+1)·B]``.
+
+    Class-major blocks ``[counts[:, 0], ..., counts[:, C-1], pred]``: a
+    1-D array, so the chip holds it in the host's linear order and its
+    copy needs no un-tiling or transpose.  Both arrays are bitcast, not
+    converted, so :func:`unpack_answer` restores them exactly.
+    """
+    for a in (counts, pred):
+        if jnp.dtype(a.dtype).itemsize != 4:
+            raise TypeError(f"a packed answer holds 32-bit arrays, got "
+                            f"{a.dtype}")
+    counts = lax.bitcast_convert_type(counts, jnp.int32)
+    pred = lax.bitcast_convert_type(pred, jnp.int32)
+    return jnp.concatenate([counts.T, pred[None]], axis=0).reshape(-1)
+
+
+def unpack_answer(buf: np.ndarray, blocks: int, classes: int,
+                  counts_dtype, pred_dtype):
+    """Invert :func:`pack_answer` on the host: ``(counts (B, C), pred
+    (B,))`` from ``blocks`` packed answers laid end to end (one per
+    data-parallel shard, each over its own rows, in row order)."""
+    a = buf.reshape(blocks, classes + 1, -1)
+    counts = a[:, :classes].view(counts_dtype).transpose(0, 2, 1)
+    return (counts.reshape(-1, classes),
+            a[:, classes].view(pred_dtype).reshape(-1))
+
+
+# ---------------------------------------------------------------------------
 # bound backend: per-(arch, bucket) compile cache
 # ---------------------------------------------------------------------------
 
@@ -222,10 +259,15 @@ class BoundBackend:
     """A backend bound to one model, with a per-bucket compile cache.
 
     ``step_for(bucket)`` returns the jitted step for that batch-bucket,
-    compiling at most once per bucket; ``wrap(fn, bucket)`` (optional,
-    supplied by the engine) may interpose ``shard_map`` for data-parallel
-    buckets.  ``compiles`` maps bucket -> number of XLA traces taken, the
-    observable the scheduler tests pin down.
+    compiling at most once per bucket: ``x (B, F) -> int32[(C+1)·B]``,
+    the step's answer packed by :func:`pack_answer`; ``unpack`` turns
+    the host copy of that buffer back into ``(counts, pred)``, and
+    calling the bound backend does both.  ``wrap(fn, bucket)``
+    (optional, supplied by the engine) may interpose ``shard_map`` for
+    data-parallel buckets; it returns the wrapped step and the number of
+    shards, each of which packs its own rows.  ``compiles`` maps bucket
+    -> number of XLA traces taken, the observable the scheduler tests
+    pin down.
     """
 
     def __init__(self, backend: Backend, model: DWNModelBundle, *,
@@ -235,6 +277,10 @@ class BoundBackend:
         self._fn = backend.make_step(model)
         self._wrap = wrap
         self._jitted: dict[int, Callable] = {}
+        #: bucket -> shards, and the (classes, counts dtype, pred dtype)
+        #: of its answer (known once traced)
+        self._shards: dict[int, int] = {}
+        self._answer: dict[int, tuple] = {}
         self.compiles: dict[int, int] = {}
 
     @property
@@ -254,16 +300,27 @@ class BoundBackend:
                 # the python body runs once per XLA trace: count them
                 self.compiles[_bucket] += 1
                 with jax.named_scope("dwn_forward"):
-                    return inner(x)
+                    counts, pred = inner(x)
+                self._answer[_bucket] = (counts.shape[-1], counts.dtype,
+                                         pred.dtype)
+                return pack_answer(counts, pred)
 
-            fn = traced
+            fn, shards = traced, 1
             if self._wrap is not None:
-                fn = self._wrap(fn, bucket)
+                fn, shards = self._wrap(fn, bucket)
+            self._shards[bucket] = shards
             self._jitted[bucket] = jax.jit(fn)
         return self._jitted[bucket]
 
+    def unpack(self, buf: np.ndarray, bucket: int):
+        """``(counts (B, C), pred (B,))`` in the backend's dtypes from the
+        host copy of ``step_for(bucket)``'s answer."""
+        return unpack_answer(buf, self._shards[bucket],
+                             *self._answer[bucket])
+
     def __call__(self, x: Array):
-        return self.step_for(x.shape[0])(x)
+        bucket = x.shape[0]
+        return self.unpack(np.asarray(self.step_for(bucket)(x)), bucket)
 
 
 # ---------------------------------------------------------------------------
@@ -347,7 +404,8 @@ def estimator_from_calibration(auto: "AutoSelector") -> StepTimeEstimator:
 
 def time_backend_step(bound: "BoundBackend", x: Array, *,
                       iters: int = 3) -> float:
-    """Best-of-``iters`` seconds of one bound step at x's bucket size.
+    """Best-of-``iters`` seconds of one bound step at x's bucket size:
+    the jitted step with its packed answer, as the engine serves it.
 
     The first (untimed) call warms the (backend, bucket) compile cache,
     so the measurement sees steady-state serving, exactly like a running
@@ -492,12 +550,12 @@ def verify_backends(model: DWNModelBundle,
     oracle = get_backend("float-oracle")
     oracle_bound = next((b for b in backends if b.is_oracle),
                         BoundBackend(oracle, model))
-    counts_ref, pred_ref = jax.device_get(oracle_bound(x))
+    counts_ref, pred_ref = oracle_bound(x)
     results: dict[str, bool] = {}
     for b in backends:
         if b.is_oracle:
             continue
-        counts, pred = jax.device_get(b(x))
+        counts, pred = b(x)
         ok = (np.array_equal(np.asarray(counts, np.float32),
                              np.asarray(counts_ref, np.float32))
               and np.array_equal(pred, pred_ref))
@@ -514,5 +572,6 @@ __all__ = [
     "AutoSelector", "Backend", "BoundBackend", "DWNModelBundle",
     "StepTimeEstimator", "autotune_model", "available_backends",
     "build_dwn_model", "estimator_from_calibration", "get_backend",
-    "register_backend", "time_backend_step", "verify_backends",
+    "pack_answer", "register_backend", "time_backend_step",
+    "unpack_answer", "verify_backends",
 ]

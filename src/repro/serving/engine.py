@@ -244,21 +244,23 @@ class ServingEngine:
         self._auto_saved = self.auto
 
     def _shard_wrap(self, fn, bucket: int):
-        """shard_map a backend step over the ("data",) mesh for one bucket.
+        """shard_map a backend step over the ("data",) mesh for one bucket;
+        returns the step and its number of shards.
 
-        Buckets that don't divide the device count run unsharded (the
-        ladder is powers of two, so with a power-of-two device count only
-        buckets below the device count fall back).
+        Each shard packs the answer of its own rows into one contiguous
+        block of the flat output, so no collective is added.  Buckets
+        that don't divide the device count run unsharded, as one block
+        (the ladder is powers of two, so with a power-of-two device count
+        only buckets below the device count fall back).
         """
         if bucket % self.n_data != 0:
-            return fn
+            return fn, 1
         spec_x = self._part.spec(("dwn_batch", None), name="dwn.serve.x")
-        spec_counts = self._part.spec(("dwn_batch", None),
-                                      name="dwn.serve.counts")
-        spec_pred = self._part.spec(("dwn_batch",), name="dwn.serve.pred")
+        spec_answer = self._part.spec(("dwn_batch",),
+                                      name="dwn.serve.answer")
         return jax.shard_map(fn, mesh=self.mesh, in_specs=(spec_x,),
-                             out_specs=(spec_counts, spec_pred),
-                             check_vma=False)
+                             out_specs=spec_answer,
+                             check_vma=False), self.n_data
 
     def use_backend(self, name: str) -> None:
         """Switch the active DWN datapath (compile caches are kept).
@@ -306,12 +308,16 @@ class ServingEngine:
                        else self.backend)
             step = backend.step_for(bucket)
             before = backend.compiles[bucket]
-            counts, pred = step(xd)
+            answer = step(xd)
+            # the one copy of the step's packed answer, in flight as soon
+            # as the forward ends
+            answer.copy_to_host_async()
             steplog.add_compiles(backend.compiles[bucket] - before)
+            steplog.add_d2h_copies(1)
         with steplog.phase("device"):
-            pred.block_until_ready()         # compute timing is this call
+            answer.block_until_ready()       # compute timing is this call
         with steplog.phase("d2h"):
-            return np.asarray(counts), np.asarray(pred)
+            return backend.unpack(np.asarray(answer), bucket)
 
     # ------------------------------------------------------------------
     # LM prefill/decode path

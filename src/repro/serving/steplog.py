@@ -9,10 +9,12 @@ span                covers
 ``serve.batch``     batch formation under the scheduler's lock, row
                     slicing, concatenation, padding to the bucket
 ``serve.h2d``       the host-to-device copy of the padded batch
-``serve.dispatch``  backend selection and the jitted call (a compile
-                    lands here)
+``serve.dispatch``  backend selection, the jitted call (a compile
+                    lands here) and issuing the copy of its answer
 ``serve.device``    waiting for the device (``block_until_ready``)
-``serve.d2h``       the device-to-host copies of counts and predictions
+``serve.d2h``       the one device-to-host copy of the step's packed
+                    answer (counts and predictions in one buffer),
+                    issued at dispatch, and its unpacking on the host
 ``serve.resolve``   splitting the outputs per request, setting futures
 ==================  ======================================================
 
@@ -26,7 +28,8 @@ and lands on the device trace's clock; otherwise a span costs one
 The same code records each step into one process-wide :class:`Ring` of
 preallocated arrays (:data:`CAPACITY` steps): the phase times from
 ``time.perf_counter_ns``, the real rows launched, the bucket, the
-requests in the batch, and the compiles its dispatch triggered.
+requests in the batch, the compiles its dispatch triggered, and
+``d2h_copies``, the device-to-host transfers the step issued (one).
 ``last(n)`` reads the newest ``n`` records; ``mark()`` / ``since(mark)``
 read what came after a point.  Recording is always on and writes nothing
 to disk.
@@ -43,7 +46,7 @@ from jax.profiler import TraceAnnotation
 
 #: the phases of a step, in order; span ``serve.<phase>``
 PHASES = ("batch", "h2d", "dispatch", "device", "d2h", "resolve")
-#: steps the process-wide ring holds (about 5.8 MB)
+#: steps the process-wide ring holds (about 6.8 MB)
 CAPACITY = 65536
 
 _INDEX = {p: i for i, p in enumerate(PHASES)}
@@ -67,6 +70,7 @@ class Records:
     bucket: np.ndarray        # rows of the padded batch
     requests: np.ndarray      # request slices in the batch
     compiles: np.ndarray      # XLA traces the dispatch took
+    d2h_copies: np.ndarray    # device-to-host transfers issued
 
     def __len__(self) -> int:
         return len(self.step_ns)
@@ -81,7 +85,8 @@ class Records:
 
     def summary(self) -> dict:
         """Mean and p99 milliseconds of the step and of each phase, the
-        occupancy, the compiles and the count of steps covered."""
+        occupancy, the compiles, the device-to-host copies and the count
+        of steps covered."""
         if not len(self):
             return {"count": 0}
         ms = {"step": self.step_ns / 1e6}
@@ -93,6 +98,7 @@ class Records:
                    for k, v in ms.items()},
             "occupancy_pct": round(self.occupancy_pct(), 3),
             "compiles": int(self.compiles.sum()),
+            "d2h_copies": int(self.d2h_copies.sum()),
         }
 
 
@@ -102,18 +108,19 @@ class Ring:
     def __init__(self, capacity: int = CAPACITY):
         self.capacity = capacity
         #: per step: the phases' ns, the step's ns, rows, bucket,
-        #: requests, compiles
-        self._data = np.zeros((capacity, len(PHASES) + 5), np.int64)
+        #: requests, compiles, d2h_copies
+        self._data = np.zeros((capacity, len(PHASES) + 6), np.int64)
         self._n = 0
         self._lock = threading.Lock()
 
     def record(self, phase_ns, step_ns: int, rows: int, bucket: int,
-               requests: int, compiles: int) -> int:
+               requests: int, compiles: int, d2h_copies: int = 0) -> int:
         """Store one step; returns its sequence number."""
         with self._lock:
             seq = self._n
             self._data[seq % self.capacity] = (*phase_ns, step_ns, rows,
-                                               bucket, requests, compiles)
+                                               bucket, requests, compiles,
+                                               d2h_copies)
             self._n = seq + 1
         return seq
 
@@ -201,12 +208,13 @@ class step(span):
     recorded.  What runs after the last phase counts as resolution, so
     the phases sum to the step."""
 
-    __slots__ = ("rows", "bucket", "requests", "compiles", "_ns", "_t0",
-                 "_t")
+    __slots__ = ("rows", "bucket", "requests", "compiles", "d2h_copies",
+                 "_ns", "_t0", "_t")
 
     def __init__(self):
         self.name = "serve.step"
         self.rows = self.bucket = self.requests = self.compiles = 0
+        self.d2h_copies = 0
 
     def __enter__(self):
         super().__enter__()
@@ -225,7 +233,8 @@ class step(span):
         if exc_type is None and self.rows:
             self.end_phase(_RESOLVE)
             seq = RING.record(self._ns, self._t - self._t0, self.rows,
-                              self.bucket, self.requests, self.compiles)
+                              self.bucket, self.requests, self.compiles,
+                              self.d2h_copies)
             if self._tm is not None:
                 self._tm.set_metadata(step=seq, rows=self.rows,
                                       bucket=self.bucket,
@@ -240,5 +249,14 @@ def add_compiles(n: int) -> None:
         rec.compiles += n
 
 
+def add_d2h_copies(n: int) -> None:
+    """Count ``n`` device-to-host transfers against the step open on this
+    thread."""
+    rec = _local.step
+    if rec is not None:
+        rec.d2h_copies += n
+
+
 __all__ = ["CAPACITY", "PHASES", "RING", "Records", "Ring", "add_compiles",
-           "last", "mark", "phase", "since", "span", "step"]
+           "add_d2h_copies", "last", "mark", "phase", "since", "span",
+           "step"]

@@ -88,6 +88,16 @@ def test_compiles_on_the_first_use_of_a_bucket_only(engine, path):
     np.testing.assert_array_equal(recs.compiles, [1, 0])
 
 
+@pytest.mark.parametrize("path", PATHS)
+def test_one_device_to_host_copy_per_step(engine, path):
+    """The engine's step issues one copy, of its packed answer; the stub,
+    which copies nothing from a device, counts none."""
+    recs = _serve(path, engine, [128, 64, 128])
+    want = 0 if path == "stub" else 1
+    np.testing.assert_array_equal(recs.d2h_copies, [want] * 3)
+    assert recs.summary()["d2h_copies"] == 3 * want
+
+
 @pytest.mark.parametrize("capacity", (4, steplog.CAPACITY))
 def test_ring_wraps_at_capacity(capacity):
     ring = steplog.Ring(capacity)
@@ -114,6 +124,7 @@ def test_report_has_a_steps_block(engine, path):
     assert all(v["mean"] > 0 and v["p99"] >= 0 for v in steps["ms"].values())
     assert 0 < steps["occupancy_pct"] <= 100
     assert steps["compiles"] >= 0
+    assert 1 <= steps["d2h_copies"] <= steps["count"]
 
 
 def test_profiler_trace_holds_the_serve_spans(engine, tmp_path):

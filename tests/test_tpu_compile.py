@@ -115,3 +115,17 @@ def test_packed_xla_step_compiles_at_lg(one_chip):
     mem = compiled.memory_analysis()
     assert "tpu_custom_call" not in compiled.as_text()
     assert 0 < mem.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_packed_answer_is_linear_on_v5e(one_chip):
+    """The served step's answer compiles to one 1-D int32 output at lg,
+    B=4096: a 1-D array is held in the host's linear order, so its copy
+    to the host needs no un-tiling or transpose."""
+    from repro.serving.backends import pack_answer
+    fr = _frozen("jsc-lg-2400")
+    fwd = _fused(fr)
+    compiled = _compile(lambda x: pack_answer(*fwd(x)), 4096, 16, one_chip)
+    entry = next(l for l in compiled.as_text().splitlines()
+                 if l.startswith("ENTRY"))
+    assert entry.rstrip(" {").endswith("-> s32[24576]"), entry
+    assert "tpu_custom_call" in compiled.as_text()
