@@ -20,7 +20,8 @@ def round_up(x: int, m: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class ArchConfig:
     name: str
-    family: str                      # dense | moe | ssm | hybrid | encdec | vlm
+    family: str                      # dense | moe | ssm | hybrid | encdec |
+                                     # vlm | ssm_moe
     num_layers: int
     d_model: int
     num_heads: int
@@ -50,6 +51,17 @@ class ArchConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_chunk: int = 256
+
+    # --- Mamba-2 + attention with MoE FFNs (family="ssm_moe", Granite 4.0-H) ---
+    layer_types: tuple = ()          # "mamba" | "attention" per layer
+    shared_ff: int = 0               # shared-expert SwiGLU width
+    experts_held: int = 0            # experts of each layer held here
+                                     # (0 = all); the router stays
+                                     # num_experts wide
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # scales each branch before its add
+    logits_scaling: float = 1.0       # logits are divided by it
+    attention_multiplier: float = 0.0  # softmax scale (0 -> head_dim^-0.5)
 
     # --- hybrid (RecurrentGemma: RG-LRU + local attention, 1 attn : 2 rec) ---
     lru_width: int = 0
@@ -161,6 +173,16 @@ class ArchConfig:
 
     def reduced(self) -> "ArchConfig":
         """Tiny same-family variant for CPU smoke tests."""
+        if self.family == "ssm_moe":
+            # a Mamba-2 and an attention layer, 4 routed experts and a
+            # shared one, at CPU widths
+            return dataclasses.replace(
+                self, name=self.name + "-reduced", num_layers=2,
+                layer_types=("mamba", "attention"),
+                d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
+                d_ff=32, shared_ff=48, vocab_size=251, num_experts=4,
+                top_k=2, experts_held=0, ssm_state=16, ssm_headdim=16,
+                ssm_chunk=8, attn_chunk=16, attention_multiplier=1 / 16)
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",

@@ -27,7 +27,7 @@ def list_archs() -> list[str]:
 
 
 def assigned_archs() -> list[str]:
-    """The 10 pool architectures (excludes the paper's own DWN models)."""
+    """The LM architectures (excludes the paper's own DWN models)."""
     _load_all()
     return sorted(n for n, c in _REGISTRY.items() if c.family != "dwn")
 
@@ -42,5 +42,5 @@ def _load_all():
     from . import (granite_moe_3b_a800m, mixtral_8x7b, whisper_large_v3,  # noqa
                    mamba2_1_3b, qwen3_8b, phi3_mini_3_8b, qwen2_7b,
                    qwen3_14b, recurrentgemma_2b, llava_next_34b, dwn_jsc,
-                   dwn_mnist, dwn_lm_head)
+                   dwn_mnist, dwn_lm_head, granite_4_0_h_small)
     _LOADED = True

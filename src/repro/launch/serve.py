@@ -127,19 +127,26 @@ def lm_serve(cfg, args) -> int:
 
     With ``--dwn-head`` (a DWNArtifact checkpoint path or a spec preset
     name like ``dwn-lm-head``) the engine also serves DWN classification
-    on its own backbone features: the same drain serves the LM batch and
-    a ``classify`` batch — one process, both request kinds.
+    on its own backbone features: a ``classify`` batch through the
+    continuous loop beside the LM batch's drain — one process, both
+    request kinds.
     """
+    B = args.batch or 4
+    # classify steps: prompt lengths up to the next power of two, B of
+    # them per step at the longest
+    longest = next_pow2(max(args.prompt_len, 8))
     engine = ServingEngine(
         cfg, reduced=args.reduced, prompt_len=args.prompt_len, gen=args.gen,
         model_parallel=args.model_parallel, seed=args.seed,
-        dwn_head=args.dwn_head or None)
-    B = args.batch or 4
+        dwn_head=args.dwn_head or None, max_bucket=longest,
+        step_tokens=B * longest)
     engine.submit(engine.make_request(B, seed=args.seed))
-    if args.dwn_head:
-        engine.submit(engine.make_request(B, seed=args.seed + 1,
-                                          classify=True))
     done = engine.drain()
+    if args.dwn_head:
+        with engine.serve():
+            head = engine.submit_async(engine.make_request(
+                B, seed=args.seed + 1, classify=True)).future.result()
+        assert head.ok and head.value[1].shape == (B,)
 
     rep = engine.report()
     tokens = done[0].result["tokens"]
@@ -147,9 +154,7 @@ def lm_serve(cfg, args) -> int:
     rep["batch"] = B
     rep["sample"] = tokens[0, :8].tolist()
     if args.dwn_head:
-        head = [r for r in done if "pred" in r.result]
-        assert head and head[0].result["pred"].shape == (B,)
-        rep["head_sample"] = head[0].result["pred"][:8].tolist()
+        rep["head_sample"] = head.value[1][:8].tolist()
     print(json.dumps(rep))
     return 0
 

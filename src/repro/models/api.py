@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig, ShapeConfig
 from ..optim.adam import Adam
 from ..optim.grad import clip_by_global_norm
-from . import transformer, mamba2, rglru, whisper, dwn_arch
+from . import transformer, mamba2, rglru, whisper, dwn_arch, granite
 from . import layers as L
 
 MODULES = {
@@ -34,6 +34,7 @@ MODULES = {
     "hybrid": rglru,
     "encdec": whisper,
     "dwn": dwn_arch,
+    "ssm_moe": granite,
 }
 
 
@@ -187,6 +188,17 @@ def make_prefill(cfg: ArchConfig, tp: int = 16, *, cache_len: int | None = None)
         return mod.prefill(params, cfg, batch, tp=tp, cache_len=cache_len)
 
     return fn
+
+
+def logit_columns(params, cfg: ArchConfig, tokens, cols: int, *,
+                  tp: int = 16):
+    """The first ``cols`` columns of the full-sequence logits (B, S, cols):
+    what a classification head pools.  A family module may compute them
+    without the rest of the vocabulary (``logit_columns``)."""
+    mod = module_for(cfg)
+    if hasattr(mod, "logit_columns"):
+        return mod.logit_columns(params, cfg, tokens, cols, tp=tp)
+    return mod.forward(params, cfg, {"tokens": tokens}, tp=tp)[0][..., :cols]
 
 
 def make_decode_step(cfg: ArchConfig, tp: int = 16):

@@ -263,19 +263,21 @@ def attention_chunked(q: Array, k: Array, v: Array, layout: HeadLayout, *,
                       causal: bool, window: int | None = None,
                       q_offset: Array | int = 0, kv_offset: Array | int = 0,
                       kv_chunk: int = 1024, kv_len: Array | None = None,
-                      scores_dtype=jnp.float32) -> Array:
+                      scores_dtype=jnp.float32,
+                      scale: float | None = None) -> Array:
     """Online-softmax flash attention, pure JAX.
 
     q: (B, Sq, Hp, hd); k/v: (B, Skv, Kp, hd)  (already padded layout).
     window: sliding-window size (None = unbounded).
     kv_len: optional (B,) valid kv length (decode against partial cache).
+    scale: softmax scale of the scores (None = hd^-0.5).
     Returns (B, Sq, Hp, hd).
     """
     B, Sq, Hp, hd = q.shape
     Skv = k.shape[1]
     Kp = layout.kv_padded
     g = Hp // Kp
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     nchunk = -(-Skv // kv_chunk)
     pad = nchunk * kv_chunk - Skv
     if pad:
@@ -574,15 +576,26 @@ def axes_moe(*, ep: bool = False):
 
 def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
               min_capacity: int = 4, num_real_experts: int = 0,
-              ep: bool = False):
-    """Token-choice top-k MoE with per-row capacity (drops overflow).
+              ep: bool = False, first_expert: int | None = None):
+    """Token-choice top-k MoE.  Returns (y, aux_loss).
 
-    x: (B, S, D).  Routing/dispatch is independent per batch row, so with
-    batch-sharded activations no routing collective crosses shards; the
-    only cross-device traffic is the TP all-reduce of the expert FFN
-    (Megatron pattern) or, with ep=True, the partial-combine all-reduce.
-    Padded (dead) experts beyond ``num_real_experts`` are masked out of
-    the router.  Returns (y, aux_loss).
+    x: (B, S, D).  The router scores every expert; each token keeps its
+    ``top_k`` and their softmax weights renormalised over the k (the same
+    as a softmax over the top-k logits).
+
+    ``first_expert=None``: per-row capacity routing that drops overflow.
+    Routing/dispatch is independent per batch row, so with batch-sharded
+    activations no routing collective crosses shards; the only
+    cross-device traffic is the TP all-reduce of the expert FFN (Megatron
+    pattern) or, with ep=True, the partial-combine all-reduce.  Padded
+    (dead) experts beyond ``num_real_experts`` are masked out of the
+    router.
+
+    ``first_expert=e0``: expert parallelism's share of one device, with
+    nothing dropped.  ``p``'s expert weights hold the ``n`` experts
+    ``e0 .. e0+n-1`` of the router's ``E``; ``y`` is what those experts
+    give for the tokens routed to them (:func:`_moe_held`).  The parts
+    of all ``E / n`` shares sum to the whole layer.
     """
     B, S, D = x.shape
     E = p["router"].shape[-1]
@@ -599,6 +612,10 @@ def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
     gate_vals, expert_idx = jax.lax.top_k(probs, top_k)          # (B,S,k)
     gate_vals = gate_vals / jnp.maximum(
         gate_vals.sum(-1, keepdims=True), 1e-9)
+    frac_probs = jnp.mean(probs, axis=(0, 1))
+    if first_expert is not None:
+        y = _moe_held(p, x, expert_idx, gate_vals, first_expert)
+        return y.astype(x.dtype), _aux_loss(expert_idx, frac_probs, E)
 
     # position of each (token, k) within its expert, token-major order
     flat_e = expert_idx.reshape(B, S * top_k)                    # (B,T)
@@ -661,12 +678,51 @@ def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
         w = (gate_vals * keep.reshape(B, S, top_k)).astype(jnp.float32)
         y = jnp.einsum("bskd,bsk->bsd", yk.astype(jnp.float32), w)
 
-    # load-balance auxiliary loss (Switch-style)
+    return y.astype(x.dtype), _aux_loss(expert_idx, frac_probs, E)
+
+
+def _aux_loss(expert_idx: Array, frac_probs: Array, E: int) -> Array:
+    """Switch-style load-balance loss from each token's first choice."""
     frac_tokens = jnp.mean(
         jax.nn.one_hot(expert_idx[..., 0], E, dtype=jnp.float32), axis=(0, 1))
-    frac_probs = jnp.mean(probs, axis=(0, 1))
-    aux = E * jnp.sum(frac_tokens * frac_probs)
-    return y.astype(x.dtype), aux
+    return E * jnp.sum(frac_tokens * frac_probs)
+
+
+def _moe_held(p, x: Array, expert_idx: Array, gate_vals: Array,
+              first_expert: int) -> Array:
+    """The held experts' part of the layer, dropless: (B, S, D) float32.
+
+    Every (token, choice) pair routed to a held expert is computed.  The
+    pairs are sorted by expert and run through grouped matrix products
+    (``lax.ragged_dot``) over the held experts' weights.  A token picks
+    each expert at most once, so at most ``T * min(k, n)`` pairs can land
+    here: that is the static size of the sorted buffer, and rows past the
+    pairs routed here are masked out.
+    """
+    B, S, D = x.shape
+    k = expert_idx.shape[-1]
+    n = p["w_gate"].shape[0]
+    T = B * S
+    local = expert_idx.reshape(T * k) - first_expert
+    key = jnp.where((local >= 0) & (local < n), local, n)        # n = absent
+    order = jnp.argsort(key, stable=True)
+    rows = order[:T * min(k, n)]
+    tok = rows // k
+    sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
+    valid = jnp.arange(rows.shape[0]) < sizes.sum()
+    cd = COMPUTE_DTYPE
+    xs = x.reshape(T, D).astype(cd)[tok]
+    g = jax.lax.ragged_dot(xs, p["w_gate"].astype(cd), sizes,
+                           preferred_element_type=jnp.float32)
+    u = jax.lax.ragged_dot(xs, p["w_up"].astype(cd), sizes,
+                           preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * u).astype(cd)
+    ye = jax.lax.ragged_dot(h, p["w_down"].astype(cd), sizes,
+                            preferred_element_type=jnp.float32)
+    w = jnp.where(valid, gate_vals.reshape(T * k)[rows], 0.0)
+    ye = jnp.where(valid[:, None], ye, 0.0) * w[:, None]
+    y = jnp.zeros((T, D), jnp.float32).at[tok].add(ye)
+    return y.reshape(B, S, D)
 
 
 def _gather_slots(src: Array, idx: Array) -> Array:

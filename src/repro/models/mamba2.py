@@ -217,11 +217,20 @@ def _causal_conv(xbc: Array, w: Array, b: Array) -> Array:
 
 def _block_apply(lp, cfg: ArchConfig, x: Array, *, state=None,
                  conv_state=None):
-    """Full-sequence SSD block.  state/conv_state: optional initial carry."""
+    """Full-sequence SSD block: ``x + mixer(RMSNorm(x))``.  state: optional
+    initial SSM carry."""
+    h = L.rms_norm(x, lp["ln"]["scale"], cfg.norm_eps)
+    out, h_last = mixer(lp, cfg, h, state=state)
+    return hint_act(x + out), h_last
+
+
+def mixer(lp, cfg: ArchConfig, h: Array, *, state=None):
+    """The Mamba-2 mixer on normalised input ``h`` (B, S, D): in-projection,
+    causal conv, chunked SSD, gated RMSNorm, out-projection.  Returns
+    (out (B, S, D), last SSM state).  ``lp`` needs no ``ln``."""
     d_inner, nheads = _dims(cfg)
     G, N, P = cfg.ssm_ngroups, cfg.ssm_state, cfg.ssm_headdim
     cd = L.COMPUTE_DTYPE
-    h = L.rms_norm(x, lp["ln"]["scale"], cfg.norm_eps)
     z = hint(jnp.einsum("bsd,di->bsi", h.astype(cd), lp["w_z"].astype(cd)),
              "dp", None, "model")
     xin = hint(jnp.einsum("bsd,di->bsi", h.astype(cd), lp["w_x"].astype(cd)),
@@ -238,7 +247,7 @@ def _block_apply(lp, cfg: ArchConfig, x: Array, *, state=None,
     B_ = conv_out[..., d_inner:d_inner + G * N]
     C_ = conv_out[..., d_inner + G * N:]
 
-    Bt, S = x.shape[0], x.shape[1]
+    Bt, S = h.shape[0], h.shape[1]
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
                          + lp["dt_bias"].astype(jnp.float32))
     A = jnp.exp(lp["A_log"].astype(jnp.float32))
@@ -254,7 +263,7 @@ def _block_apply(lp, cfg: ArchConfig, x: Array, *, state=None,
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(cd)  # gated
     y = L.rms_norm(y, lp["out_norm"]["scale"], cfg.norm_eps)
     out = jnp.einsum("bsi,id->bsd", y, lp["w_out"].astype(cd))
-    return hint_act(x + out), h_last
+    return out, h_last
 
 
 def forward(params, cfg: ArchConfig, batch, *, tp: int = 16,

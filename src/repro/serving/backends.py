@@ -223,32 +223,41 @@ class FloatOracleBackend(Backend):
 # the packed answer: one 32-bit buffer per step
 # ---------------------------------------------------------------------------
 
-def pack_answer(counts: Array, pred: Array) -> Array:
+def pack_answer(counts: Array, pred: Array, *extra: Array) -> Array:
     """``counts (B, C)`` and ``pred (B,)`` as one ``int32[(C+1)·B]``.
 
     Class-major blocks ``[counts[:, 0], ..., counts[:, C-1], pred]``: a
     1-D array, so the chip holds it in the host's linear order and its
-    copy needs no un-tiling or transpose.  Both arrays are bitcast, not
-    converted, so :func:`unpack_answer` restores them exactly.
+    copy needs no un-tiling or transpose.  Every array is bitcast, not
+    converted, so :func:`unpack_answer` restores them exactly.  Each
+    ``extra`` (B, k) 32-bit array follows as k further blocks.
     """
-    for a in (counts, pred):
+    arrays = (counts, pred) + extra
+    for a in arrays:
         if jnp.dtype(a.dtype).itemsize != 4:
             raise TypeError(f"a packed answer holds 32-bit arrays, got "
                             f"{a.dtype}")
-    counts = lax.bitcast_convert_type(counts, jnp.int32)
-    pred = lax.bitcast_convert_type(pred, jnp.int32)
-    return jnp.concatenate([counts.T, pred[None]], axis=0).reshape(-1)
+    blocks = [lax.bitcast_convert_type(a, jnp.int32) for a in arrays]
+    blocks = [b.T if b.ndim == 2 else b[None] for b in blocks]
+    return jnp.concatenate(blocks, axis=0).reshape(-1)
 
 
 def unpack_answer(buf: np.ndarray, blocks: int, classes: int,
-                  counts_dtype, pred_dtype):
+                  counts_dtype, pred_dtype, extra=()):
     """Invert :func:`pack_answer` on the host: ``(counts (B, C), pred
-    (B,))`` from ``blocks`` packed answers laid end to end (one per
-    data-parallel shard, each over its own rows, in row order)."""
-    a = buf.reshape(blocks, classes + 1, -1)
+    (B,), *extra)`` from ``blocks`` packed answers laid end to end (one
+    per data-parallel shard, each over its own rows, in row order).
+    ``extra`` gives each extra array's (width, dtype)."""
+    a = buf.reshape(blocks, classes + 1 + sum(w for w, _ in extra), -1)
     counts = a[:, :classes].view(counts_dtype).transpose(0, 2, 1)
-    return (counts.reshape(-1, classes),
-            a[:, classes].view(pred_dtype).reshape(-1))
+    out = [counts.reshape(-1, classes),
+           a[:, classes].view(pred_dtype).reshape(-1)]
+    row = classes + 1
+    for width, dtype in extra:
+        block = a[:, row:row + width].view(dtype).transpose(0, 2, 1)
+        out.append(block.reshape(-1, width))
+        row += width
+    return tuple(out)
 
 
 # ---------------------------------------------------------------------------

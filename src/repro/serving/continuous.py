@@ -35,11 +35,16 @@ Design:
   so an open-loop producer feels the engine's capacity instead of
   growing an unbounded heap.
 
-The loop is model-agnostic: ``step(x)`` takes a bucket-padded array and
-returns a tuple of per-sample result arrays, exactly the
-``drain_batched`` contract.  ``step_once()`` runs one scheduling decision
-plus one step synchronously — the unit tests drive it without threads,
-so ordering assertions are deterministic.
+The loop is model-agnostic: a *batching* decides which queued samples
+a step takes and how they are padded, and ``step(*args)`` runs the padded
+batch and returns a tuple of per-sample result arrays, exactly the
+``drain_batched`` contract.  :class:`RowBuckets` (the default) packs
+feature rows densely along axis 0 into the power-of-two ladder;
+:class:`TokenBuckets` right-pads token sequences into one of a few
+(batch, length) shapes of equal token count and passes the real lengths
+along.  ``step_once()`` runs one scheduling decision plus one step
+synchronously — the unit tests drive it without threads, so ordering
+assertions are deterministic.
 """
 
 from __future__ import annotations
@@ -136,15 +141,114 @@ class AsyncRequest(Request):
                 self.rid)
 
 
+class RowBuckets:
+    """Feature rows, packed densely along axis 0 and padded to the
+    power-of-two bucket ladder; ``step(x)`` gets the (bucket, ...) array.
+
+    Requests are taken in sort order and the one straddling the bucket
+    boundary is split: its head rows fill this step, the rest stays
+    queued (front of its priority class) for the next step.  Oversize
+    requests fall out of the same rule as max-bucket chunks.
+    """
+
+    def __init__(self, buckets: tuple[int, ...]):
+        self.buckets = buckets
+        self.max_bucket = buckets[-1]
+
+    def take(self, pending) -> list[tuple["AsyncRequest", int]]:
+        """(request, samples) pairs for the next step, in order."""
+        out, total = [], 0
+        for r in pending:
+            if total >= self.max_bucket:
+                break
+            n = min(r.size - r.offset, self.max_bucket - total)
+            out.append((r, n))
+            total += n
+        return out
+
+    def assemble(self, xs: list[np.ndarray]):
+        """(step args, rows, bucket rows, tokens, bucket tokens)."""
+        total = sum(x.shape[0] for x in xs)
+        bucket = bucket_for(total, self.buckets)
+        x = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
+        if bucket > total:
+            pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
+            x = np.concatenate([x, pad], axis=0)
+        return (x,), total, bucket, 0, 0
+
+
+class TokenBuckets:
+    """Token sequences, right-padded into one of a fixed set of step
+    shapes: for each length ``L`` of ``lengths``, ``step_tokens // L``
+    sequences of ``L`` tokens.  ``step(tokens, lengths)`` gets the padded
+    int32 (batch, L) tokens and each row's real length (0 for a padding
+    row).
+
+    A request's payload is an int (n, L) array: n sequences of L tokens.
+    The first queued request sets the step's length (the shortest ``L``
+    that holds it); later ones no longer than that fill the remaining
+    rows in order, splitting the last across steps.
+    """
+
+    def __init__(self, lengths: tuple[int, ...], step_tokens: int):
+        if any(step_tokens < L or step_tokens % L for L in lengths):
+            raise ValueError(f"step_tokens={step_tokens} must be a "
+                             f"multiple of every length in {lengths}")
+        self.lengths = tuple(sorted(lengths))
+        self.step_tokens = step_tokens
+
+    @property
+    def shapes(self) -> tuple[tuple[int, int], ...]:
+        """Every (batch, length) a step can have."""
+        return tuple((self.step_tokens // L, L) for L in self.lengths)
+
+    def shape_for(self, length: int) -> tuple[int, int]:
+        """(batch, length) of the step that holds a sequence of
+        ``length`` tokens."""
+        if not 0 < length <= self.lengths[-1]:
+            raise ValueError(f"a sequence of {length} tokens does not fit "
+                             f"the longest step length {self.lengths[-1]}")
+        L = next(L for L in self.lengths if length <= L)
+        return self.step_tokens // L, L
+
+    def take(self, pending) -> list[tuple["AsyncRequest", int]]:
+        batch, L = self.shape_for(np.shape(pending[0].payload)[1])
+        out, total = [], 0
+        for r in pending:
+            if total >= batch:
+                break
+            if np.shape(r.payload)[1] <= L:
+                n = min(r.size - r.offset, batch - total)
+                out.append((r, n))
+                total += n
+        return out
+
+    def assemble(self, xs: list[np.ndarray]):
+        batch, L = self.shape_for(max(x.shape[1] for x in xs))
+        tokens = np.zeros((batch, L), np.int32)
+        lengths = np.zeros((batch,), np.int32)
+        row = 0
+        for x in xs:
+            tokens[row:row + x.shape[0], :x.shape[1]] = x
+            lengths[row:row + x.shape[0]] = x.shape[1]
+            row += x.shape[0]
+        return ((tokens, lengths), row, batch, int(lengths.sum()),
+                batch * L)
+
+
 class ContinuousScheduler:
     """The continuous-batching loop behind ``ServingEngine.serve()``.
 
     Args:
-      step: ``step(x) -> tuple[per-sample arrays]`` on a bucket-padded
-        batch; must block until results are ready (its wall time is the
-        compute measurement and the estimator update).
+      step: ``step(*args) -> tuple[per-sample arrays]`` on a padded batch
+        (``args`` as the batching assembles them); must block until
+        results are ready (its wall time is the compute measurement and
+        the estimator update).
       max_bucket / min_bucket: the power-of-two bucket ladder (identical
         to the sync scheduler's, so compiles are shared).
+      batching: how a step takes and pads samples; None =
+        :class:`RowBuckets` over the ladder.  Admission estimates assume
+        the ladder.
       slo: :class:`SLOConfig`; None = defaults (large queue, no implicit
         deadlines).
       estimator: per-bucket step-time estimates for admission control
@@ -161,10 +265,13 @@ class ContinuousScheduler:
     def __init__(self, step: Callable, *, max_bucket: int = 256,
                  min_bucket: int = 8, slo: SLOConfig | None = None,
                  estimator=None, monitor=None,
-                 timer: Callable[[], float] = time.perf_counter):
+                 timer: Callable[[], float] = time.perf_counter,
+                 batching=None):
         self.buckets = power_of_two_buckets(
             min(min_bucket, max_bucket), max_bucket)
         self.max_bucket = max_bucket
+        self.batching = (batching if batching is not None
+                         else RowBuckets(self.buckets))
         self.slo = slo if slo is not None else SLOConfig()
         self.estimator = estimator
         self.monitor = monitor
@@ -301,17 +408,13 @@ class ContinuousScheduler:
     def _form_batch_locked(self, now: float):
         """One scheduling decision: (batch slices, expired requests).
 
-        ``batch`` is a list of ``(request, lo, hi)`` payload row slices
-        totalling <= max_bucket, packed **densely**: requests are taken
-        in sort order and the one straddling the bucket boundary is
-        split — its head rows fill this step, the rest stays queued
-        (front of its priority class) for the next step.  Oversize
-        requests fall out of the same rule as max-bucket chunks.  Dense
-        packing is what makes the continuous path's steady-state
-        samples/step match the sync facade's instead of padding away
-        ~half of each bucket on ragged sizes.  Requests whose deadline
-        can no longer be met even if launched immediately are pulled out
-        as ``expired``.
+        ``batch`` is a list of ``(request, lo, hi)`` payload row slices,
+        as the batching takes them (:class:`RowBuckets` packs them
+        **densely**, which is what makes the continuous path's
+        steady-state samples/step match the sync facade's instead of
+        padding away ~half of each bucket on ragged sizes).  Requests
+        whose deadline can no longer be met even if launched immediately
+        are pulled out as ``expired``.
         """
         expired: list[AsyncRequest] = []
         if self._deadline_pending:
@@ -342,15 +445,11 @@ class ContinuousScheduler:
             if expired:
                 self._pending = keep
         batch: list[tuple[AsyncRequest, int, int]] = []
-        total = 0
-        for r in self._pending:
-            if total >= self.max_bucket:
-                break
-            take = min(r.size - r.offset, self.max_bucket - total)
+        for r, take in (self.batching.take(self._pending)
+                        if self._pending else ()):
             batch.append((r, r.offset, r.offset + take))
             r.offset += take
             self._queued_samples -= take
-            total += take
         if batch:
             still: list[AsyncRequest] = []
             for r in self._pending:
@@ -391,14 +490,11 @@ class ContinuousScheduler:
                     if r.t_start == 0.0:  # first launch only: no restart
                         r.t_start = t_start
                 xs = [np.asarray(r.payload)[lo:hi] for r, lo, hi in batch]
-                total = sum(x.shape[0] for x in xs)
-                bucket = bucket_for(total, self.buckets)
-                x = np.concatenate(xs, axis=0) if len(xs) > 1 else xs[0]
-                if bucket > total:
-                    pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
-                    x = np.concatenate([x, pad], axis=0)
+                args, total, bucket, tokens, bucket_tokens = \
+                    self.batching.assemble(xs)
                 rec.rows, rec.bucket, rec.requests = total, bucket, len(batch)
-            outs = self._step(x)
+                rec.tokens, rec.bucket_tokens = tokens, bucket_tokens
+            outs = self._step(*args)
             with steplog.phase("resolve"):
                 self._resolve(batch, bucket, outs, t_start)
         return total
@@ -508,7 +604,7 @@ class ContinuousScheduler:
 
 
 __all__ = [
-    "AsyncRequest", "ContinuousScheduler", "QueueFull", "SLOConfig",
-    "ServeResult", "SHED_ADMISSION", "SHED_EXPIRED", "SHED_LATE",
+    "AsyncRequest", "ContinuousScheduler", "QueueFull", "RowBuckets",
+    "SLOConfig", "ServeResult", "TokenBuckets", "SHED_ADMISSION", "SHED_EXPIRED", "SHED_LATE",
     "SHED_SHUTDOWN",
 ]

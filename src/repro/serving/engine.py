@@ -14,7 +14,13 @@ SSM / LRU caches) one request per step, through the same queue and the
 same per-request queue/compute latency accounting.  With ``dwn_head=``
 an LM engine *also* serves a packed DWN classification head on its own
 backbone's pooled features (``classify`` requests), so one process
-serves LM decode and DWN classification side by side.
+serves LM decode and DWN classification side by side.  Classify requests
+go through the continuous-batching loop: prompts are right-padded into
+one of a few (batch, length) step shapes of ``step_tokens`` tokens
+(``continuous.TokenBuckets``), and one jitted step runs the backbone,
+pools each prompt's features over its real tokens, runs the head on the
+DWN serving backend and packs the answer (counts, predictions and
+features) into one buffer.
 
 Two serving modes share the datapath and its compile/autotune caches:
 
@@ -60,9 +66,11 @@ from ..launch.mesh import make_data_mesh, make_host_mesh
 from .backends import (AutoSelector, BoundBackend, DWNModelBundle,
                        StepTimeEstimator, available_backends,
                        estimator_from_calibration, get_backend,
-                       time_backend_step, verify_backends)
+                       pack_answer, time_backend_step, unpack_answer,
+                       verify_backends)
 from . import steplog
-from .continuous import AsyncRequest, ContinuousScheduler, SLOConfig
+from .continuous import (AsyncRequest, ContinuousScheduler, SLOConfig,
+                         TokenBuckets)
 from .scheduler import MicrobatchScheduler, Request, latency_stats
 
 
@@ -82,7 +90,9 @@ class ServingEngine:
         batch bucket at startup and serves each bucket on the fastest
         (see ``backends.AutoSelector``); explicit names remain the
         override.
-      max_bucket / min_bucket: the power-of-two batch-bucket ladder.
+      max_bucket / min_bucket: the power-of-two batch-bucket ladder (LM
+        engines with a ``dwn_head``: the ladder of classify prompt
+        lengths).
       data_parallel: shard DWN buckets over the ("data",) host mesh with
         ``shard_map`` (buckets not divisible by the device count fall back
         to single-device execution for that bucket).
@@ -100,11 +110,16 @@ class ServingEngine:
       n_train: training rows (of the spec's workload) used to fit
         thermometer thresholds.
       prompt_len / gen / model_parallel: LM serving shape knobs.
+      params: LM engines: the backbone's weights (a pytree in the
+        family's layout); None = initialised from ``seed``.
       dwn_head: LM engines only — attach a packed DWN classification
         head on the backbone's pooled features (a ``DWNArtifact``, a
         checkpoint path, or a spec-preset name like ``"dwn-lm-head"``).
-        ``classify`` requests then route through the same queue as LM
-        decode: one engine, both request kinds, one process.
+        ``classify`` requests then go through the continuous-batching
+        loop (:meth:`serve` / :meth:`submit_async`) while LM decode
+        keeps the sync queue: one engine, both request kinds, one
+        process.
+      step_tokens: tokens (batch x length) of each padded classify step.
     """
 
     def __init__(self, arch: str | ArchConfig, *,
@@ -114,7 +129,8 @@ class ServingEngine:
                  autotune: bool | None = None,
                  reduced: bool = False, n_train: int = 2000,
                  seed: int = 0, prompt_len: int = 32, gen: int = 16,
-                 model_parallel: int = 1, dwn_head=None):
+                 model_parallel: int = 1, params=None, dwn_head=None,
+                 step_tokens: int = 8192):
         from ..dwn import DWNArtifact, DWNSpec, has_spec, get_spec
         self.artifact: "DWNArtifact | None" = None
         self.spec: "DWNSpec | None" = None
@@ -152,7 +168,6 @@ class ServingEngine:
         self._async_counters: dict = {}
         self.head_artifact = None
         self.head_bit_exact: bool | None = None
-        self._head_served = 0
         #: report()'s "steps" block covers the steps recorded from here on
         self._step_mark = steplog.mark()
         if self.family == "dwn":
@@ -163,9 +178,9 @@ class ServingEngine:
         else:
             if reduced:
                 self.cfg = cfg = cfg.reduced()
-            self._init_lm(cfg, prompt_len, gen, model_parallel)
+            self._init_lm(cfg, prompt_len, gen, model_parallel, params)
             if dwn_head is not None:
-                self._init_dwn_head(dwn_head, verify)
+                self._init_dwn_head(dwn_head, verify, step_tokens)
 
     # ------------------------------------------------------------------
     # DWN classification path
@@ -291,7 +306,13 @@ class ServingEngine:
         streams may still hit other ladder buckets inside timing — bounded
         by one compile per bucket.
         """
-        assert self.family == "dwn"
+        if self.family == "lm":
+            assert self.head_artifact is not None, \
+                "warmup compiles served steps: DWN archs or a dwn_head"
+            for batch, length in self.head_buckets.shapes:
+                self._classify_step(np.zeros((batch, length), np.int32),
+                                    np.full((batch,), length, np.int32))
+            return
         if size is None:
             bucket = self.scheduler.max_bucket
         else:
@@ -324,7 +345,7 @@ class ServingEngine:
     # ------------------------------------------------------------------
 
     def _init_lm(self, cfg: ArchConfig, prompt_len: int, gen: int,
-                 model_parallel: int):
+                 model_parallel: int, params=None):
         self.prompt_len, self.gen = prompt_len, gen
         self.mesh = make_host_mesh(model_parallel)
         tp = self.mesh.shape["model"]
@@ -337,6 +358,9 @@ class ServingEngine:
         self._jdecode = jax.jit(decode, in_shardings=(p_shard, None, None),
                                 donate_argnums=(1,))
         self.tp = tp
+        if params is not None:
+            self.params = jax.device_put(params, p_shard)
+            return
         mod = api.module_for(cfg)
         key = jax.random.PRNGKey(self.seed)
         with self.mesh:
@@ -347,19 +371,18 @@ class ServingEngine:
     # DWN head on the LM backbone (dwn_head=)
     # ------------------------------------------------------------------
 
-    def _init_dwn_head(self, head, verify: bool) -> None:
+    def _init_dwn_head(self, head, verify: bool, step_tokens: int) -> None:
         """Attach a packed DWN classification head on this engine's own
         backbone: pooled-feature extraction (``workloads.lm_head.
-        pool_features`` — the same pooling the head trained on) feeds
-        ``apply_hard_packed`` of the head artifact.  ``classify``
-        requests then serve through the same queue/drain as LM decode.
+        pool_features`` over each prompt's real tokens — the pooling the
+        head trained on) feeds the head on its spec's serving backend.
+        ``classify`` requests then serve through the continuous loop.
         """
         from pathlib import Path
 
-        from ..core.model import apply_hard, apply_hard_packed
-        from ..core.classifier import predict
+        from ..core.model import apply_hard
         from ..dwn import DWNArtifact, resolve_spec
-        from ..workloads.lm_head import pool_features
+        from ..workloads.lm_head import FEATS, pool_features
         if isinstance(head, DWNArtifact):
             art = head
         elif Path(str(head)).exists():
@@ -375,49 +398,66 @@ class ServingEngine:
             art.freeze()
         art.pack()
         self.head_artifact = art
+        self.head_buckets = TokenBuckets(self.scheduler.buckets, step_tokens)
         cfg, tp = self.cfg, self.tp
-        mod = api.module_for(cfg)
-        frozen = art.frozen
+        head_fn = get_backend(art.spec.datapath).make_step(
+            art.serving_model())
+        self._classify_compiles = 0
 
-        @jax.jit
-        def head_step(params, toks):
-            logits, _, _ = mod.forward(params, cfg, {"tokens": toks}, tp=tp)
-            feats = pool_features(logits)
-            counts = apply_hard_packed(frozen, feats)
-            return feats, counts, predict(counts)
+        def traced_classify(params, tokens, lengths):
+            # the python bodies run once per XLA trace: count them
+            self._classify_compiles += 1
+            cols = api.logit_columns(params, cfg, tokens, FEATS, tp=tp)
+            return pool_features(cols, lengths)
 
-        self._jhead = head_step
+        def classify_head(feats):
+            self._classify_compiles += 1
+            with jax.named_scope("dwn_head"):
+                counts, pred = head_fn(feats)
+            self._head_answer = (counts.shape[-1], counts.dtype, pred.dtype,
+                                 ((FEATS, feats.dtype),))
+            return pack_answer(counts, pred, feats)
+
+        # Two programs a step: the backbone (module "jit_traced_classify",
+        # as a profiler trace names it) takes every weight as an argument,
+        # so its compiled form does not depend on them and the persistent
+        # compile cache serves it to any later process; the head's tables
+        # are constants of its own small program.
+        self._jclassify = jax.jit(traced_classify)
+        self._jhead = jax.jit(classify_head)
         if verify:
-            # startup cross-check: the packed head must agree bit-exactly
-            # with the float oracle on this backbone's real features
+            # startup cross-check on the shortest step shape: the served
+            # head must agree bit-exactly with the float oracle on this
+            # backbone's real features
+            batch, length = self.head_buckets.shapes[0]
             rng = np.random.default_rng(self.seed)
-            toks = jnp.asarray(rng.integers(
-                0, cfg.vocab_size, (8, self.prompt_len)).astype(np.int32))
-            with self.mesh:
-                feats, counts, _ = self._jhead(self.params, toks)
-            oracle = np.asarray(apply_hard(frozen, feats))
-            self.head_bit_exact = bool(
-                np.array_equal(np.asarray(counts), oracle))
+            toks = rng.integers(0, cfg.vocab_size, (batch, length))
+            lens = rng.integers(1, length + 1, batch)
+            counts, _, feats = self._classify_step(toks.astype(np.int32),
+                                                   lens.astype(np.int32))
+            oracle = np.asarray(apply_hard(art.frozen, jnp.asarray(feats)))
+            self.head_bit_exact = bool(np.array_equal(counts, oracle))
             assert self.head_bit_exact, \
-                "packed DWN head disagrees with the apply_hard oracle"
+                "served DWN head disagrees with the apply_hard oracle"
 
-    def _head_step(self, batch: dict) -> dict:
-        """Serve one classify request: tokens -> backbone features ->
-        packed DWN head (counts + predictions)."""
-        assert self.head_artifact is not None, \
-            "no DWN head attached: construct with dwn_head=..."
-        toks = jnp.asarray(batch["tokens"])
-        with self.mesh:
-            feats, counts, pred = self._jhead(self.params, toks)
-        pred.block_until_ready()
-        self._head_served += int(toks.shape[0])
-        return {"counts": np.asarray(counts), "pred": np.asarray(pred),
-                "features": np.asarray(feats)}
-
-    def _lm_or_head_step(self, batch: dict) -> dict:
-        if isinstance(batch, dict) and batch.get("classify"):
-            return self._head_step(batch)
-        return self._lm_step(batch)
+    def _classify_step(self, tokens: np.ndarray, lengths: np.ndarray):
+        """One classify step on right-padded ``tokens (B, L)`` with real
+        ``lengths (B,)``: backbone -> pooled features -> DWN head.
+        Returns per-row ``(counts, pred, features)``; the answer comes
+        back in one device-to-host copy issued at dispatch."""
+        with steplog.phase("h2d"):
+            tokens_d, lengths_d = jnp.asarray(tokens), jnp.asarray(lengths)
+        with steplog.phase("dispatch"):
+            before = self._classify_compiles
+            answer = self._jhead(
+                self._jclassify(self.params, tokens_d, lengths_d))
+            answer.copy_to_host_async()
+            steplog.add_compiles(self._classify_compiles - before)
+            steplog.add_d2h_copies(1)
+        with steplog.phase("device"):
+            answer.block_until_ready()
+        with steplog.phase("d2h"):
+            return unpack_answer(np.asarray(answer), 1, *self._head_answer)
 
     def _lm_step(self, batch: dict) -> dict:
         cfg = self.cfg
@@ -455,9 +495,9 @@ class ServingEngine:
           size: samples (DWN: feature rows drawn from the test split) or
             sequences (LM: random token prompts of ``prompt_len``).
           seed: draw seed, so streams are reproducible.
-          classify: LM engines with a ``dwn_head``: mark the request for
-            the DWN head (tokens -> pooled features -> packed classify)
-            instead of prefill/decode.
+          classify: LM engines with a ``dwn_head``: a request for the
+            DWN head (tokens -> pooled features -> packed classify), to
+            pass to :meth:`submit_async`.
 
         Returns the payload in the shape :meth:`submit` expects.
         """
@@ -490,7 +530,8 @@ class ServingEngine:
 
         Args:
           payload: (size, F) feature array (DWN) or an LM batch dict with
-            a (size, prompt_len) ``tokens`` entry.
+            a (size, prompt_len) ``tokens`` entry.  Classify requests go
+            through :meth:`submit_async`.
 
         Returns the queued :class:`Request` (latency fields filled in by
         the drain that serves it; ``queue_ms``/``compute_ms`` are
@@ -499,6 +540,10 @@ class ServingEngine:
         if self.family == "dwn":
             payload = np.asarray(payload)
             return self.scheduler.submit(payload, payload.shape[0])
+        if payload.get("classify"):
+            raise ValueError("classify requests are served by the "
+                             "continuous loop: engine.serve() and "
+                             "submit_async()")
         size = int(np.asarray(payload["tokens"]).shape[0])
         return self.scheduler.submit(payload, size)
 
@@ -508,11 +553,10 @@ class ServingEngine:
         if self.family == "dwn":
             done = self.scheduler.drain_batched(self._monitored_step)
         else:
-            done = self.scheduler.drain_serial(self._lm_or_head_step)
+            done = self.scheduler.drain_serial(self._lm_step)
             self._lm_stats.extend((r.result["prefill_s"],
                                    r.result["decode_s_per_tok"])
-                                  for r in done
-                                  if "prefill_s" in r.result)
+                                  for r in done)
         self._drain_wall += time.perf_counter() - t0
         return done
 
@@ -526,7 +570,7 @@ class ServingEngine:
         return out
 
     # ------------------------------------------------------------------
-    # continuous-batching async API (DWN only)
+    # continuous-batching async API (DWN classification, and the DWN head)
     # ------------------------------------------------------------------
 
     def start_serving(self, *, slo: SLOConfig | None = None) -> None:
@@ -538,10 +582,20 @@ class ServingEngine:
         shared with the sync facade).  Admission control's step-time
         estimates seed from the ``AutoSelector`` calibration when
         ``backend="auto"``, else from one probe of the active backend at
-        ``max_bucket``; every step refines them online.
+        ``max_bucket``; every step refines them online.  An LM engine
+        serves its ``dwn_head``'s classify requests here, in the
+        (batch, length) step shapes of ``head_buckets``; deadlines are
+        enforced at expiry and completion, with no admission estimate.
         """
-        assert self.family == "dwn", "continuous batching is the DWN path"
         assert self._cont is None, "serving loop already running"
+        if self.family == "lm":
+            assert self.head_artifact is not None, \
+                "an LM engine serves classify requests: pass dwn_head="
+            self._cont = ContinuousScheduler(
+                self._classify_step, slo=slo, monitor=self.straggler,
+                batching=self.head_buckets)
+            self._cont.start()
+            return
         if self.estimator is None:
             if self.auto is not None:
                 self.estimator = estimator_from_calibration(self.auto)
@@ -592,9 +646,23 @@ class ServingEngine:
         typed shed when the deadline was unmeetable (admission), expired
         in queue, or missed at completion.  Raises ``QueueFull`` after
         ``timeout`` when backpressure applies.
+
+        LM engines with a ``dwn_head`` take classify requests: an int
+        (n, L) token array (or a dict with it under ``tokens``), n
+        prompts of L tokens each; ``value == (counts, pred, features)``.
         """
         assert self._cont is not None, \
             "submit_async needs the serving loop: use engine.serve()"
+        if self.family == "lm":
+            if isinstance(payload, dict):
+                payload = payload["tokens"]
+            payload = np.asarray(payload)
+            if payload.ndim != 2 or payload.dtype.kind not in "iu":
+                raise ValueError(f"a classify request is an int (n, L) "
+                                 f"token array, got {payload.dtype} "
+                                 f"{payload.shape}")
+            self.head_buckets.shape_for(payload.shape[1])
+            payload = payload.astype(np.int32)
         payload = np.asarray(payload)
         return self._cont.submit(payload, payload.shape[0],
                                  deadline_ms=deadline_ms,
@@ -723,13 +791,14 @@ class ServingEngine:
                 out["decode_s_per_tok"] = round(
                     float(np.mean([s[1] for s in self._lm_stats])), 4)
             if self.head_artifact is not None:
+                out["steps"] = self._steps_summary()
                 out["dwn_head"] = {
                     "spec": self.head_artifact.spec.to_dict(),
                     "spec_fingerprint":
                         self.head_artifact.spec.fingerprint(),
                     "artifact_stage": self.head_artifact.stage,
                     "bit_exact_vs_oracle": self.head_bit_exact,
-                    "served": self._head_served,
+                    "served": sum(r.size for r in async_ok),
                 }
         return out
 

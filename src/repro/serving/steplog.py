@@ -28,8 +28,11 @@ and lands on the device trace's clock; otherwise a span costs one
 The same code records each step into one process-wide :class:`Ring` of
 preallocated arrays (:data:`CAPACITY` steps): the phase times from
 ``time.perf_counter_ns``, the real rows launched, the bucket, the
-requests in the batch, the compiles its dispatch triggered, and
-``d2h_copies``, the device-to-host transfers the step issued (one).
+requests in the batch, the compiles its dispatch triggered,
+``d2h_copies``, the device-to-host transfers the step issued (one), and,
+for a step over token sequences (the DWN head's classify step),
+``tokens`` (real prompt tokens) and ``bucket_tokens`` (batch x length of
+the padded step); both are 0 for a step over feature rows.
 ``last(n)`` reads the newest ``n`` records; ``mark()`` / ``since(mark)``
 read what came after a point.  Recording is always on and writes nothing
 to disk.
@@ -71,6 +74,8 @@ class Records:
     requests: np.ndarray      # request slices in the batch
     compiles: np.ndarray      # XLA traces the dispatch took
     d2h_copies: np.ndarray    # device-to-host transfers issued
+    tokens: np.ndarray        # real tokens of a step over sequences
+    bucket_tokens: np.ndarray  # its padded batch x length
 
     def __len__(self) -> int:
         return len(self.step_ns)
@@ -83,10 +88,17 @@ class Records:
         """Real rows over padded bucket rows, in percent."""
         return float(self.rows.sum() / self.bucket.sum() * 100.0)
 
+    def token_occupancy_pct(self) -> float | None:
+        """Real tokens over padded bucket tokens, in percent; None where
+        no step ran over token sequences."""
+        total = self.bucket_tokens.sum()
+        return float(self.tokens.sum() / total * 100.0) if total else None
+
     def summary(self) -> dict:
         """Mean and p99 milliseconds of the step and of each phase, the
-        occupancy, the compiles, the device-to-host copies and the count
-        of steps covered."""
+        occupancy (and the token occupancy of steps over sequences), the
+        compiles, the device-to-host copies and the count of steps
+        covered."""
         if not len(self):
             return {"count": 0}
         ms = {"step": self.step_ns / 1e6}
@@ -99,7 +111,8 @@ class Records:
             "occupancy_pct": round(self.occupancy_pct(), 3),
             "compiles": int(self.compiles.sum()),
             "d2h_copies": int(self.d2h_copies.sum()),
-        }
+        } | ({} if self.token_occupancy_pct() is None else
+             {"token_occupancy_pct": round(self.token_occupancy_pct(), 3)})
 
 
 class Ring:
@@ -108,19 +121,21 @@ class Ring:
     def __init__(self, capacity: int = CAPACITY):
         self.capacity = capacity
         #: per step: the phases' ns, the step's ns, rows, bucket,
-        #: requests, compiles, d2h_copies
-        self._data = np.zeros((capacity, len(PHASES) + 6), np.int64)
+        #: requests, compiles, d2h_copies, tokens, bucket_tokens
+        self._data = np.zeros((capacity, len(PHASES) + 8), np.int64)
         self._n = 0
         self._lock = threading.Lock()
 
     def record(self, phase_ns, step_ns: int, rows: int, bucket: int,
-               requests: int, compiles: int, d2h_copies: int = 0) -> int:
+               requests: int, compiles: int, d2h_copies: int = 0,
+               tokens: int = 0, bucket_tokens: int = 0) -> int:
         """Store one step; returns its sequence number."""
         with self._lock:
             seq = self._n
             self._data[seq % self.capacity] = (*phase_ns, step_ns, rows,
                                                bucket, requests, compiles,
-                                               d2h_copies)
+                                               d2h_copies, tokens,
+                                               bucket_tokens)
             self._n = seq + 1
         return seq
 
@@ -203,18 +218,19 @@ class phase(span):
 
 class step(span):
     """One served step: the ``serve.step`` span, and its record in the
-    ring.  The scheduler sets ``rows``, ``bucket`` and ``requests`` once
-    the batch is formed; a step that launched no rows, or raised, is not
-    recorded.  What runs after the last phase counts as resolution, so
-    the phases sum to the step."""
+    ring.  The scheduler sets ``rows``, ``bucket``, ``requests`` and, for
+    token sequences, ``tokens`` and ``bucket_tokens`` once the batch is
+    formed; a step that launched no rows, or raised, is not recorded.
+    What runs after the last phase counts as resolution, so the phases
+    sum to the step."""
 
     __slots__ = ("rows", "bucket", "requests", "compiles", "d2h_copies",
-                 "_ns", "_t0", "_t")
+                 "tokens", "bucket_tokens", "_ns", "_t0", "_t")
 
     def __init__(self):
         self.name = "serve.step"
         self.rows = self.bucket = self.requests = self.compiles = 0
-        self.d2h_copies = 0
+        self.d2h_copies = self.tokens = self.bucket_tokens = 0
 
     def __enter__(self):
         super().__enter__()
@@ -234,7 +250,8 @@ class step(span):
             self.end_phase(_RESOLVE)
             seq = RING.record(self._ns, self._t - self._t0, self.rows,
                               self.bucket, self.requests, self.compiles,
-                              self.d2h_copies)
+                              self.d2h_copies, self.tokens,
+                              self.bucket_tokens)
             if self._tm is not None:
                 self._tm.set_metadata(step=seq, rows=self.rows,
                                       bucket=self.bucket,

@@ -39,15 +39,26 @@ LM_HEAD_PRESETS = {
 }
 
 
-def pool_features(logits):
+def pool_features(logits, lengths=None):
     """Pool full-sequence backbone logits into FEATS head features.
 
-    ``tanh(0.3 * mean-over-sequence logits[:, :FEATS])`` — identical to
-    the original demo, and shared by the loader and the serving engine's
-    ``dwn_head`` path so training and serving see the same features.
+    ``tanh(0.3 * mean-over-sequence logits[:, :FEATS])``, the mean taken
+    over each sequence's first ``lengths[b]`` (real) positions; None: all
+    of them.  ``logits`` (B, S, V >= FEATS) may hold only the first FEATS
+    columns.  Shared by the loader and the serving engine's ``dwn_head``
+    path so training and serving see the same features; right padding
+    does not change a sequence's features.  The mean is summed in float32
+    and rounded to the logits' type, as ``jnp.mean`` rounds it.
     """
     import jax.numpy as jnp
-    pooled = logits.mean(axis=1)[:, :FEATS].astype(jnp.float32)
+    B, S = logits.shape[:2]
+    cols = logits[..., :FEATS]
+    if lengths is None:
+        lengths = jnp.full((B,), S, jnp.int32)
+    real = jnp.arange(S)[None, :] < lengths[:, None]
+    total = jnp.where(real[..., None], cols.astype(jnp.float32), 0.0).sum(1)
+    mean = total / jnp.maximum(lengths, 1)[:, None].astype(jnp.float32)
+    pooled = mean.astype(logits.dtype).astype(jnp.float32)
     return jnp.tanh(pooled * 0.3)
 
 
@@ -67,8 +78,8 @@ def _backbone():
 
         @jax.jit
         def features(toks):
-            logits, _, _ = mod.forward(params, cfg, {"tokens": toks}, tp=1)
-            return pool_features(logits)
+            return pool_features(
+                api.logit_columns(params, cfg, toks, FEATS, tp=1))
 
         # fixed teacher projection: labels = argmax(features @ Wt)
         Wt = jax.random.normal(jax.random.PRNGKey(7),

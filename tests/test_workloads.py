@@ -249,16 +249,19 @@ def test_one_engine_serves_lm_decode_and_dwn_head():
     art.freeze().pack()
 
     engine = ServingEngine("qwen3-8b", reduced=True, prompt_len=8, gen=2,
-                           seed=0, dwn_head=art)
+                           seed=0, dwn_head=art, max_bucket=8,
+                           step_tokens=32)
     assert engine.head_bit_exact is True          # startup oracle gate
     engine.submit(engine.make_request(2, seed=0))                 # LM
-    engine.submit(engine.make_request(4, seed=1, classify=True))  # head
     done = engine.drain()
-    kinds = {"head" if "pred" in r.result else "lm" for r in done}
-    assert kinds == {"lm", "head"}
-    head = next(r for r in done if "pred" in r.result)
-    assert head.result["pred"].shape == (4,)
-    assert head.result["counts"].shape == (4, 5)
+    assert done[0].result["tokens"].shape == (2, 2)
+    with engine.serve():                                          # head
+        head = engine.submit_async(
+            engine.make_request(4, seed=1, classify=True)).future.result()
+    counts, pred, feats = head.value
+    assert pred.shape == (4,)
+    assert counts.shape == (4, 5)
+    assert feats.shape == (4, 16)
     rep = engine.report()
     assert rep["dwn_head"]["bit_exact_vs_oracle"] is True
     assert rep["dwn_head"]["served"] == 4
