@@ -10,9 +10,9 @@ Layering (each importable on its own):
     scheduler.py   admission-order request queue, power-of-two batch
                    buckets, per-request queue/compute latency accounting
                    (the synchronous submit/drain facade)
-    continuous.py  continuous-batching loop: scheduler thread, futures,
-                   SLO-aware admission + deadline shedding, bounded-queue
-                   backpressure
+    continuous.py  continuous-batching loop: scheduler thread with two
+                   steps in flight, futures, SLO-aware admission +
+                   deadline shedding, bounded-queue backpressure
     steplog.py     phase spans of each served step on the profiler's
                    clock + a process-wide ring of per-step records
     engine.py      ServingEngine: sync submit/drain AND async

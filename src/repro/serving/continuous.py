@@ -18,7 +18,7 @@ Design:
 * **Oversize chunking without clock restarts.**  A request larger than
   ``max_bucket`` is served in max-bucket chunks across consecutive
   steps; its queue time is attributed from the *original submit* to the
-  *first* chunk launch, and its future resolves once after the last
+  *first* chunk's start, and its future resolves once after the last
   chunk.
 * **SLO-aware admission.**  A request may declare a deadline.  Admission
   rejects (types the result as shed, never raises) work that provably
@@ -35,16 +35,43 @@ Design:
   so an open-loop producer feels the engine's capacity instead of
   growing an unbounded heap.
 
+* **Two steps in flight.**  A step comes in two halves:
+  ``launch(*args) -> handle`` copies the padded batch in and dispatches
+  it, returning while the device works;
+  ``collect(handle)`` waits for the device and the answer's copy back
+  and returns the per-sample arrays.  After launching step N the loop
+  looks at the queue: if it holds work, it forms and launches step N+1
+  *before* collecting and resolving N, so that N+1's batch formation,
+  copy in and dispatch run while the device computes N; if the queue is
+  empty it collects N at once, so a lone request on an idle loop waits
+  no longer than a serial step.  The depth is fixed at two.  Steps are
+  collected in launch order, so a request's chunks are appended in
+  order; a request resolves with the step that held its last rows.
+  A request's ``t_start`` is the start of its first step as ``busy_s``
+  counts it (below), so a step launched behind a held one starts when
+  the held one completes, as in the serial loop.
+  ``stop(drain=True)`` collects the held step before the loop ends, and
+  an exception in launching N+1 still collects and resolves N.
+
 The loop is model-agnostic: a *batching* decides which queued samples
-a step takes and how they are padded, and ``step(*args)`` runs the padded
-batch and returns a tuple of per-sample result arrays, exactly the
-``drain_batched`` contract.  :class:`RowBuckets` (the default) packs
-feature rows densely along axis 0 into the power-of-two ladder;
-:class:`TokenBuckets` right-pads token sequences into one of a few
-(batch, length) shapes of equal token count and passes the real lengths
-along.  ``step_once()`` runs one scheduling decision plus one step
-synchronously — the unit tests drive it without threads, so ordering
-assertions are deterministic.
+a step takes and how they are padded, and the two halves run the padded
+batch and return a tuple of per-sample result arrays.
+:class:`RowBuckets` (the default) packs feature rows densely along axis
+0 into the power-of-two ladder; :class:`TokenBuckets` right-pads token
+sequences into one of a few (batch, length) shapes of equal token count
+and passes the real lengths along.  ``step_once()`` runs one scheduling
+decision plus one launch and collect synchronously, with no overlap —
+the unit tests drive it without threads, so ordering assertions are
+deterministic.
+
+Each step is one ``steplog`` record: its phases, timed into that step's
+own record even while the other step's phases run between them, and
+``overlapped`` (1 where it was launched before its predecessor was
+collected).  ``busy_s``, and the step time the estimator and the
+straggler monitor see, count each step from the later of its launch and
+the previous step's completion to its own completion, so two steps in
+flight are never counted twice and ``busy_s / steps`` is the loop's time
+per step.
 """
 
 from __future__ import annotations
@@ -143,7 +170,7 @@ class AsyncRequest(Request):
 
 class RowBuckets:
     """Feature rows, packed densely along axis 0 and padded to the
-    power-of-two bucket ladder; ``step(x)`` gets the (bucket, ...) array.
+    power-of-two bucket ladder; ``launch(x)`` gets the (bucket, ...) array.
 
     Requests are taken in sort order and the one straddling the bucket
     boundary is split: its head rows fill this step, the rest stays
@@ -180,7 +207,7 @@ class RowBuckets:
 class TokenBuckets:
     """Token sequences, right-padded into one of a fixed set of step
     shapes: for each length ``L`` of ``lengths``, ``step_tokens // L``
-    sequences of ``L`` tokens.  ``step(tokens, lengths)`` gets the padded
+    sequences of ``L`` tokens.  ``launch(tokens, lengths)`` gets the padded
     int32 (batch, L) tokens and each row's real length (0 for a padding
     row).
 
@@ -236,14 +263,26 @@ class TokenBuckets:
                 batch * L)
 
 
+@dataclasses.dataclass
+class _InFlight:
+    """A launched step, not yet collected."""
+    rec: steplog.Step
+    batch: list            # (request, lo, hi) payload row slices
+    bucket: int
+    handle: Any            # what ``launch`` returned
+    t_launch: float
+
+
 class ContinuousScheduler:
     """The continuous-batching loop behind ``ServingEngine.serve()``.
 
     Args:
-      step: ``step(*args) -> tuple[per-sample arrays]`` on a padded batch
-        (``args`` as the batching assembles them); must block until
-        results are ready (its wall time is the compute measurement and
-        the estimator update).
+      launch: ``launch(*args) -> handle``, the first half of a step on a
+        padded batch (``args`` as the batching assembles them): copy in
+        and dispatch, returning without waiting for the device.
+      collect: ``collect(handle) -> tuple[per-sample arrays]``, the second
+        half: block until the results are ready and bring them to the
+        host.
       max_bucket / min_bucket: the power-of-two bucket ladder (identical
         to the sync scheduler's, so compiles are shared).
       batching: how a step takes and pads samples; None =
@@ -257,12 +296,13 @@ class ContinuousScheduler:
         None disables admission-time shedding (expiry and late marking
         still apply: those need no estimate).
       monitor: optional ``runtime.straggler.StragglerMonitor``; every
-        step's wall time is reported to it (the engine's report shows
-        its anomalies).
+        step's time, as ``busy_s`` counts it, is reported to it (the
+        engine's report shows its anomalies).
       timer: injectable clock (tests use a deterministic one).
     """
 
-    def __init__(self, step: Callable, *, max_bucket: int = 256,
+    def __init__(self, launch: Callable, collect: Callable,
+                 *, max_bucket: int = 256,
                  min_bucket: int = 8, slo: SLOConfig | None = None,
                  estimator=None, monitor=None,
                  timer: Callable[[], float] = time.perf_counter,
@@ -275,7 +315,8 @@ class ContinuousScheduler:
         self.slo = slo if slo is not None else SLOConfig()
         self.estimator = estimator
         self.monitor = monitor
-        self._step = step
+        self._launch_fn = launch
+        self._collect_fn = collect
         self._timer = timer
         # RLock: _finish() takes the lock for the completed/shed counters
         # and is reached both from submit() (admission shed, lock held)
@@ -296,7 +337,10 @@ class ContinuousScheduler:
         self.completed: list[AsyncRequest] = []
         self.shed_counts: dict[str, int] = {}
         self.steps = 0
-        self.busy_s = 0.0            # sum of step wall times
+        #: sum of step times, each from the later of its launch and the
+        #: previous step's completion to its own completion
+        self.busy_s = 0.0
+        self._t_collected = -math.inf  # the last step's completion
         self.session_wall_s = 0.0    # start() -> stop() wall, accumulated
         self.max_depth_samples = 0
         self.max_depth_requests = 0
@@ -464,12 +508,13 @@ class ContinuousScheduler:
         return batch, expired
 
     def step_once(self, *, wait_s: float = 0.0) -> int:
-        """Run one scheduling decision + one device step synchronously.
+        """Run one scheduling decision + one device step synchronously:
+        launch, then collect at once.
 
         Returns the number of samples launched (0 if the queue was empty
-        after waiting ``wait_s``).  The thread loop is just this method
-        on repeat; tests call it directly for deterministic ordering.
-        The step's phases are ``steplog`` spans and one ring record.
+        after waiting ``wait_s``).  Tests call it directly for
+        deterministic ordering.  The step's phases are ``steplog`` spans
+        and one ring record.
         """
         with self._cond:
             if not self._pending and wait_s > 0:
@@ -477,33 +522,65 @@ class ContinuousScheduler:
                     self._cond.wait(wait_s)
             if not self._pending:
                 return 0
-        with steplog.step() as rec:
-            with steplog.phase("batch"):
-                with self._cond:
-                    batch, expired = self._form_batch_locked(self._timer())
-                for r in expired:
-                    self._finish(r, shed=SHED_EXPIRED)
-                if not batch:
-                    return 0
-                t_start = self._timer()
-                for r, _, _ in batch:
-                    if r.t_start == 0.0:  # first launch only: no restart
-                        r.t_start = t_start
-                xs = [np.asarray(r.payload)[lo:hi] for r, lo, hi in batch]
-                args, total, bucket, tokens, bucket_tokens = \
-                    self.batching.assemble(xs)
-                rec.rows, rec.bucket, rec.requests = total, bucket, len(batch)
-                rec.tokens, rec.bucket_tokens = tokens, bucket_tokens
-            outs = self._step(*args)
-            with steplog.phase("resolve"):
-                self._resolve(batch, bucket, outs, t_start)
-        return total
+        step = self._launch()
+        return self._collect(step) if step is not None else 0
 
-    def _resolve(self, batch, bucket: int, outs, t_start: float) -> None:
+    def _launch(self, *, overlapped: bool = False) -> _InFlight | None:
+        """The step's first half: form the next batch, then ``launch`` it.
+        None where the queue held nothing to launch."""
+        rec = steplog.Step()
+        try:
+            with rec:
+                with steplog.phase("batch"):
+                    with self._cond:
+                        batch, expired = self._form_batch_locked(
+                            self._timer())
+                    for r in expired:
+                        self._finish(r, shed=SHED_EXPIRED)
+                    if batch:
+                        t_launch = self._timer()
+                        xs = [np.asarray(r.payload)[lo:hi]
+                              for r, lo, hi in batch]
+                        args, total, bucket, tokens, bucket_tokens = \
+                            self.batching.assemble(xs)
+                        rec.rows, rec.bucket = total, bucket
+                        rec.requests, rec.overlapped = (len(batch),
+                                                        int(overlapped))
+                        rec.tokens, rec.bucket_tokens = (tokens,
+                                                         bucket_tokens)
+                if not batch:
+                    rec.close()
+                    return None
+                handle = self._launch_fn(*args)
+        except BaseException:
+            rec.close(ok=False)
+            raise
+        return _InFlight(rec, batch, bucket, handle, t_launch)
+
+    def _collect(self, step: _InFlight) -> int:
+        """The step's second half: ``collect`` its outputs and resolve its
+        requests.  Returns the samples it served."""
+        try:
+            with step.rec:
+                outs = self._collect_fn(step.handle)
+                with steplog.phase("resolve"):
+                    self._resolve(step.batch, step.bucket, outs,
+                                  step.t_launch)
+        except BaseException:
+            step.rec.close(ok=False)
+            raise
+        step.rec.close()
+        return step.rec.rows
+
+    def _resolve(self, batch, bucket: int, outs, t_launch: float) -> None:
         """Count the step, then hand each request its rows of ``outs``
-        and resolve the futures of those fully served."""
+        and resolve the futures of those whose last rows these were."""
         t_done = self._timer()
-        step_s = t_done - t_start
+        # from the later of its launch and the previous step's completion:
+        # two steps in flight are never counted twice
+        t_begin = max(t_launch, self._t_collected)
+        step_s = t_done - t_begin
+        self._t_collected = t_done
         self.steps += 1
         self.busy_s += step_s
         if self.estimator is not None:
@@ -513,10 +590,14 @@ class ContinuousScheduler:
         off = 0
         for r, lo, hi in batch:
             n = hi - lo
+            if lo == 0:                  # the request's first step
+                r.t_start = t_begin
             r.parts.append(tuple(np.asarray(o)[off:off + n] for o in outs))
             r.buckets = r.buckets + (bucket,)
             off += n
-            if r.offset >= r.size:    # fully served: resolve the future
+            # by the slice, not by r.offset: a step launched since may
+            # already hold the request's next rows
+            if hi == r.size:
                 r.t_done = t_done
                 if len(r.parts) == 1:
                     result = r.parts[0]
@@ -533,12 +614,27 @@ class ContinuousScheduler:
     # ------------------------------------------------------------------
 
     def _loop(self) -> None:
+        held = None          # launched, not yet collected
         while True:
-            with self._lock:
-                idle = not self._pending
-                if self._stopping and idle:
-                    return
-            self.step_once(wait_s=0.002 if idle else 0.0)
+            with self._cond:
+                if held is None and not self._pending:
+                    if self._stopping:
+                        return
+                    with steplog.span("serve.wait"):
+                        self._cond.wait(0.002)
+                    continue
+            # with a step held, launch the next before collecting it, so
+            # that its copy in and dispatch overlap the held step's wait
+            try:
+                nxt = (self._launch(overlapped=held is not None)
+                       if self._pending else None)
+            finally:
+                if held is not None:
+                    self._collect(held)
+            held = nxt
+            if held is not None and not self._pending:
+                self._collect(held)      # nothing queued behind it
+                held = None
 
     def start(self) -> None:
         assert self._thread is None, "already started"
@@ -551,7 +647,8 @@ class ContinuousScheduler:
 
     def stop(self, *, drain: bool = True) -> None:
         """Stop the loop.  ``drain=True`` serves everything queued first;
-        ``drain=False`` sheds queued requests with ``SHED_SHUTDOWN``."""
+        ``drain=False`` sheds queued requests with ``SHED_SHUTDOWN``.
+        Either way every launched step is collected and resolved."""
         assert self._thread is not None, "not started"
         if not drain:
             with self._cond:

@@ -49,9 +49,10 @@ Usage (continuous):
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import os
 import time
-from typing import Any, Sequence
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -72,6 +73,23 @@ from . import steplog
 from .continuous import (AsyncRequest, ContinuousScheduler, SLOConfig,
                          TokenBuckets)
 from .scheduler import MicrobatchScheduler, Request, latency_stats
+
+
+@dataclasses.dataclass(frozen=True)
+class Launched:
+    """A dispatched step: its packed answer, whose copy to the host is
+    already issued, and how to unpack that copy."""
+
+    answer: jax.Array
+    unpack: Callable[[np.ndarray], tuple]
+
+    def collect(self) -> tuple:
+        """The step's second half: wait for the device, then for the
+        answer's copy, and unpack it into per-row arrays."""
+        with steplog.phase("device"):
+            self.answer.block_until_ready()
+        with steplog.phase("d2h"):
+            return self.unpack(np.asarray(self.answer))
 
 
 class ServingEngine:
@@ -320,7 +338,9 @@ class ServingEngine:
                 min(size, self.scheduler.max_bucket))
         self._dwn_step(np.asarray(self.data.x_test[:bucket]))
 
-    def _dwn_step(self, x: np.ndarray):
+    def _dwn_launch(self, x: np.ndarray) -> Launched:
+        """The DWN step's first half on padded rows ``x``: copy in and
+        dispatch, with the answer's copy back issued."""
         bucket = x.shape[0]
         with steplog.phase("h2d"):
             xd = jnp.asarray(x)
@@ -335,10 +355,19 @@ class ServingEngine:
             answer.copy_to_host_async()
             steplog.add_compiles(backend.compiles[bucket] - before)
             steplog.add_d2h_copies(1)
-        with steplog.phase("device"):
-            answer.block_until_ready()       # compute timing is this call
-        with steplog.phase("d2h"):
-            return backend.unpack(np.asarray(answer), bucket)
+        return Launched(answer, lambda buf: backend.unpack(buf, bucket))
+
+    def _dwn_collect(self, launched: Launched):
+        """The DWN step's second half: ``(counts, pred)`` per row."""
+        return launched.collect()
+
+    def _dwn_step(self, x):
+        """One blocking DWN step on padded rows ``x``: ``(counts, pred)``.
+        Given a step :meth:`_dwn_launch` already launched, only its second
+        half: the continuous loop collects through here too, so every DWN
+        answer the engine serves leaves through this method."""
+        return self._dwn_collect(
+            x if isinstance(x, Launched) else self._dwn_launch(x))
 
     # ------------------------------------------------------------------
     # LM prefill/decode path
@@ -440,11 +469,12 @@ class ServingEngine:
             assert self.head_bit_exact, \
                 "served DWN head disagrees with the apply_hard oracle"
 
-    def _classify_step(self, tokens: np.ndarray, lengths: np.ndarray):
-        """One classify step on right-padded ``tokens (B, L)`` with real
-        ``lengths (B,)``: backbone -> pooled features -> DWN head.
-        Returns per-row ``(counts, pred, features)``; the answer comes
-        back in one device-to-host copy issued at dispatch."""
+    def _classify_launch(self, tokens: np.ndarray,
+                         lengths: np.ndarray) -> Launched:
+        """The classify step's first half on right-padded ``tokens (B, L)``
+        with real ``lengths (B,)``: copy in and dispatch backbone ->
+        pooled features -> DWN head, with the answer's one copy back
+        issued."""
         with steplog.phase("h2d"):
             tokens_d, lengths_d = jnp.asarray(tokens), jnp.asarray(lengths)
         with steplog.phase("dispatch"):
@@ -454,10 +484,13 @@ class ServingEngine:
             answer.copy_to_host_async()
             steplog.add_compiles(self._classify_compiles - before)
             steplog.add_d2h_copies(1)
-        with steplog.phase("device"):
-            answer.block_until_ready()
-        with steplog.phase("d2h"):
-            return unpack_answer(np.asarray(answer), 1, *self._head_answer)
+        head = self._head_answer
+        return Launched(answer, lambda buf: unpack_answer(buf, 1, *head))
+
+    def _classify_step(self, tokens: np.ndarray, lengths: np.ndarray):
+        """One blocking classify step: per-row ``(counts, pred,
+        features)``."""
+        return self._classify_launch(tokens, lengths).collect()
 
     def _lm_step(self, batch: dict) -> dict:
         cfg = self.cfg
@@ -592,8 +625,8 @@ class ServingEngine:
             assert self.head_artifact is not None, \
                 "an LM engine serves classify requests: pass dwn_head="
             self._cont = ContinuousScheduler(
-                self._classify_step, slo=slo, monitor=self.straggler,
-                batching=self.head_buckets)
+                self._classify_launch, Launched.collect, slo=slo,
+                monitor=self.straggler, batching=self.head_buckets)
             self._cont.start()
             return
         if self.estimator is None:
@@ -607,7 +640,8 @@ class ServingEngine:
                     self.scheduler.max_bucket,
                     time_backend_step(self.backend, probe, iters=2))
         self._cont = ContinuousScheduler(
-            self._dwn_step, max_bucket=self.scheduler.max_bucket,
+            self._dwn_launch, self._dwn_step,
+            max_bucket=self.scheduler.max_bucket,
             min_bucket=self.scheduler.min_bucket, slo=slo,
             estimator=self.estimator, monitor=self.straggler)
         self._cont.start()
@@ -696,8 +730,9 @@ class ServingEngine:
         ``queue_depth`` / ``shed`` / ``straggler`` cover both serving
         modes; LM ``prefill_s`` / ``decode_s_per_tok`` are seconds.  DWN
         ``steps`` summarises the per-step phase record (``steplog``): mean
-        and p99 ms of each phase, occupancy in %, compiles, and the count
-        of steps it covers.
+        and p99 ms of each phase, occupancy in %, the share of steps
+        launched while another was in flight (``overlap_pct``), compiles,
+        and the count of steps it covers.
         """
         async_all = list(self._async_done)
         async_counters = dict(self._async_counters)

@@ -75,7 +75,7 @@ class Request:
     payload: Any                       # (size, F) features | LM batch dict
     size: int                          # samples (DWN) / sequences (LM)
     t_submit: float
-    t_start: float = 0.0               # first step launch
+    t_start: float = 0.0               # first step's start
     t_done: float = 0.0                # last result ready
     buckets: tuple = ()                # bucket(s) this request ran in
     result: Any = None

@@ -1,7 +1,6 @@
 """Phase times of every served DWN step: profiler spans and an in-memory ring.
 
-A served step runs in six phases, in this order, and together they
-partition it:
+A served step runs in six phases, in this order:
 
 ==================  ======================================================
 span                covers
@@ -18,28 +17,41 @@ span                covers
 ``serve.resolve``   splitting the outputs per request, setting futures
 ==================  ======================================================
 
-``serve.step`` is their parent, with the stats ``step`` (the record's
-sequence number), ``rows``, ``bucket`` and ``requests``; ``serve.wait``
-marks the continuous loop waiting on an empty queue, outside any step.
-While a profiler runs, each span is a ``jax.profiler.TraceAnnotation``
-and lands on the device trace's clock; otherwise a span costs one
-``is_enabled`` check.
+The first three launch the step and the last three collect it.  The
+continuous loop may launch the next step between the two halves (two
+steps in flight), so one step's phases need not be contiguous in time;
+each phase span is, and each is timed from its own start to its own end
+into the record of its own step (:class:`Step`, made current on the
+thread around each piece of work).  A step's time is the sum of its
+phases: the thread's time spent on that step, never on the other one in
+flight.  Under overlap ``serve.device`` and ``serve.d2h`` are what is
+left of the wait when the host comes to collect, not the device's time.
+
+``serve.step`` runs from the step's first phase to its last, with the
+stats ``step`` (the record's sequence number), ``rows``, ``bucket`` and
+``requests``; two steps' ``serve.step`` spans overlap where the loop
+held one over.  ``serve.wait`` marks the continuous loop waiting on an
+empty queue, outside any step.  While a profiler runs, each span is a
+``jax.profiler.TraceAnnotation`` and lands on the device trace's clock;
+otherwise a span costs one ``is_enabled`` check.
 
 The same code records each step into one process-wide :class:`Ring` of
 preallocated arrays (:data:`CAPACITY` steps): the phase times from
 ``time.perf_counter_ns``, the real rows launched, the bucket, the
 requests in the batch, the compiles its dispatch triggered,
-``d2h_copies``, the device-to-host transfers the step issued (one), and,
+``d2h_copies``, the device-to-host transfers the step issued (one),
 for a step over token sequences (the DWN head's classify step),
 ``tokens`` (real prompt tokens) and ``bucket_tokens`` (batch x length of
-the padded step); both are 0 for a step over feature rows.
-``last(n)`` reads the newest ``n`` records; ``mark()`` / ``since(mark)``
-read what came after a point.  Recording is always on and writes nothing
-to disk.
+the padded step), both 0 for a step over feature rows, and
+``overlapped``: 1 where the step was launched while its predecessor was
+still uncollected.  ``last(n)`` reads the newest ``n`` records;
+``mark()`` / ``since(mark)`` read what came after a point.  Recording is
+always on and writes nothing to disk.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -53,12 +65,11 @@ PHASES = ("batch", "h2d", "dispatch", "device", "d2h", "resolve")
 CAPACITY = 65536
 
 _INDEX = {p: i for i, p in enumerate(PHASES)}
-_RESOLVE = _INDEX["resolve"]
 _enabled = TraceAnnotation.is_enabled
 
 
 class _Local(threading.local):
-    step = None          # the step open on this thread
+    step = None          # the step current on this thread
 
 
 _local = _Local()
@@ -76,6 +87,7 @@ class Records:
     d2h_copies: np.ndarray    # device-to-host transfers issued
     tokens: np.ndarray        # real tokens of a step over sequences
     bucket_tokens: np.ndarray  # its padded batch x length
+    overlapped: np.ndarray    # 1: launched before its predecessor's collect
 
     def __len__(self) -> int:
         return len(self.step_ns)
@@ -94,11 +106,16 @@ class Records:
         total = self.bucket_tokens.sum()
         return float(self.tokens.sum() / total * 100.0) if total else None
 
+    def overlap_pct(self) -> float:
+        """Steps launched while their predecessor was still in flight, in
+        percent."""
+        return float(self.overlapped.mean() * 100.0)
+
     def summary(self) -> dict:
         """Mean and p99 milliseconds of the step and of each phase, the
         occupancy (and the token occupancy of steps over sequences), the
-        compiles, the device-to-host copies and the count of steps
-        covered."""
+        share of steps launched while another was in flight, the compiles,
+        the device-to-host copies and the count of steps covered."""
         if not len(self):
             return {"count": 0}
         ms = {"step": self.step_ns / 1e6}
@@ -109,6 +126,7 @@ class Records:
                        "p99": round(float(np.percentile(v, 99)), 4)}
                    for k, v in ms.items()},
             "occupancy_pct": round(self.occupancy_pct(), 3),
+            "overlap_pct": round(self.overlap_pct(), 3),
             "compiles": int(self.compiles.sum()),
             "d2h_copies": int(self.d2h_copies.sum()),
         } | ({} if self.token_occupancy_pct() is None else
@@ -121,21 +139,23 @@ class Ring:
     def __init__(self, capacity: int = CAPACITY):
         self.capacity = capacity
         #: per step: the phases' ns, the step's ns, rows, bucket,
-        #: requests, compiles, d2h_copies, tokens, bucket_tokens
-        self._data = np.zeros((capacity, len(PHASES) + 8), np.int64)
+        #: requests, compiles, d2h_copies, tokens, bucket_tokens,
+        #: overlapped
+        self._data = np.zeros((capacity, len(PHASES) + 9), np.int64)
         self._n = 0
         self._lock = threading.Lock()
 
     def record(self, phase_ns, step_ns: int, rows: int, bucket: int,
                requests: int, compiles: int, d2h_copies: int = 0,
-               tokens: int = 0, bucket_tokens: int = 0) -> int:
+               tokens: int = 0, bucket_tokens: int = 0,
+               overlapped: int = 0) -> int:
         """Store one step; returns its sequence number."""
         with self._lock:
             seq = self._n
             self._data[seq % self.capacity] = (*phase_ns, step_ns, rows,
                                                bucket, requests, compiles,
                                                d2h_copies, tokens,
-                                               bucket_tokens)
+                                               bucket_tokens, overlapped)
             self._n = seq + 1
         return seq
 
@@ -198,82 +218,104 @@ class span:
 
 
 class phase(span):
-    """One phase of the step open on this thread: the ``serve.<name>``
-    span, and the time since the previous phase ended added to the step's
-    record.  Outside a step (warm-up, a bare engine step) only the span."""
+    """One phase of the step current on this thread: the ``serve.<name>``
+    span, and its time, from its own start to its own end, added to the
+    step's record.  Outside a step (warm-up, a bare engine step) only the
+    span."""
 
-    __slots__ = ("_i",)
+    __slots__ = ("_i", "_rec", "_t")
 
     def __init__(self, name: str):
         self.name = "serve." + name
         self._i = _INDEX[name]
 
+    def __enter__(self):
+        super().__enter__()
+        self._rec = _local.step
+        self._t = time.perf_counter_ns()
+        return self
+
     def __exit__(self, *exc):
-        if self._tm is not None:
-            self._tm.__exit__(*exc)
-        rec = _local.step
-        if rec is not None:
-            rec.end_phase(self._i)
+        if self._rec is not None:
+            self._rec.phase_ns[self._i] += time.perf_counter_ns() - self._t
+        super().__exit__(*exc)
 
 
-class step(span):
-    """One served step: the ``serve.step`` span, and its record in the
-    ring.  The scheduler sets ``rows``, ``bucket``, ``requests`` and, for
-    token sequences, ``tokens`` and ``bucket_tokens`` once the batch is
-    formed; a step that launched no rows, or raised, is not recorded.
-    What runs after the last phase counts as resolution, so the phases
-    sum to the step."""
+class Step(span):
+    """One served step: its record, and its ``serve.step`` span from
+    construction to :meth:`close`.
+
+    ``with rec:`` makes it the step current on this thread, which the
+    :class:`phase` spans inside record into; the scheduler does so around
+    each piece of work of the step, so that two steps in flight on one
+    thread each keep their own phases.  The scheduler sets ``rows``,
+    ``bucket``, ``requests``, ``overlapped`` and, for token sequences,
+    ``tokens`` and ``bucket_tokens``; :meth:`close` stores the record in
+    the ring unless the step launched no rows or failed.  Its step time is
+    the sum of its phases: the thread's time spent on this step."""
 
     __slots__ = ("rows", "bucket", "requests", "compiles", "d2h_copies",
-                 "tokens", "bucket_tokens", "_ns", "_t0", "_t")
+                 "tokens", "bucket_tokens", "overlapped", "phase_ns",
+                 "_prev")
 
     def __init__(self):
         self.name = "serve.step"
         self.rows = self.bucket = self.requests = self.compiles = 0
         self.d2h_copies = self.tokens = self.bucket_tokens = 0
+        self.overlapped = 0
+        self.phase_ns = [0] * len(PHASES)
+        super().__enter__()
 
     def __enter__(self):
-        super().__enter__()
-        self._ns = [0] * len(PHASES)
-        self._t0 = self._t = time.perf_counter_ns()
-        _local.step = self
+        self._prev, _local.step = _local.step, self
         return self
 
-    def end_phase(self, i: int) -> None:
-        t = time.perf_counter_ns()
-        self._ns[i] += t - self._t
-        self._t = t
+    def __exit__(self, *exc):
+        _local.step = self._prev
 
-    def __exit__(self, exc_type, *rest):
-        _local.step = None
-        if exc_type is None and self.rows:
-            self.end_phase(_RESOLVE)
-            seq = RING.record(self._ns, self._t - self._t0, self.rows,
+    def close(self, ok: bool = True) -> None:
+        """End the span; record the step if ``ok`` and it launched rows."""
+        if ok and self.rows:
+            seq = RING.record(self.phase_ns, sum(self.phase_ns), self.rows,
                               self.bucket, self.requests, self.compiles,
                               self.d2h_copies, self.tokens,
-                              self.bucket_tokens)
+                              self.bucket_tokens, self.overlapped)
             if self._tm is not None:
                 self._tm.set_metadata(step=seq, rows=self.rows,
                                       bucket=self.bucket,
                                       requests=self.requests)
-        super().__exit__(exc_type, *rest)
+        super().__exit__(None, None, None)
+
+
+@contextlib.contextmanager
+def step():
+    """``with step() as rec:`` one step run start to end on this thread:
+    a :class:`Step`, current inside the block and closed at its end."""
+    rec = Step()
+    ok = False
+    try:
+        with rec:
+            yield rec
+        ok = True
+    finally:
+        rec.close(ok)
 
 
 def add_compiles(n: int) -> None:
-    """Count ``n`` XLA traces against the step open on this thread."""
+    """Count ``n`` XLA traces against the step current on this thread."""
     rec = _local.step
     if rec is not None:
         rec.compiles += n
 
 
 def add_d2h_copies(n: int) -> None:
-    """Count ``n`` device-to-host transfers against the step open on this
-    thread."""
+    """Count ``n`` device-to-host transfers against the step current on
+    this thread."""
     rec = _local.step
     if rec is not None:
         rec.d2h_copies += n
 
 
-__all__ = ["CAPACITY", "PHASES", "RING", "Records", "Ring", "add_compiles",
-           "add_d2h_copies", "last", "mark", "phase", "since", "span",
-           "step"]
+__all__ = ["CAPACITY", "PHASES", "RING", "Records", "Ring", "Step",
+           "add_compiles", "add_d2h_copies", "last", "mark", "phase",
+           "since", "span", "step"]

@@ -43,6 +43,11 @@ def _tag_payload(rid, n):
     return x
 
 
+def _identity(outs):
+    """``collect`` for a stub whose ``launch`` runs the whole step."""
+    return outs
+
+
 def _tag_step(clock=None, step_s=0.0, shapes=None):
     def step(x):
         if clock is not None:
@@ -73,8 +78,9 @@ class ConstEstimator:
 
 def test_out_of_order_completion_by_priority():
     clock = FakeClock()
-    sched = ContinuousScheduler(_tag_step(), max_bucket=8, min_bucket=8,
-                                timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8, min_bucket=8,
+        timer=clock)
     low = sched.submit(_tag_payload(0, 8), priority=0)
     high = sched.submit(_tag_payload(1, 8), priority=1)
     sched.step_once()
@@ -91,8 +97,9 @@ def test_out_of_order_completion_by_priority():
 
 def test_edf_within_priority_class():
     clock = FakeClock()
-    sched = ContinuousScheduler(_tag_step(), max_bucket=8, min_bucket=8,
-                                timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8, min_bucket=8,
+        timer=clock)
     loose = sched.submit(_tag_payload(0, 8), deadline_ms=10_000.0)
     tight = sched.submit(_tag_payload(1, 8), deadline_ms=1_000.0)
     sched.step_once()
@@ -103,8 +110,9 @@ def test_edf_within_priority_class():
 def test_dense_packing_and_oversize_chunking():
     clock = FakeClock()
     shapes = []
-    sched = ContinuousScheduler(_tag_step(shapes=shapes), max_bucket=8,
-                                min_bucket=8, timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(shapes=shapes), _identity, max_bucket=8,
+        min_bucket=8, timer=clock)
     a = sched.submit(_tag_payload(0, 5))
     b = sched.submit(_tag_payload(1, 5))
     big = sched.submit(_tag_payload(2, 20))
@@ -126,8 +134,9 @@ def test_dense_packing_and_oversize_chunking():
 
 def test_admission_shed_on_unmeetable_deadline():
     clock = FakeClock()
-    sched = ContinuousScheduler(_tag_step(), max_bucket=8, min_bucket=8,
-                                estimator=ConstEstimator(1.0), timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8, min_bucket=8,
+        estimator=ConstEstimator(1.0), timer=clock)
     # one step costs ~1s; a 10ms deadline is provably unmeetable
     req = sched.submit(_tag_payload(0, 4), deadline_ms=10.0)
     res = req.future.result(timeout=0)     # resolved before queueing
@@ -135,16 +144,18 @@ def test_admission_shed_on_unmeetable_deadline():
     assert res.value is None
     assert sched.pending == 0
     # same deadline with a feasible estimator is admitted
-    sched2 = ContinuousScheduler(_tag_step(), max_bucket=8, min_bucket=8,
-                                 estimator=ConstEstimator(1e-4), timer=clock)
+    sched2 = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8, min_bucket=8,
+        estimator=ConstEstimator(1e-4), timer=clock)
     ok = sched2.submit(_tag_payload(0, 4), deadline_ms=10.0)
     assert not ok.future.done() and sched2.pending == 1
 
 
 def test_queued_deadline_expires_at_step_boundary():
     clock = FakeClock()
-    sched = ContinuousScheduler(_tag_step(), max_bucket=8, min_bucket=8,
-                                timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8, min_bucket=8,
+        timer=clock)
     req = sched.submit(_tag_payload(0, 4), deadline_ms=50.0)
     clock.advance(0.06)                    # deadline passes while queued
     sched.step_once()
@@ -156,8 +167,9 @@ def test_queued_deadline_expires_at_step_boundary():
 def test_late_completion_is_marked_never_silent():
     clock = FakeClock()
     # the step itself overruns the deadline: served, but marked
-    sched = ContinuousScheduler(_tag_step(clock, step_s=0.1), max_bucket=8,
-                                min_bucket=8, timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(clock, step_s=0.1), _identity, max_bucket=8,
+        min_bucket=8, timer=clock)
     req = sched.submit(_tag_payload(0, 4), deadline_ms=50.0)
     sched.step_once()
     res = req.future.result(timeout=0)
@@ -170,8 +182,9 @@ def test_late_completion_is_marked_never_silent():
 def test_backpressure_queue_full_then_drains():
     clock = FakeClock()
     slo = SLOConfig(max_queue_samples=8, submit_timeout_s=0.0)
-    sched = ContinuousScheduler(_tag_step(), max_bucket=8, min_bucket=8,
-                                slo=slo, timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8, min_bucket=8,
+        slo=slo, timer=clock)
     sched.submit(_tag_payload(0, 8))
     with pytest.raises(QueueFull):
         sched.submit(_tag_payload(1, 1))
@@ -191,7 +204,8 @@ def test_stop_without_drain_sheds_shutdown():
         gate.wait(timeout=10.0)
         return (x[:, 0].copy(),)
 
-    sched = ContinuousScheduler(step, max_bucket=8, min_bucket=8)
+    sched = ContinuousScheduler(step, _identity, max_bucket=8,
+                                min_bucket=8)
     sched.start()
     a = sched.submit(_tag_payload(0, 8))
     deadline = _time.monotonic() + 10.0
@@ -212,8 +226,9 @@ def test_stop_without_drain_sheds_shutdown():
 
 def test_queue_time_attributed_from_original_submit_across_chunks():
     clock = FakeClock()
-    sched = ContinuousScheduler(_tag_step(clock, step_s=1.0), max_bucket=8,
-                                min_bucket=8, timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(clock, step_s=1.0), _identity, max_bucket=8,
+        min_bucket=8, timer=clock)
     req = sched.submit(_tag_payload(0, 20))
     clock.advance(5.0)                     # waits 5s before the loop runs
     while not req.future.done():
@@ -228,8 +243,9 @@ def test_queue_time_attributed_from_original_submit_across_chunks():
 def test_estimator_and_counters_updated_per_step():
     clock = FakeClock()
     est = ConstEstimator(1e-6)
-    sched = ContinuousScheduler(_tag_step(clock, step_s=0.25), max_bucket=8,
-                                min_bucket=8, estimator=est, timer=clock)
+    sched = ContinuousScheduler(
+        _tag_step(clock, step_s=0.25), _identity, max_bucket=8,
+        min_bucket=8, estimator=est, timer=clock)
     sched.submit(_tag_payload(0, 6))
     sched.step_once()
     assert est.updates == [(8, pytest.approx(0.25))]
@@ -237,6 +253,259 @@ def test_estimator_and_counters_updated_per_step():
     assert c["steps"] == 1 and c["served_requests"] == 1
     assert c["served_samples"] == 6
     assert c["busy_s"] == pytest.approx(0.25)
+
+
+# ---------------------------------------------------------------------------
+# two steps in flight: the thread loop over split launch / collect halves
+# ---------------------------------------------------------------------------
+
+class Halves:
+    """A step split as the engine splits it: ``launch`` returns a handle
+    (the batch, copied, and its launch number), ``collect`` the tagged
+    rows.  ``collect_gate`` (when set) holds every collect until it
+    opens; ``collecting`` is set on the first collect; ``fail_launch``
+    makes that launch (1-based) raise."""
+
+    def __init__(self, collect_gate=None, fail_launch=None):
+        import threading
+        self.launched, self.collected = [], []
+        self.collect_gate = collect_gate
+        self.collecting = threading.Event()
+        self.fail_launch = fail_launch
+
+    def launch(self, x):
+        self.launched.append(x.shape[0])
+        if len(self.launched) == self.fail_launch:
+            raise RuntimeError("launch failed")
+        return len(self.launched), x.copy()
+
+    def collect(self, handle):
+        n, x = handle
+        self.collecting.set()
+        if self.collect_gate is not None:
+            assert self.collect_gate.wait(timeout=10.0)
+        self.collected.append(n)
+        return (x[:, 0].copy(),)
+
+
+def _backlog(sched, sizes):
+    """Queue one tagged request per size before the loop starts, so the
+    loop finds a backlog; record the order their futures resolve in."""
+    order = []
+    reqs = [sched.submit(_tag_payload(i, n)) for i, n in enumerate(sizes)]
+    for r in reqs:
+        r.future.add_done_callback(lambda f, rid=r.rid: order.append(rid))
+    return reqs, order
+
+
+BACKLOG = [5, 17, 8, 3, 30, 1, 12]
+
+
+def test_backlog_resolves_in_launch_order_bit_exact_to_serial():
+    from repro.serving import steplog
+    serial = ContinuousScheduler(_tag_step(), _identity, max_bucket=8,
+                                 min_bucket=8)
+    want, want_order = _backlog(serial, BACKLOG)
+    while serial.step_once():
+        pass
+    halves = Halves()
+    sched = ContinuousScheduler(halves.launch, halves.collect, max_bucket=8,
+                                min_bucket=8)
+    reqs, order = _backlog(sched, BACKLOG)
+    mark = steplog.mark()
+    sched.start()
+    sched.stop(drain=True)
+    assert order == want_order
+    assert halves.collected == sorted(halves.collected)
+    assert len(sched.completed) == len(BACKLOG)
+    for got, ref, n in zip(reqs, want, BACKLOG):
+        res = got.future.result(timeout=0)
+        assert res.ok
+        np.testing.assert_array_equal(res.value[0],
+                                      ref.future.result().value[0])
+        np.testing.assert_array_equal(
+            res.value[0], got.rid * 1000 + np.arange(n, dtype=np.float64))
+    # every step after the first launched with its predecessor in flight
+    recs = steplog.since(mark)
+    assert len(recs) == sched.steps == len(halves.launched)
+    np.testing.assert_array_equal(recs.overlapped,
+                                  [0] + [1] * (len(recs) - 1))
+
+
+def test_request_split_across_two_steps_in_flight_resolves_once():
+    """12 rows over an 8-row bucket: the second step takes the last four
+    rows before the first is collected; the first step's collect must not
+    resolve the request with its 8 rows alone."""
+    halves = Halves()
+    sched = ContinuousScheduler(halves.launch, halves.collect, max_bucket=8,
+                                min_bucket=8)
+    (big, small), order = _backlog(sched, [12, 4])
+    seen = []
+    big.future.add_done_callback(
+        lambda f: seen.append(len(f.result().value[0])))
+    sched.start()
+    sched.stop(drain=True)
+    assert halves.launched == [8, 8]
+    assert seen == [12] and order == [big.rid, small.rid]
+    np.testing.assert_array_equal(big.future.result().value[0],
+                                  np.arange(12, dtype=np.float64))
+    assert big.buckets == (8, 8)
+    assert len(sched.completed) == 2
+
+
+def test_lone_request_on_an_idle_loop_is_never_held_over():
+    from repro.serving import steplog
+    halves = Halves()
+    sched = ContinuousScheduler(halves.launch, halves.collect, max_bucket=8,
+                                min_bucket=8)
+    mark = steplog.mark()
+    sched.start()
+    try:
+        for i, n in enumerate((3, 8, 20)):
+            res = sched.submit(_tag_payload(i, n)).future.result(timeout=10.0)
+            assert res.ok
+    finally:
+        sched.stop()
+    recs = steplog.since(mark)
+    # a request that fits one step has nothing queued behind it; the
+    # 20-row one is three steps, and its own remaining rows are queued
+    # behind its first two
+    np.testing.assert_array_equal(recs.rows, [3, 8, 8, 8, 4])
+    np.testing.assert_array_equal(recs.overlapped, [0, 0, 0, 1, 1])
+
+
+@pytest.mark.parametrize("drain", [True, False])
+def test_stop_with_a_held_step(drain):
+    """A collecting, B launched and held, C queued when ``stop`` comes:
+    draining answers all three; not draining sheds C alone."""
+    import threading
+    gate = threading.Event()
+    halves = Halves(collect_gate=gate)
+    sched = ContinuousScheduler(halves.launch, halves.collect, max_bucket=8,
+                                min_bucket=8)
+    (a, b, c), _ = _backlog(sched, [8, 8, 8])
+    sched.start()
+    assert halves.collecting.wait(timeout=10.0)
+    assert halves.launched == [8, 8] and sched.pending == 1
+    stopper = threading.Thread(target=lambda: sched.stop(drain=drain))
+    stopper.start()
+    if not drain:
+        res_c = c.future.result(timeout=5.0)
+        assert not res_c.ok and res_c.shed == SHED_SHUTDOWN
+        assert not a.future.done() and not b.future.done()
+    gate.set()
+    stopper.join(timeout=10.0)
+    assert not stopper.is_alive()
+    for req in (a, b) if not drain else (a, b, c):
+        res = req.future.result(timeout=0)
+        assert res.ok
+        np.testing.assert_array_equal(
+            res.value[0], req.rid * 1000 + np.arange(8, dtype=np.float64))
+    assert halves.launched == ([8, 8] if not drain else [8, 8, 8])
+
+
+def test_failed_launch_still_resolves_the_held_step(monkeypatch):
+    import threading
+    raised = []
+    monkeypatch.setattr(threading, "excepthook",
+                        lambda args: raised.append(args.exc_value))
+    halves = Halves(fail_launch=2)
+    sched = ContinuousScheduler(halves.launch, halves.collect, max_bucket=8,
+                                min_bucket=8)
+    (a, b), _ = _backlog(sched, [8, 8])
+    sched.start()
+    res = a.future.result(timeout=10.0)
+    sched.stop()
+    assert res.ok and halves.collected == [1]
+    np.testing.assert_array_equal(res.value[0],
+                                  np.arange(8, dtype=np.float64))
+    assert [str(e) for e in raised] == ["launch failed"]
+
+
+def test_concurrent_submitters_get_their_own_rows_under_overlap():
+    """Eight threads submit ragged requests into a running loop with a
+    short switch interval: every answer holds exactly its own rows, in
+    order, whichever steps and held-over steps carried them."""
+    import sys
+    import threading
+    halves = Halves()
+    sched = ContinuousScheduler(halves.launch, halves.collect, max_bucket=8,
+                                min_bucket=8,
+                                slo=SLOConfig(max_queue_samples=100_000))
+    sent = {}
+
+    def submitter(k):
+        rng = np.random.default_rng(k)
+        for i in range(25):
+            x = _tag_payload(100 * k + i, int(rng.integers(1, 21)))
+            sent[100 * k + i] = (x, sched.submit(x))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        sched.start()
+        threads = [threading.Thread(target=submitter, args=(k,))
+                   for k in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30.0)
+            assert not t.is_alive()
+        for x, req in sent.values():
+            res = req.future.result(timeout=30.0)
+            assert res.ok
+            np.testing.assert_array_equal(res.value[0], x[:, 0])
+    finally:
+        sys.setswitchinterval(interval)
+        sched.stop()
+    assert len(sent) == len(sched.completed) == 200
+
+
+def test_busy_time_counts_overlapping_steps_once():
+    """Launch at 0 and 1, collected at 3 and 4: the second step counts
+    from the first one's completion, so busy time is 4, not 3 + 3."""
+    clock = FakeClock()
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8,
+        min_bucket=8, timer=clock)
+    for i in range(2):
+        sched.submit(_tag_payload(i, 8))
+    first = sched._launch()
+    clock.advance(1.0)
+    second = sched._launch(overlapped=True)
+    clock.advance(2.0)
+    sched._collect(first)
+    clock.advance(1.0)
+    sched._collect(second)
+    assert sched.busy_s == pytest.approx(4.0)
+    assert [r.t_done for r in sched.completed] == [3.0, 4.0]
+
+
+def test_request_starts_when_its_step_gets_the_device():
+    """Launched at 1 behind a step that completes at 3, the second
+    request's step starts at 3, as ``busy_s`` counts it: its
+    ``compute_ms`` is its own step's time, not the two steps', and an
+    oversize request keeps the start of its first chunk's step."""
+    clock = FakeClock()
+    sched = ContinuousScheduler(
+        _tag_step(), _identity, max_bucket=8,
+        min_bucket=8, timer=clock)
+    a = sched.submit(_tag_payload(0, 8))
+    b = sched.submit(_tag_payload(1, 12))
+    first = sched._launch()
+    clock.advance(1.0)
+    second = sched._launch(overlapped=True)       # b's first 8 rows
+    clock.advance(2.0)
+    sched._collect(first)
+    clock.advance(1.0)
+    third = sched._launch(overlapped=True)        # b's last 4 rows
+    sched._collect(second)
+    clock.advance(1.0)
+    sched._collect(third)
+    assert (a.t_start, a.t_done) == (0.0, 3.0)
+    assert (b.t_start, b.t_done) == (3.0, 5.0)
+    assert b.queue_ms == pytest.approx(3000.0)
+    assert sched.busy_s == pytest.approx(5.0)
 
 
 # ---------------------------------------------------------------------------

@@ -227,8 +227,8 @@ def test_token_buckets_fill_behind_the_first_request():
         seen.append((tokens.shape, lengths.tolist()))
         return (np.arange(tokens.shape[0]),)
 
-    sched = ContinuousScheduler(step, batching=TokenBuckets((8, 16, 32),
-                                                           64))
+    sched = ContinuousScheduler(step, lambda outs: outs,
+                                batching=TokenBuckets((8, 16, 32), 64))
     reqs = [sched.submit(np.ones((1, 12), np.int32)),
             sched.submit(np.ones((1, 30), np.int32)),
             sched.submit(np.ones((5, 16), np.int32)),

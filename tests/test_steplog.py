@@ -41,11 +41,17 @@ def _stub_step(x):
         return (np.asarray(y),)
 
 
+def _identity(outs):
+    """``collect`` for the stub step, whose launch runs the whole step."""
+    return outs
+
+
 def _serve(path, engine, sizes):
     """Serve one request of each size, one step each; the steps' records."""
     mark = steplog.mark()
     if path == "stub":
-        sched = ContinuousScheduler(_stub_step, max_bucket=128, min_bucket=8)
+        sched = ContinuousScheduler(_stub_step, _identity,
+                                    max_bucket=128, min_bucket=8)
         for n in sizes:
             sched.submit(np.ones((n, 3), np.float32))
             assert sched.step_once() == n
@@ -96,6 +102,83 @@ def test_one_device_to_host_copy_per_step(engine, path):
     want = 0 if path == "stub" else 1
     np.testing.assert_array_equal(recs.d2h_copies, [want] * 3)
     assert recs.summary()["d2h_copies"] == 3 * want
+
+
+def _stub_launch(x):
+    """The stub step's first half: ``_dwn_launch``'s two phases."""
+    with steplog.phase("h2d"):
+        y = np.array(x)
+    with steplog.phase("dispatch"):
+        return y[:, 0] * 2.0
+
+
+def _stub_collect(y):
+    """Its second half: ``Launched.collect``'s two phases."""
+    with steplog.phase("device"):
+        y = y + 0.0
+    with steplog.phase("d2h"):
+        return (np.asarray(y),)
+
+
+@pytest.mark.parametrize("halves", ("stub", "engine"))
+def test_steps_in_flight_keep_their_own_phases(engine, halves):
+    """A backlog through the thread loop: every step after the first is
+    launched before its predecessor is collected, each record still holds
+    all six of its own phases, summing to its step time, and the loop's
+    busy time is no more than its wall time."""
+    launch, collect = ((_stub_launch, _stub_collect) if halves == "stub"
+                       else (engine._dwn_launch, engine._dwn_step))
+    sched = ContinuousScheduler(launch, collect, max_bucket=128,
+                                min_bucket=8)
+    sizes = [128, 64, 100, 128, 300]
+    reqs = [sched.submit(engine.make_request(n, seed=n)) for n in sizes]
+    mark = steplog.mark()
+    sched.start()
+    sched.stop(drain=True)
+    recs = steplog.since(mark)
+    assert len(recs) == sched.steps == 6
+    np.testing.assert_array_equal(recs.overlapped, [0, 1, 1, 1, 1, 1])
+    np.testing.assert_array_equal(recs.rows, [128] * 5 + [80])
+    assert (recs.phase_ns > 0).all(), recs.phase_ns
+    np.testing.assert_array_equal(recs.phase_ns.sum(axis=1), recs.step_ns)
+    assert recs.summary()["overlap_pct"] == pytest.approx(500 / 6, abs=1e-3)
+    counters = sched.counters()
+    assert 0 < counters["busy_s"] <= counters["session_wall_s"]
+    if halves == "engine":
+        oracle = engine.backends["float-oracle"]
+        for req, n in zip(reqs, sizes):
+            res = req.future.result(timeout=0)
+            x = engine.make_request(n, seed=n)
+            for got, want in zip(res.value, oracle(x)):
+                np.testing.assert_array_equal(got, want)
+
+
+def _overlap_reader():
+    import importlib.util
+    path = (Path(__file__).resolve().parents[1] / "bench" / "metrics"
+            / "overlap_share.py")
+    spec = importlib.util.spec_from_file_location("overlap_share", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("case", ["filled", "no_steps", "no_column"])
+def test_overlap_share_reader(monkeypatch, case):
+    """The benchmark's ``overlap_share`` reads the window's records, and
+    nothing from a step log without the ``overlapped`` column."""
+    from types import SimpleNamespace
+    ring = steplog.Ring(8)
+    monkeypatch.setattr(steplog, "RING", ring)
+    phases = [1] * len(steplog.PHASES)
+    ring.record(phases, len(phases), 8, 8, 1, 0)        # before the window
+    for overlapped in (0, 1, 1, 1):
+        ring.record(phases, len(phases), 8, 8, 1, 0, 1, overlapped=overlapped)
+    steps = 0 if case == "no_steps" else 4
+    if case == "no_column":
+        monkeypatch.delattr(steplog.Records, "overlap_pct")
+    got = _overlap_reader().read(SimpleNamespace(counters={"steps": steps}))
+    assert got == (75.0 if case == "filled" else None)
 
 
 @pytest.mark.parametrize("capacity", (4, steplog.CAPACITY))
