@@ -69,7 +69,7 @@ class DWNSpec:
       input_bits: PEN fixed-point input width in *total* bits (1 sign +
         n fractional); must be set iff ``variant == "PEN"``.
       datapath: serving backend name ("fused-packed" | "packed-xla" |
-        "float-oracle" | "auto") — validated against the registry at
+        "float-oracle") — validated against the registry at
         construction.
       grouping: popcount grouping ("contig" | "strided").
       workload: registered workload name the spec trains/serves on
@@ -136,12 +136,11 @@ class DWNSpec:
             raise ValueError(
                 f"unknown popcount grouping {self.grouping!r}; supported: "
                 f"{list(GROUPINGS)}")
-        allowed = _serving_datapaths() + ["auto"]
-        if self.datapath not in allowed:
+        if self.datapath not in _serving_datapaths():
             raise ValueError(
                 f"unregistered serving datapath {self.datapath!r}; "
-                f"registered backends: {sorted(allowed)} (register new "
-                f"ones via repro.serving.backends.register_backend)")
+                f"registered backends: {_serving_datapaths()} (register "
+                f"new ones via repro.serving.backends.register_backend)")
 
     # -- derived views -------------------------------------------------
 
@@ -240,7 +239,7 @@ class DWNSpec:
                 f"not a JSC tier width ({sorted(_LUTS_TO_TIER)}); register "
                 f"a preset tier first")
         datapath = cfg.dwn_datapath
-        if datapath not in _serving_datapaths() + ["auto"]:
+        if datapath not in _serving_datapaths():
             datapath = "fused-packed"
         grouping = cfg.dwn_grouping if cfg.dwn_grouping in GROUPINGS \
             else "contig"

@@ -203,24 +203,18 @@ def run_sync(engines: dict, arrivals: list[Arrival], payloads: list) -> dict:
     while i < len(arrivals):
         t_target = t0 + arrivals[i].t
         now = time.perf_counter()
-        if now < t_target and all(
-                eng.scheduler.pending == 0 for eng in engines.values()):
+        if now < t_target:               # every queue is drained here
             _sleep_until(t_target)
             now = time.perf_counter()
-        submitted = False
+        due = {}
         while i < len(arrivals) and t0 + arrivals[i].t <= now:
             arr = arrivals[i]
             lateness.append(now - (t0 + arr.t))
-            reqs.append((arr, _engine_for(engines, arr).submit(payloads[i])))
+            eng = _engine_for(engines, arr)
+            reqs.append((arr, eng.submit(payloads[i])))
+            due[id(eng)] = eng
             i += 1
-            submitted = True
-        if submitted or any(eng.scheduler.pending
-                            for eng in engines.values()):
-            for eng in engines.values():
-                if eng.scheduler.pending:
-                    eng.drain()
-    for eng in engines.values():
-        if eng.scheduler.pending:
+        for eng in due.values():
             eng.drain()
     t_end = time.perf_counter()
     return _metrics(reqs, t0, t_end, rejected=0, lateness_s=lateness)
@@ -331,14 +325,16 @@ def main(argv=None):
     ap.add_argument("--burst-every", type=float, default=0.0)
     ap.add_argument("--burst-len", type=float, default=0.0)
     ap.add_argument("--max-bucket", type=int, default=256)
-    ap.add_argument("--backend", default="auto")
+    ap.add_argument("--backend", default="",
+                    help="DWN datapath backend (default: the spec's "
+                         "datapath)")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
     from .compile_cache import enable_compile_cache
     enable_compile_cache()
 
     presets = args.preset or ["dwn-jsc-sm"]
-    engines = {p: ServingEngine(p, backend=args.backend,
+    engines = {p: ServingEngine(p, backend=args.backend or None,
                                 max_bucket=args.max_bucket, n_train=2000)
                for p in presets}
     capacity = {p: measure_capacity(eng) for p, eng in engines.items()}

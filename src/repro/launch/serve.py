@@ -2,9 +2,9 @@
 
 All serving logic lives in the subsystem — ``serving/backends.py``
 (pluggable DWN datapaths + compile cache + oracle cross-check),
-``serving/scheduler.py`` (admission-order microbatching into power-of-two
-batch buckets), ``serving/engine.py`` (unified submit/drain engine, DWN
-buckets sharded data-parallel over the host mesh).  This module only
+``serving/continuous.py`` (the batching loop: dense packing into
+power-of-two batch buckets), ``serving/engine.py`` (unified submit/drain
+engine, DWN buckets sharded data-parallel over the host mesh).  This module only
 parses flags, synthesizes a request stream, and prints the JSON report.
 
 LM archs: batches of prompts are prefilled once, then decoded
@@ -15,7 +15,7 @@ vectors are classified through the selected datapath backend
 (--backend fused-packed | packed-xla | float-oracle); every non-oracle
 backend is checked bit-exactly against the ``apply_hard`` oracle before
 timing starts.  --ragged draws mixed request sizes in [1, batch] so the
-scheduler's coalescing/padding is exercised.  --continuous serves the
+scheduler's dense packing and padding are exercised.  --continuous serves the
 same stream through the continuous-batching async engine (scheduler
 thread, out-of-order futures, optional --deadline-ms SLO) instead of the
 sync submit/drain facade.
@@ -201,12 +201,10 @@ def main(argv=None):
                          "deadline; requests that provably cannot meet it "
                          "are shed at admission (0 = no deadline)")
     ap.add_argument("--backend", default="",
-                    choices=["", "auto"] + available_backends(),
-                    help="DWN datapath backend (default: the arch's "
-                         "dwn_datapath, else fused-packed; 'auto' "
-                         "calibrates per batch bucket at startup and "
-                         "serves each bucket on the fastest bit-exact "
-                         "backend)")
+                    choices=[""] + available_backends(),
+                    help="DWN datapath backend, served at every bucket "
+                         "(default: the arch's dwn_datapath, else "
+                         "fused-packed)")
     ap.add_argument("--no-data-parallel", action="store_true",
                     help="DWN mode: disable shard_map data parallelism")
     ap.add_argument("--model-parallel", type=int, default=1)

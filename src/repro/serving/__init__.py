@@ -1,18 +1,19 @@
-"""Serving subsystem: pluggable DWN datapath backends, a microbatching
-request scheduler, and the engine that unifies DWN classification with LM
-prefill/decode serving behind one submit/drain API.
+"""Serving subsystem: pluggable DWN datapath backends, one
+continuous-batching loop, and the engine that unifies DWN classification
+with LM prefill/decode serving behind one submit/drain API.
 
 Layering (each importable on its own):
 
     backends.py    datapath registry + per-(arch, bucket) compile cache
                    + startup bit-exactness cross-check vs the oracle
                    + per-bucket step-time estimates (StepTimeEstimator)
-    scheduler.py   admission-order request queue, power-of-two batch
-                   buckets, per-request queue/compute latency accounting
-                   (the synchronous submit/drain facade)
-    continuous.py  continuous-batching loop: scheduler thread with two
-                   steps in flight, futures, SLO-aware admission +
-                   deadline shedding, bounded-queue backpressure
+    scheduler.py   power-of-two bucket ladder, per-request queue/compute
+                   latency accounting, the FIFO that LM decode drains
+    continuous.py  the batching loop: dense packing into the ladder,
+                   stepped by the sync drain() or by a scheduler thread
+                   with two steps in flight, futures, SLO-aware
+                   admission + deadline shedding, bounded-queue
+                   backpressure
     steplog.py     phase spans of each served step on the profiler's
                    clock + a process-wide ring of per-step records
     engine.py      ServingEngine: sync submit/drain AND async

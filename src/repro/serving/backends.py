@@ -343,12 +343,10 @@ class StepTimeEstimator:
     request could complete?" *before* serving it.  The estimate has two
     sources, in order of freshness:
 
-    * a **seed** from startup calibration — ``AutoSelector`` already
-      times every backend at every bucket for ``backend="auto"``, so the
-      winning backend's time per bucket is free; pinned backends seed
-      from one ``time_backend_step`` probe at ``max_bucket`` (step time
-      is overhead-dominated at these model sizes, so one bucket's time
-      is a usable prior for the whole ladder);
+    * a **seed**: one ``time_backend_step`` probe of the served backend
+      at ``max_bucket`` when the loop starts (step time is
+      overhead-dominated at these model sizes, so one bucket's time is a
+      usable prior for the whole ladder);
     * an **online EWMA** over the actual step times the loop observes
       (``update`` after every step), which quickly overrides the seed
       and tracks drift (thermal, contention, interpret-vs-compiled).
@@ -396,19 +394,8 @@ class StepTimeEstimator:
                 for b in buckets}
 
 
-def estimator_from_calibration(auto: "AutoSelector") -> StepTimeEstimator:
-    """Seed an estimator from an ``AutoSelector``'s startup calibration:
-    each bucket's prior is the *chosen* backend's measured step time."""
-    est = StepTimeEstimator()
-    for bucket, times in auto.timings.items():
-        choice = auto.choice.get(bucket)
-        if choice in times:
-            est.seed(bucket, times[choice])
-    return est
-
-
 # ---------------------------------------------------------------------------
-# per-(arch, bucket) backend auto-select
+# timing a bound step, tuning the fused kernel
 # ---------------------------------------------------------------------------
 
 def time_backend_step(bound: "BoundBackend", x: Array, *,
@@ -421,7 +408,7 @@ def time_backend_step(bound: "BoundBackend", x: Array, *,
     server would.  The timing loop itself is ``autotune.time_step`` —
     the same machinery the kernel autotuner sweeps candidates with —
     with a 1 ms accumulation floor so microsecond-scale steps (small
-    models, small buckets) are raced over enough reps to beat scheduler
+    models, small buckets) are timed over enough reps to beat scheduler
     jitter.
     """
     return autotune.time_step(bound.step_for(x.shape[0]), x, iters=iters,
@@ -463,83 +450,6 @@ def autotune_model(model: DWNModelBundle, buckets, x_probe, *,
     return dict(model.tuned_configs)
 
 
-class AutoSelector:
-    """Per-(arch, bucket) fastest-bit-exact-backend chooser.
-
-    The fastest datapath is *size dependent* (in CPU runs the float
-    oracle outran the packed paths on dwn-jsc-sm, while on md/lg the
-    packed paths won).  Instead of hardcoding,
-    the selector times every backend that passed the startup bit-exactness
-    gate (the oracle is exact by definition) on probe rows at each bucket
-    size and serves that bucket on the winner.  Calibration runs once per
-    (arch, bucket): the engine calibrates its whole bucket ladder at
-    startup, so no timed request pays calibration (compiles + timing
-    probes) inside its compute window; ``backend_for`` keeps a lazy
-    fallback for selectors created mid-session via
-    ``use_backend("auto")``.
-
-    Calibration consults the model's *tuned* fused configs, not just the
-    backend choice: ``autotune_model`` runs first, so the fused-packed
-    candidate being timed at each bucket runs the autotuned rows per grid
-    step for that bucket, and ``configs`` records what was actually timed.
-
-    Near-ties break toward ``fused-packed``: at small buckets the real
-    spread between datapaths is a few microseconds — below the jitter of
-    the CPU interpret-mode emulation the timings run under — and the
-    fused kernel is the deployment-target path the emulation stands in
-    for.  A backend only displaces it by beating it past
-    ``tie_break_pct``.
-
-    Attributes:
-      choice: bucket -> winning backend name (filled by calibration).
-      timings: bucket -> {backend: best step seconds} for reporting.
-      configs: bucket -> tuned ``FusedConfig`` in effect at calibration
-        time (None for untuned buckets).
-    """
-
-    #: preferred backend on near-ties (the deployment-target kernel).
-    TIE_BREAK_BACKEND = "fused-packed"
-
-    def __init__(self, backends: dict[str, "BoundBackend"],
-                 bit_exact: dict[str, bool], *, iters: int = 5,
-                 tie_break_pct: float = 10.0):
-        self.backends = backends
-        self.eligible = [name for name, b in backends.items()
-                         if b.is_oracle or bit_exact.get(name, False)]
-        assert self.eligible, "no bit-exact backend to select from"
-        self.iters = iters
-        self.tie_break_pct = tie_break_pct
-        self.choice: dict[int, str] = {}
-        self.timings: dict[int, dict[str, float]] = {}
-        self.configs: dict[int, "autotune.FusedConfig | None"] = {}
-
-    def calibrate(self, x: Array) -> str:
-        """Time every eligible backend at x's bucket; returns the winner."""
-        bucket = x.shape[0]
-        times = {name: time_backend_step(self.backends[name], x,
-                                         iters=self.iters)
-                 for name in self.eligible}
-        self.timings[bucket] = times
-        best = min(times, key=times.get)
-        tb = self.TIE_BREAK_BACKEND
-        if (tb in times and tb != best
-                and times[tb] <= times[best]
-                * (1 + self.tie_break_pct / 100)):
-            best = tb
-        self.choice[bucket] = best
-        model = self.backends[best].model
-        self.configs[bucket] = model.tuned_configs.get(bucket)
-        return best
-
-    def backend_for(self, x: Array) -> "BoundBackend":
-        """The calibrated winner for x's bucket (calibrating on first
-        encounter — bounded one calibration per bucket, like compiles)."""
-        bucket = x.shape[0]
-        if bucket not in self.choice:
-            self.calibrate(x)
-        return self.backends[self.choice[bucket]]
-
-
 # ---------------------------------------------------------------------------
 # startup cross-check
 # ---------------------------------------------------------------------------
@@ -578,9 +488,8 @@ def verify_backends(model: DWNModelBundle,
 
 
 __all__ = [
-    "AutoSelector", "Backend", "BoundBackend", "DWNModelBundle",
-    "StepTimeEstimator", "autotune_model", "available_backends",
-    "build_dwn_model", "estimator_from_calibration", "get_backend",
-    "pack_answer", "register_backend", "time_backend_step",
+    "Backend", "BoundBackend", "DWNModelBundle", "StepTimeEstimator",
+    "autotune_model", "available_backends", "build_dwn_model",
+    "get_backend", "pack_answer", "register_backend", "time_backend_step",
     "unpack_answer", "verify_backends",
 ]

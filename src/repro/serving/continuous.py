@@ -1,20 +1,22 @@
 """Continuous-batching request loop with SLO-aware admission control.
 
-The synchronous ``MicrobatchScheduler`` coalesces requests in strict
-admission order and blocks the caller in ``drain()`` — a closed-loop
-measurement device, not a serving engine.  This module is the open-loop
-core: a dedicated scheduler thread keeps device steps in flight while new
-requests stream in from any number of submitting threads, and every
-request completes **out of order** through its own future.
+The one batching loop every served DWN and classify step goes through.
+Started, a dedicated scheduler thread keeps device steps in flight while
+new requests stream in from any number of submitting threads, and every
+request completes **out of order** through its own future.  Never
+started, it is the engine's synchronous ``submit``/``drain`` facade:
+``drain()`` calls :meth:`ContinuousScheduler.step_once` on the caller's
+thread until the queue is empty.
 
 Design:
 
 * **Batch formation at step boundaries.**  At each step the loop takes
   whatever is queued, ordered by (priority desc, deadline asc, admission
   order) — earliest-deadline-first within a priority class — coalesces
-  up to ``max_bucket`` samples, and pads to the same power-of-two bucket
-  ladder the sync scheduler uses, so the per-(backend, bucket) compile
-  cache and the autotuned kernel configs carry over unchanged.
+  up to ``max_bucket`` samples, and pads to the power-of-two bucket
+  ladder (``scheduler.power_of_two_buckets``), so the per-(backend,
+  bucket) compile cache and the autotuned kernel configs are shared by
+  every step of that size.
 * **Oversize chunking without clock restarts.**  A request larger than
   ``max_bucket`` is served in max-bucket chunks across consecutive
   steps; its queue time is attributed from the *original submit* to the
@@ -24,7 +26,7 @@ Design:
   rejects (types the result as shed, never raises) work that provably
   cannot meet its deadline given the samples queued ahead of it and the
   per-bucket step-time estimates (``backends.StepTimeEstimator`` — seeded
-  from the ``AutoSelector`` calibration, refined online from every step).
+  from one probe of the served backend, refined online from every step).
   Queued work whose deadline expires before it can launch is shed at the
   step boundary instead of being served late; work that still completes
   past its deadline (estimates are estimates) is returned **marked
@@ -61,8 +63,8 @@ batch and return a tuple of per-sample result arrays.
 sequences into one of a few (batch, length) shapes of equal token count
 and passes the real lengths along.  ``step_once()`` runs one scheduling
 decision plus one launch and collect synchronously, with no overlap —
-the unit tests drive it without threads, so ordering assertions are
-deterministic.
+the engine's sync ``drain()`` and the unit tests drive it without
+threads, so ordering assertions are deterministic.
 
 Each step is one ``steplog`` record: its phases, timed into that step's
 own record even while the other step's phases run between them, and
@@ -283,8 +285,8 @@ class ContinuousScheduler:
       collect: ``collect(handle) -> tuple[per-sample arrays]``, the second
         half: block until the results are ready and bring them to the
         host.
-      max_bucket / min_bucket: the power-of-two bucket ladder (identical
-        to the sync scheduler's, so compiles are shared).
+      max_bucket / min_bucket: the power-of-two bucket ladder (the
+        engine's, so compiles are shared).
       batching: how a step takes and pads samples; None =
         :class:`RowBuckets` over the ladder.  Admission estimates assume
         the ladder.
@@ -446,7 +448,8 @@ class ContinuousScheduler:
                                           shed=shed, rid=req.rid))
 
     # ------------------------------------------------------------------
-    # the step loop (scheduler thread, or step_once from tests)
+    # the step loop (scheduler thread, or step_once: the engine's sync
+    # drain() and the tests)
     # ------------------------------------------------------------------
 
     def _form_batch_locked(self, now: float):
@@ -454,11 +457,9 @@ class ContinuousScheduler:
 
         ``batch`` is a list of ``(request, lo, hi)`` payload row slices,
         as the batching takes them (:class:`RowBuckets` packs them
-        **densely**, which is what makes the continuous path's
-        steady-state samples/step match the sync facade's instead of
-        padding away ~half of each bucket on ragged sizes).  Requests
-        whose deadline can no longer be met even if launched immediately
-        are pulled out as ``expired``.
+        **densely**, so that ragged sizes do not pad away ~half of each
+        bucket).  Requests whose deadline can no longer be met even if
+        launched immediately are pulled out as ``expired``.
         """
         expired: list[AsyncRequest] = []
         if self._deadline_pending:
@@ -512,9 +513,10 @@ class ContinuousScheduler:
         launch, then collect at once.
 
         Returns the number of samples launched (0 if the queue was empty
-        after waiting ``wait_s``).  Tests call it directly for
-        deterministic ordering.  The step's phases are ``steplog`` spans
-        and one ring record.
+        after waiting ``wait_s``).  The engine's sync ``drain()`` calls it
+        until it returns 0; tests call it directly for deterministic
+        ordering.  The step's phases are ``steplog`` spans and one ring
+        record.
         """
         with self._cond:
             if not self._pending and wait_s > 0:

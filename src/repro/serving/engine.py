@@ -1,36 +1,39 @@
 """ServingEngine: one submit/drain API over both served families.
 
 DWN archs (``family == "dwn"``) serve batched classification of their
-spec's workload (``repro.workloads``: JSC, MNIST, ...) through a
-pluggable datapath backend (``serving.backends``), microbatched into
-power-of-two buckets (``serving.scheduler``), and sharded data-parallel
-across the host mesh with ``shard_map`` when a bucket divides the device
-count.  Every non-oracle backend is cross-checked bit-exactly against the
-``apply_hard`` float oracle at startup — the engine refuses to construct a
-broken datapath.
+spec's workload (``repro.workloads``: JSC, MNIST, ...) through one
+datapath backend (``serving.backends``), chosen at construction, packed
+into power-of-two buckets by the continuous-batching scheduler
+(``serving.continuous``), and sharded data-parallel across the host mesh
+with ``shard_map`` when a bucket divides the device count.  Every
+non-oracle backend is cross-checked bit-exactly against the
+``apply_hard`` float oracle at startup — the engine refuses to construct
+a broken datapath.
 
 LM archs serve the existing prefill + token-by-token decode loop (KV /
-SSM / LRU caches) one request per step, through the same queue and the
-same per-request queue/compute latency accounting.  With ``dwn_head=``
-an LM engine *also* serves a packed DWN classification head on its own
-backbone's pooled features (``classify`` requests), so one process
-serves LM decode and DWN classification side by side.  Classify requests
-go through the continuous-batching loop: prompts are right-padded into
-one of a few (batch, length) step shapes of ``step_tokens`` tokens
-(``continuous.TokenBuckets``), and one jitted step runs the backbone,
-pools each prompt's features over its real tokens, runs the head on the
-DWN serving backend and packs the answer (counts, predictions and
-features) into one buffer.
+SSM / LRU caches) one request per step, through the FIFO queue of
+``serving.scheduler``, with the same per-request queue/compute latency
+accounting.  With ``dwn_head=`` an LM engine *also* serves a packed DWN
+classification head on its own backbone's pooled features (``classify``
+requests), so one process serves LM decode and DWN classification side
+by side.  Classify requests go through the continuous-batching loop:
+prompts are right-padded into one of a few (batch, length) step shapes
+of ``step_tokens`` tokens (``continuous.TokenBuckets``), and one jitted
+step runs the backbone, pools each prompt's features over its real
+tokens, runs the head on the DWN serving backend and packs the answer
+(counts, predictions and features) into one buffer.
 
-Two serving modes share the datapath and its compile/autotune caches:
+DWN requests take one batching loop, ``ContinuousScheduler``, in either
+of two modes that share the datapath and its compile/autotune caches:
 
-* **sync facade** (``submit`` + ``drain``): closed-loop, admission-order
-  microbatching — unchanged semantics, bit-exact with the async path;
+* **sync facade** (``submit`` + ``drain``): the engine's own scheduler,
+  never started; ``drain()`` runs its steps one at a time on the calling
+  thread until the queue is empty, in admission order;
 * **continuous batching** (``serve()`` / ``submit_async``): a dedicated
   scheduler thread keeps steps in flight while requests stream in,
   results complete out of order via per-request futures, deadlines are
   enforced by SLO-aware admission control, and a bounded queue exerts
-  backpressure (``serving.continuous``).
+  backpressure.
 
 Usage (sync):
     engine = ServingEngine("dwn-jsc-sm", max_bucket=256)
@@ -50,7 +53,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import os
+import sys
 import time
 from typing import Any, Callable, Sequence
 
@@ -64,11 +67,9 @@ from ..models import api
 from ..runtime.straggler import StragglerMonitor
 from ..sharding.partition import Partitioner
 from ..launch.mesh import make_data_mesh, make_host_mesh
-from .backends import (AutoSelector, BoundBackend, DWNModelBundle,
-                       StepTimeEstimator, available_backends,
-                       estimator_from_calibration, get_backend,
-                       pack_answer, time_backend_step, unpack_answer,
-                       verify_backends)
+from .backends import (BoundBackend, DWNModelBundle, StepTimeEstimator,
+                       available_backends, get_backend, pack_answer,
+                       time_backend_step, unpack_answer, verify_backends)
 from . import steplog
 from .continuous import (AsyncRequest, ContinuousScheduler, SLOConfig,
                          TokenBuckets)
@@ -101,13 +102,11 @@ class ServingEngine:
         the artifact lifecycle itself), or a ``repro.dwn.DWNArtifact``
         (served as-is; trained/frozen state is reused, missing stages
         are completed in place).
-      backend: DWN datapath backend name.  ``None`` resolves from the
+      backend: DWN datapath backend name, one of the registered
+        backends; every bucket serves on it.  ``None`` resolves from the
         spec's validated ``datapath`` field (legacy archs bridge through
         ``DWNSpec.from_arch``, which keeps the old fused-packed
-        fallback).  ``"auto"`` calibrates every bit-exact backend per
-        batch bucket at startup and serves each bucket on the fastest
-        (see ``backends.AutoSelector``); explicit names remain the
-        override.
+        fallback).
       max_bucket / min_bucket: the power-of-two batch-bucket ladder (LM
         engines with a ``dwn_head``: the ladder of classify prompt
         lengths).
@@ -120,8 +119,7 @@ class ServingEngine:
         startup (``backends.autotune_model``): each bucket serves the
         fastest (rows, LUTs)-per-step fused config, cache-hit from
         the persistent config cache (docs/autotune.md) or timed once on
-        miss.  ``None`` (default) resolves to True exactly when
-        ``backend == "auto"``; ``REPRO_AUTOTUNE=0`` force-disables.
+        miss.
       reduced: LM archs: serve the tiny same-family variant.  DWN archs:
         kept for CLI symmetry (the model is never shrunk — the datapath
         is the thing being served; callers shrink the request volume).
@@ -144,7 +142,7 @@ class ServingEngine:
                  backend: str | None = None,
                  max_bucket: int = 256, min_bucket: int = 8,
                  data_parallel: bool = True, verify: bool = True,
-                 autotune: bool | None = None,
+                 autotune: bool = False,
                  reduced: bool = False, n_train: int = 2000,
                  seed: int = 0, prompt_len: int = 32, gen: int = 16,
                  model_parallel: int = 1, params=None, dwn_head=None,
@@ -172,12 +170,13 @@ class ServingEngine:
             max_bucket=max_bucket, min_bucket=min(min_bucket, max_bucket))
         self.bit_exact: dict[str, bool] = {}
         self.tuned_configs: dict = {}
-        self._autotune_arg = autotune
         self._drain_wall = 0.0
         self._lm_stats: list[tuple[float, float]] = []
         #: anomalous step times surface as counters in report(); fed by
-        #: both the sync drain loop and the continuous-batching loop
+        #: every DWN and classify step, sync or continuous
         self.straggler = StragglerMonitor()
+        #: DWN engines: the sync facade's scheduler (``_init_dwn``)
+        self._sync: ContinuousScheduler | None = None
         self._cont: ContinuousScheduler | None = None
         self.estimator: StepTimeEstimator | None = None
         #: slim async requests from finished serve() sessions + the last
@@ -192,7 +191,8 @@ class ServingEngine:
             assert dwn_head is None, \
                 "dwn_head attaches to an LM engine (the head rides the " \
                 "backbone); DWN archs already serve classification"
-            self._init_dwn(cfg, backend, n_train, data_parallel, verify)
+            self._init_dwn(cfg, backend, n_train, data_parallel, verify,
+                           autotune)
         else:
             if reduced:
                 self.cfg = cfg = cfg.reduced()
@@ -205,9 +205,16 @@ class ServingEngine:
     # ------------------------------------------------------------------
 
     def _init_dwn(self, cfg: ArchConfig, backend: str | None,
-                  n_train: int, data_parallel: bool, verify: bool):
+                  n_train: int, data_parallel: bool, verify: bool,
+                  autotune: bool):
         from ..dwn import DWNArtifact
         from ..workloads import load_workload
+        # the spec validated its datapath at construction; an override is
+        # checked here, before any work
+        backend = self.spec.datapath if backend is None else backend
+        if backend not in available_backends():
+            raise ValueError(f"unknown serving backend {backend!r}; "
+                             f"registered: {available_backends()}")
         self.data = load_workload(self.spec.workload, n_train,
                                   max(self.scheduler.max_bucket, 512),
                                   seed=self.seed)
@@ -231,18 +238,8 @@ class ServingEngine:
         self.backends = {name: BoundBackend(get_backend(name), self.model,
                                             wrap=wrap)
                          for name in available_backends()}
-        if backend is None:
-            # the spec's datapath is validated at construction, so no
-            # arch-name-suffix parsing or registry fallback is needed
-            backend = self.spec.datapath
-        self.auto: AutoSelector | None = None
         probe = self.data.x_test[:self.scheduler.max_bucket]
-        do_tune = self._autotune_arg
-        if do_tune is None:
-            do_tune = backend == "auto"
-        if os.environ.get("REPRO_AUTOTUNE") == "0":
-            do_tune = False
-        if do_tune:
+        if autotune:
             # tune BEFORE anything compiles: BoundBackend jits one entry
             # per bucket and each trace binds the tuned config it sees.
             # The startup verification below then cross-checks the tuned
@@ -251,30 +248,24 @@ class ServingEngine:
             self.tuned_configs = autotune_model(
                 self.model, self.scheduler.buckets, probe,
                 spec_fingerprint=self.spec.fingerprint())
-        if verify or backend == "auto":
+        if verify:
             # probe at the largest bucket: the multi-block grid path that
             # serving actually uses is the one cross-checked, and the
-            # probe's compile is the one the serve loop reuses.  Auto
-            # selection always verifies: it only picks among bit-exact
-            # datapaths.
+            # probe's compile is the one the serve loop reuses
             self.bit_exact = verify_backends(
                 self.model, list(self.backends.values()), probe)
-        if backend == "auto":
-            # calibrate the whole bucket ladder at startup so no timed
-            # request ever pays calibration (compiles + timing probes)
-            # inside its compute window; the per-bucket compiles are the
-            # same ones a ragged stream would pay lazily anyway
-            self.auto = AutoSelector(self.backends, self.bit_exact)
-            for bucket in self.scheduler.buckets:
-                self.auto.calibrate(jnp.asarray(probe[:bucket]))
-            self.backend = self.backends[
-                self.auto.choice[self.scheduler.max_bucket]]
-        else:
-            self.backend = self.backends[backend]
-        # survive use_backend() round-trips: pinning a backend then
-        # returning to "auto" restores this calibrated selector instead
-        # of re-timing the ladder
-        self._auto_saved = self.auto
+        self.backend = self.backends[backend]
+        #: the sync facade's scheduler: never started, drain() steps it on
+        #: the caller's thread; no deadlines, so its order is admission
+        #: order, and no bound on its queue, which drain() empties
+        self._sync = ContinuousScheduler(
+            self._dwn_launch, self._dwn_step,
+            max_bucket=self.scheduler.max_bucket,
+            min_bucket=self.scheduler.min_bucket,
+            slo=SLOConfig(max_queue_samples=sys.maxsize),
+            monitor=self.straggler)
+        #: requests the sync facade resolved since the last drain()
+        self._sync_done: list[AsyncRequest] = []
 
     def _shard_wrap(self, fn, bucket: int):
         """shard_map a backend step over the ("data",) mesh for one bucket;
@@ -296,22 +287,9 @@ class ServingEngine:
                              check_vma=False), self.n_data
 
     def use_backend(self, name: str) -> None:
-        """Switch the active DWN datapath (compile caches are kept).
-
-        ``"auto"`` switches to per-bucket auto-selection among the
-        bit-exact backends (requires the startup verification to have
-        run); any registered backend name pins that datapath.
-        """
+        """Pin the DWN datapath to the registered backend ``name``
+        (compile caches are kept)."""
         assert self.family == "dwn"
-        if name == "auto":
-            if self.auto is None:
-                assert self.bit_exact, "auto-select needs verify=True"
-                saved = getattr(self, "_auto_saved", None)
-                self.auto = saved if saved is not None \
-                    else AutoSelector(self.backends, self.bit_exact)
-                self._auto_saved = self.auto
-            return
-        self.auto = None
         self.backend = self.backends[name]
 
     def warmup(self, size: int | None = None) -> None:
@@ -345,8 +323,7 @@ class ServingEngine:
         with steplog.phase("h2d"):
             xd = jnp.asarray(x)
         with steplog.phase("dispatch"):
-            backend = (self.auto.backend_for(xd) if self.auto is not None
-                       else self.backend)
+            backend = self.backend
             step = backend.step_for(bucket)
             before = backend.compiles[bucket]
             answer = step(xd)
@@ -568,11 +545,15 @@ class ServingEngine:
 
         Returns the queued :class:`Request` (latency fields filled in by
         the drain that serves it; ``queue_ms``/``compute_ms`` are
-        milliseconds).
+        milliseconds).  DWN rows are packed densely into steps, so a
+        request may share a step with others or span several.
         """
         if self.family == "dwn":
             payload = np.asarray(payload)
-            return self.scheduler.submit(payload, payload.shape[0])
+            req = self._sync.submit(payload, payload.shape[0])
+            req.future.add_done_callback(
+                lambda _, r=req: self._sync_done.append(r))
+            return req
         if payload.get("classify"):
             raise ValueError("classify requests are served by the "
                              "continuous loop: engine.serve() and "
@@ -581,10 +562,13 @@ class ServingEngine:
         return self.scheduler.submit(payload, size)
 
     def drain(self) -> list[Request]:
-        """Serve every queued request; blocks until all results ready."""
+        """Serve every queued request, one step at a time on this thread;
+        returns them in completion order once all results are ready."""
         t0 = time.perf_counter()
         if self.family == "dwn":
-            done = self.scheduler.drain_batched(self._monitored_step)
+            while self._sync.step_once():
+                pass
+            done, self._sync_done = self._sync_done, []
         else:
             done = self.scheduler.drain_serial(self._lm_step)
             self._lm_stats.extend((r.result["prefill_s"],
@@ -592,15 +576,6 @@ class ServingEngine:
                                   for r in done)
         self._drain_wall += time.perf_counter() - t0
         return done
-
-    def _monitored_step(self, x: np.ndarray):
-        """The DWN step with its wall time fed to the straggler monitor
-        (the sync drain loop's half of the satellite wiring; the
-        continuous loop reports through the same monitor)."""
-        t0 = time.perf_counter()
-        out = self._dwn_step(x)
-        self.straggler.report(time.perf_counter() - t0)
-        return out
 
     # ------------------------------------------------------------------
     # continuous-batching async API (DWN classification, and the DWN head)
@@ -613,8 +588,7 @@ class ServingEngine:
         out of order via their futures; batch formation happens at step
         boundaries over the same bucket ladder (compile + autotune caches
         shared with the sync facade).  Admission control's step-time
-        estimates seed from the ``AutoSelector`` calibration when
-        ``backend="auto"``, else from one probe of the active backend at
+        estimates seed from one probe of the served backend at
         ``max_bucket``; every step refines them online.  An LM engine
         serves its ``dwn_head``'s classify requests here, in the
         (batch, length) step shapes of ``head_buckets``; deadlines are
@@ -630,15 +604,11 @@ class ServingEngine:
             self._cont.start()
             return
         if self.estimator is None:
-            if self.auto is not None:
-                self.estimator = estimator_from_calibration(self.auto)
-            else:
-                self.estimator = StepTimeEstimator()
-                probe = jnp.asarray(
-                    self.data.x_test[:self.scheduler.max_bucket])
-                self.estimator.seed(
-                    self.scheduler.max_bucket,
-                    time_backend_step(self.backend, probe, iters=2))
+            self.estimator = StepTimeEstimator()
+            probe = jnp.asarray(self.data.x_test[:self.scheduler.max_bucket])
+            self.estimator.seed(
+                self.scheduler.max_bucket,
+                time_backend_step(self.backend, probe, iters=2))
         self._cont = ContinuousScheduler(
             self._dwn_launch, self._dwn_step,
             max_bucket=self.scheduler.max_bucket,
@@ -741,8 +711,12 @@ class ServingEngine:
             async_counters = self._cont.counters()
         async_ok = [r for r in async_all if r.shed is None]
         shed = [r for r in async_all if r.shed is not None]
-        reqs: Sequence[Request] = (list(self.scheduler.completed)
-                                   + async_ok)
+        # the sync facade's queue: DWN rows in its scheduler, LM decode in
+        # the FIFO; the sync scheduler has no deadlines, so sheds nothing
+        sync = self._sync
+        reqs: Sequence[Request] = (
+            list(self.scheduler.completed)
+            + (list(sync.completed) if sync is not None else []) + async_ok)
         served = sum(r.size for r in reqs)
         wall = self._drain_wall + async_counters.get("session_wall_s", 0.0)
         shed_by: dict[str, int] = {}
@@ -759,9 +733,11 @@ class ServingEngine:
             "latency": latency_stats(list(reqs)),
             "queue_depth": {
                 "pending": self.scheduler.pending
+                + (sync.pending if sync is not None else 0)
                 + (self._cont.pending if self._cont is not None else 0),
                 "max_requests": max(
                     self.scheduler.max_pending,
+                    sync.max_depth_requests if sync is not None else 0,
                     async_counters.get("queue_depth_max_requests", 0)),
             },
             "shed": {
@@ -785,8 +761,7 @@ class ServingEngine:
         if self.family == "dwn":
             out.update({
                 "mode": "dwn-classify",
-                "datapath": ("auto" if self.auto is not None
-                             else self.backend.name),
+                "datapath": self.backend.name,
                 "backends": available_backends(),
                 "bit_exact_vs_oracle": self.bit_exact,
                 "buckets": list(self.scheduler.buckets),
@@ -803,16 +778,6 @@ class ServingEngine:
             if self.tuned_configs:
                 out["autotune"] = {int(b): cfg.to_dict()
                                    for b, cfg in self.tuned_configs.items()}
-            if self.auto is not None:
-                out["auto"] = {
-                    "choice": dict(self.auto.choice),
-                    "configs": {b: (cfg.to_dict() if cfg else None)
-                                for b, cfg in self.auto.configs.items()},
-                    "timings_ms": {b: {n: round(t * 1e3, 3)
-                                       for n, t in times.items()}
-                                   for b, times in
-                                   self.auto.timings.items()},
-                }
         else:
             out.update({
                 "mode": "lm-generate",

@@ -1,29 +1,26 @@
-"""Request queue with dynamic microbatching into power-of-two buckets.
+"""The power-of-two bucket ladder, requests and their latency
+accounting, and the FIFO queue that LM decode is served from.
 
 Serving traffic is ragged: requests carry anywhere from one sample to
 thousands.  Compiling one XLA executable per observed batch size would
 recompile constantly, and padding everything to one giant batch wastes
-compute on small requests.  The middle ground implemented here:
-
-* requests are drained strictly in **admission order** (FIFO — no
-  reordering, so latency is predictable and starvation impossible);
-* consecutive requests are **coalesced** into a microbatch as long as the
-  combined sample count fits the largest bucket;
-* the microbatch is **padded up to the smallest power-of-two bucket** that
-  holds it, so the set of shapes XLA ever sees is the fixed bucket ladder
-  ``{min_bucket, 2*min_bucket, ..., max_bucket}`` — bounding JIT
-  recompiles to at most one per (backend, bucket);
-* oversized requests (> max_bucket) are split into max_bucket chunks.
+compute on small requests.  Every batched step is therefore padded up
+to the smallest power-of-two bucket that holds it, so the set of shapes
+XLA ever sees is the fixed ladder ``{min_bucket, 2*min_bucket, ...,
+max_bucket}`` — bounding JIT recompiles to at most one per (backend,
+bucket).  The batching itself (dense packing, oversize requests in
+max-bucket chunks, one loop for the sync and the continuous mode) lives
+in ``serving.continuous``.
 
 Every request records wall-clock (``time.perf_counter`` — monotonic, the
-correct timer for sub-ms latencies) for **queue** time (submit -> step
-launch) and **compute** time (step launch -> results ready) separately,
-so a serving report can distinguish "waiting behind other traffic" from
-"the datapath is slow".
+correct timer for sub-ms latencies) for **queue** time (submit -> first
+step's start) and **compute** time (first step's start -> results
+ready) separately, so a serving report can distinguish "waiting behind
+other traffic" from "the datapath is slow".
 
-The scheduler is model-agnostic: ``drain_batched`` is for array payloads
-that coalesce along a batch axis (DWN feature batches); ``drain_serial``
-is for opaque payloads served one request per step (LM prefill/decode).
+:class:`MicrobatchScheduler` holds the ladder an engine serves on and a
+FIFO of opaque payloads served one request per step (``drain_serial``:
+LM prefill/decode), in strict admission order.
 """
 
 from __future__ import annotations
@@ -34,8 +31,6 @@ from collections import deque
 from typing import Any, Callable
 
 import numpy as np
-
-from . import steplog
 
 
 def next_pow2(n: int) -> int:
@@ -94,11 +89,11 @@ class Request:
 
 
 class MicrobatchScheduler:
-    """Admission-order FIFO with power-of-two batch bucketing.
+    """The power-of-two bucket ladder and an admission-order FIFO served
+    one request per step.
 
     ``timer`` is injectable (default ``time.perf_counter``) so latency
-    attribution — queue time from *original submit* even across oversize
-    chunk splits — is testable with a deterministic clock.
+    attribution is testable with a deterministic clock.
     """
 
     def __init__(self, *, max_bucket: int = 256, min_bucket: int = 8,
@@ -116,10 +111,6 @@ class MicrobatchScheduler:
         #: Full requests — payloads and results included — are returned to
         #: the caller by the drain call that served them.
         self.completed: list[Request] = []
-
-    def _record(self, done: list[Request]) -> None:
-        self.completed.extend(
-            dataclasses.replace(r, payload=None, result=None) for r in done)
 
     # -- admission ----------------------------------------------------------
 
@@ -143,91 +134,11 @@ class MicrobatchScheduler:
 
     # -- draining -----------------------------------------------------------
 
-    def _take_microbatch(self) -> list[Request]:
-        """Pop the next admission-order run of requests fitting max_bucket."""
-        group = [self._queue.popleft()]
-        total = group[0].size            # <= max_bucket: oversize heads
-        # take the split path in drain_batched before reaching here
-        while self._queue and total + self._queue[0].size <= self.max_bucket:
-            nxt = self._queue.popleft()
-            group.append(nxt)
-            total += nxt.size
-        return group
-
-    def _run_chunk(self, step: Callable, xs: list[np.ndarray],
-                   total: int):
-        """Pad a coalesced chunk to its bucket and run one step, recorded
-        in ``steplog`` like the continuous loop's."""
-        with steplog.step() as rec:
-            with steplog.phase("batch"):
-                bucket = self.bucket_for(total)
-                x = (np.concatenate(xs, axis=0) if len(xs) > 1
-                     else np.asarray(xs[0]))
-                if bucket > total:
-                    pad = np.zeros((bucket - total,) + x.shape[1:], x.dtype)
-                    x = np.concatenate([x, pad], axis=0)
-                rec.rows, rec.bucket, rec.requests = total, bucket, len(xs)
-            out = step(x)
-            with steplog.phase("resolve"):
-                return bucket, [np.asarray(o)[:total] for o in out]
-
-    def drain_batched(self, step: Callable) -> list[Request]:
-        """Serve every queued request; returns them in completion order.
-
-        ``step(x)`` takes a bucket-padded (bucket, ...) array and returns a
-        tuple of per-sample result arrays; it must block until the results
-        are ready (the scheduler's compute timing is the step call).
-        """
-        done: list[Request] = []
-        while self._queue:
-            head = self._queue[0]
-            if head.size > self.max_bucket:
-                # oversize: serve alone, split into max_bucket chunks.
-                # The clock does NOT restart per chunk: t_start is
-                # stamped once at first step launch (before the payload
-                # conversion, which is compute-side work — the group path
-                # converts inside _run_chunk, after its t_start), so
-                # queue_ms spans original submit -> first launch and
-                # compute_ms spans every chunk.
-                req = self._queue.popleft()
-                req.t_start = self._timer()
-                x = np.asarray(req.payload)
-                chunks, buckets = [], []
-                for i in range(0, req.size, self.max_bucket):
-                    bucket, outs = self._run_chunk(
-                        step, [x[i:i + self.max_bucket]],
-                        min(self.max_bucket, req.size - i))
-                    buckets.append(bucket)
-                    chunks.append(outs)
-                req.result = tuple(np.concatenate(parts, axis=0)
-                                   for parts in zip(*chunks))
-                req.t_done = self._timer()
-                req.buckets = tuple(buckets)
-                done.append(req)
-                continue
-            group = self._take_microbatch()
-            total = sum(r.size for r in group)
-            t_start = self._timer()
-            for r in group:
-                r.t_start = t_start
-            bucket, outs = self._run_chunk(
-                step, [np.asarray(r.payload) for r in group], total)
-            t_done = self._timer()
-            off = 0
-            for r in group:
-                r.result = tuple(o[off:off + r.size] for o in outs)
-                r.t_done = t_done
-                r.buckets = (bucket,)
-                off += r.size
-                done.append(r)
-        self._record(done)
-        return done
-
     def drain_serial(self, step: Callable) -> list[Request]:
         """Serve queued requests one per step (LM prefill/decode path).
 
         ``step(payload)`` returns the request's result and blocks until
-        ready.  Same queue/compute accounting as the batched path.
+        ready; returns the requests in completion order.
         """
         done: list[Request] = []
         while self._queue:
@@ -237,7 +148,8 @@ class MicrobatchScheduler:
             req.t_done = self._timer()
             req.buckets = (req.size,)
             done.append(req)
-        self._record(done)
+        self.completed.extend(
+            dataclasses.replace(r, payload=None, result=None) for r in done)
         return done
 
 

@@ -8,7 +8,7 @@ span                covers
 ``serve.batch``     batch formation under the scheduler's lock, row
                     slicing, concatenation, padding to the bucket
 ``serve.h2d``       the host-to-device copy of the padded batch
-``serve.dispatch``  backend selection, the jitted call (a compile
+``serve.dispatch``  the jitted call on the served backend (a compile
                     lands here) and issuing the copy of its answer
 ``serve.device``    waiting for the device (``block_until_ready``)
 ``serve.d2h``       the one device-to-host copy of the step's packed
@@ -51,7 +51,6 @@ always on and writes nothing to disk.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import threading
 import time
@@ -287,20 +286,6 @@ class Step(span):
         super().__exit__(None, None, None)
 
 
-@contextlib.contextmanager
-def step():
-    """``with step() as rec:`` one step run start to end on this thread:
-    a :class:`Step`, current inside the block and closed at its end."""
-    rec = Step()
-    ok = False
-    try:
-        with rec:
-            yield rec
-        ok = True
-    finally:
-        rec.close(ok)
-
-
 def add_compiles(n: int) -> None:
     """Count ``n`` XLA traces against the step current on this thread."""
     rec = _local.step
@@ -318,4 +303,4 @@ def add_d2h_copies(n: int) -> None:
 
 __all__ = ["CAPACITY", "PHASES", "RING", "Records", "Ring", "Step",
            "add_compiles", "add_d2h_copies", "last", "mark", "phase",
-           "since", "span", "step"]
+           "since", "span"]

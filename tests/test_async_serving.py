@@ -1,6 +1,6 @@
 """Continuous-batching serving: scheduling, SLO control, load generator.
 
-Covers the async layer added on top of the sync microbatch scheduler:
+Covers the batching loop that both serving modes run:
 
 * ContinuousScheduler, no threads/jax: ``step_once`` is driven directly
   with a fake clock and a tagged step fn, so completion order, dense
@@ -224,20 +224,28 @@ def test_stop_without_drain_sheds_shutdown():
     assert a.future.result(timeout=5.0).ok   # in-flight work still lands
 
 
-def test_queue_time_attributed_from_original_submit_across_chunks():
+@pytest.mark.parametrize("sizes,max_bucket,buckets,compute_ms", [
+    ((20,), 8, [(8, 8, 8)], 3_000.0),
+    ((100,), 32, [(32, 32, 32, 8)], 4_000.0),
+    ((4, 8), 32, [(16,), (16,)], 1_000.0),
+], ids=["oversize", "oversize-padded-tail", "coalesced"])
+def test_queue_time_attributed_from_original_submit_across_chunks(
+        sizes, max_bucket, buckets, compute_ms):
     clock = FakeClock()
     sched = ContinuousScheduler(
-        _tag_step(clock, step_s=1.0), _identity, max_bucket=8,
+        _tag_step(clock, step_s=1.0), _identity, max_bucket=max_bucket,
         min_bucket=8, timer=clock)
-    req = sched.submit(_tag_payload(0, 20))
+    reqs = [sched.submit(_tag_payload(i, n)) for i, n in enumerate(sizes)]
     clock.advance(5.0)                     # waits 5s before the loop runs
-    while not req.future.done():
-        sched.step_once()
+    while sched.step_once():
+        pass
     # queue time = submit -> first chunk launch, exactly; the clock never
-    # restarts for chunks 2 and 3, whose time lands in compute
-    assert req.queue_ms == pytest.approx(5_000.0)
-    assert req.compute_ms == pytest.approx(3_000.0)
-    assert req.buckets == (8, 8, 8)
+    # restarts for later chunks, whose time lands in compute; requests
+    # coalesced into one step share its start and its compute time
+    for req, want in zip(reqs, buckets):
+        assert req.queue_ms == pytest.approx(5_000.0)
+        assert req.compute_ms == pytest.approx(compute_ms)
+        assert req.buckets == want
 
 
 def test_estimator_and_counters_updated_per_step():
