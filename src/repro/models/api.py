@@ -193,12 +193,34 @@ def make_prefill(cfg: ArchConfig, tp: int = 16, *, cache_len: int | None = None)
 def logit_columns(params, cfg: ArchConfig, tokens, cols: int, *,
                   tp: int = 16):
     """The first ``cols`` columns of the full-sequence logits (B, S, cols):
-    what a classification head pools.  A family module may compute them
-    without the rest of the vocabulary (``logit_columns``)."""
+    what a classification head pools (:func:`logit_columns_and_fallbacks`
+    on unpadded prompts, without its count)."""
+    return logit_columns_and_fallbacks(params, cfg, tokens, cols, tp=tp)[0]
+
+
+def logit_columns_and_fallbacks(params, cfg: ArchConfig, tokens, cols: int,
+                                *, tp: int = 16, token_mask=None):
+    """:func:`logit_columns` and an int32 count of the MoE layer calls
+    whose held experts fell back to the worst-case buffer.
+
+    A family module may compute the columns without the rest of the
+    vocabulary (its ``logit_columns``, which returns both); it then also
+    takes ``token_mask`` (B, S), the real tokens of right-padded prompts
+    (None: all), and routes only those through its experts.  Other
+    families ignore the mask and count 0."""
     mod = module_for(cfg)
     if hasattr(mod, "logit_columns"):
-        return mod.logit_columns(params, cfg, tokens, cols, tp=tp)
-    return mod.forward(params, cfg, {"tokens": tokens}, tp=tp)[0][..., :cols]
+        return mod.logit_columns(params, cfg, tokens, cols, tp=tp,
+                                 token_mask=token_mask)
+    logits = mod.forward(params, cfg, {"tokens": tokens}, tp=tp)[0]
+    return logits[..., :cols], jnp.zeros((), jnp.int32)
+
+
+def held_moe_layers(cfg: ArchConfig) -> int:
+    """MoE layer calls of one forward on the held-experts path, those
+    :func:`logit_columns_and_fallbacks` counts: every layer of the
+    ``ssm_moe`` family, none elsewhere."""
+    return cfg.num_layers if cfg.family == "ssm_moe" else 0
 
 
 def make_decode_step(cfg: ArchConfig, tp: int = 16):

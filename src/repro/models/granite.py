@@ -15,7 +15,14 @@ is GQA with no positional embedding and softmax scale
 router's ``cfg.num_experts`` experts (0: all), the first ones, and gives
 their part of the layer with nothing dropped (``layers.moe_apply`` with
 ``first_expert``): one device's share under expert parallelism, run
-without the exchange.
+without the exchange.  The held experts run on a sorted buffer sized to
+twice the pairs a layer routes to them on average
+(``layers.held_rows``), and on the worst-case buffer, exactly as before,
+in a layer call whose pairs overflow it; :func:`hidden` counts those
+calls.  With a ``token_mask`` only real tokens route: padding positions
+route no pair and their MoE output is 0.  Every mixer is causal, so
+right padding never reaches a real position and the mask changes no
+answer there.
 
 Layers are kinds apart, so parameters are a list with one dict per layer
 and the forward is unrolled.  Named scopes: ``granite.mamba``,
@@ -23,7 +30,8 @@ and the forward is unrolled.  Named scopes: ``granite.mamba``,
 
 The serving path is whole-prompt: :func:`logit_columns` gives the first
 columns of the logits at every position, the input of the DWN head's
-pooling.  There is no decode cache.
+pooling, and the count of MoE layer calls that fell back to the
+worst-case buffer.  There is no decode cache.
 """
 
 from __future__ import annotations
@@ -102,7 +110,7 @@ def _attention(p, cfg: ArchConfig, h: Array, layout: L.HeadLayout) -> Array:
 
 
 def _layer(lp, cfg: ArchConfig, kind: str, x: Array,
-           layout: L.HeadLayout):
+           layout: L.HeadLayout, token_mask: Array | None):
     r = cfg.residual_multiplier
     h = L.rms_norm(x, lp["ln1"]["scale"], cfg.norm_eps)
     if kind == "mamba":
@@ -114,15 +122,19 @@ def _layer(lp, cfg: ArchConfig, kind: str, x: Array,
     x = x + r * out
     h = L.rms_norm(x, lp["ln2"]["scale"], cfg.norm_eps)
     with jax.named_scope("granite.moe"):
-        y, aux = L.moe_apply(lp["moe"], h, top_k=cfg.top_k, first_expert=0)
+        y, aux, fallback = L.moe_apply(lp["moe"], h, top_k=cfg.top_k,
+                                       first_expert=0, token_mask=token_mask)
     with jax.named_scope("granite.shared_expert"):
         y = y + L.swiglu(lp["shared"], h)
-    return x + r * y, aux
+    return x + r * y, aux, fallback
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "tp"))
-def hidden(params, cfg: ArchConfig, tokens: Array, *, tp: int = 16):
-    """Final-normalised hidden states (B, S, D) and the summed aux loss.
+def hidden(params, cfg: ArchConfig, tokens: Array, *, tp: int = 16,
+           token_mask: Array | None = None):
+    """Final-normalised hidden states (B, S, D), the summed aux loss and
+    the count of layers whose held experts ran on the worst-case buffer
+    (int32).  ``token_mask`` (B, S): the real tokens, None for all.
 
     Jitted by itself so that an eager caller runs one compiled program
     rather than every op of the unrolled layers; inside a jitted step it
@@ -130,10 +142,12 @@ def hidden(params, cfg: ArchConfig, tokens: Array, *, tp: int = 16):
     layout = _layout(cfg, tp)
     x = L.embed(params["embed"], tokens) * cfg.embedding_multiplier
     aux = jnp.zeros((), jnp.float32)
+    fallbacks = jnp.zeros((), jnp.int32)
     for lp, kind in zip(params["layers"], cfg.layer_types):
-        x, a = _layer(lp, cfg, kind, x, layout)
-        aux = aux + a
-    return L.rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps), aux
+        x, a, f = _layer(lp, cfg, kind, x, layout, token_mask)
+        aux, fallbacks = aux + a, fallbacks + f
+    h = L.rms_norm(x, params["final_norm"]["scale"], cfg.norm_eps)
+    return h, aux, fallbacks
 
 
 def _logits(params, cfg: ArchConfig, h: Array, cols: int | None = None):
@@ -147,15 +161,17 @@ def _logits(params, cfg: ArchConfig, h: Array, cols: int | None = None):
 
 def forward(params, cfg: ArchConfig, batch, *, tp: int = 16):
     """Full-sequence forward -> (logits (B, S, Vp), aux, None)."""
-    h, aux = hidden(params, cfg, batch["tokens"], tp=tp)
+    h, aux, _ = hidden(params, cfg, batch["tokens"], tp=tp)
     return _logits(params, cfg, h), aux, None
 
 
 def logit_columns(params, cfg: ArchConfig, tokens: Array, cols: int, *,
-                  tp: int = 16) -> Array:
-    """``forward``'s logits[..., :cols] without the rest of the vocab."""
-    h, _ = hidden(params, cfg, tokens, tp=tp)
-    return _logits(params, cfg, h, cols)
+                  tp: int = 16, token_mask: Array | None = None):
+    """``forward``'s logits[..., :cols] without the rest of the vocab, and
+    :func:`hidden`'s count of worst-case buffers."""
+    h, _, fallbacks = hidden(params, cfg, tokens, tp=tp,
+                             token_mask=token_mask)
+    return _logits(params, cfg, h, cols), fallbacks
 
 
 def loss_fn(params, cfg: ArchConfig, batch, *, tp: int = 16) -> Array:

@@ -576,8 +576,10 @@ def axes_moe(*, ep: bool = False):
 
 def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
               min_capacity: int = 4, num_real_experts: int = 0,
-              ep: bool = False, first_expert: int | None = None):
-    """Token-choice top-k MoE.  Returns (y, aux_loss).
+              ep: bool = False, first_expert: int | None = None,
+              token_mask: Array | None = None):
+    """Token-choice top-k MoE.  Returns (y, aux_loss), and with
+    ``first_expert`` (y, aux_loss, fallback).
 
     x: (B, S, D).  The router scores every expert; each token keeps its
     ``top_k`` and their softmax weights renormalised over the k (the same
@@ -595,8 +597,15 @@ def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
     nothing dropped.  ``p``'s expert weights hold the ``n`` experts
     ``e0 .. e0+n-1`` of the router's ``E``; ``y`` is what those experts
     give for the tokens routed to them (:func:`_moe_held`).  The parts
-    of all ``E / n`` shares sum to the whole layer.
+    of all ``E / n`` shares sum to the whole layer.  ``token_mask``
+    (B, S), None meaning every token is real: a token outside it routes
+    no pair here and its ``y`` is 0.  ``fallback`` (int32, 0 or 1) is 1
+    where the routed pairs overflowed the sized buffer and the layer ran
+    on the worst-case one (:func:`held_rows`).
     """
+    if token_mask is not None and first_expert is None:
+        raise ValueError("token_mask applies to the held-experts path "
+                         "(first_expert=) only")
     B, S, D = x.shape
     E = p["router"].shape[-1]
     E_real = num_real_experts or E
@@ -614,8 +623,10 @@ def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
         gate_vals.sum(-1, keepdims=True), 1e-9)
     frac_probs = jnp.mean(probs, axis=(0, 1))
     if first_expert is not None:
-        y = _moe_held(p, x, expert_idx, gate_vals, first_expert)
-        return y.astype(x.dtype), _aux_loss(expert_idx, frac_probs, E)
+        y, fallback = _moe_held(p, x, expert_idx, gate_vals, first_expert,
+                                token_mask)
+        return (y.astype(x.dtype), _aux_loss(expert_idx, frac_probs, E),
+                fallback)
 
     # position of each (token, k) within its expert, token-major order
     flat_e = expert_idx.reshape(B, S * top_k)                    # (B,T)
@@ -688,41 +699,81 @@ def _aux_loss(expert_idx: Array, frac_probs: Array, E: int) -> Array:
     return E * jnp.sum(frac_tokens * frac_probs)
 
 
+#: the held experts' sorted buffer is a whole number of these rows
+HELD_ROWS_ALIGN = 512
+
+
+def held_rows(T: int, k: int, n: int, E: int) -> int:
+    """Rows of the held experts' sorted buffer for ``T`` tokens that each
+    choose ``k`` of the router's ``E`` experts, ``n`` of them held here.
+
+    Twice the mean count of pairs routed here, ``T k n / E``, rounded up
+    to :data:`HELD_ROWS_ALIGN`, and never more than the worst case
+    ``T * min(k, n)`` (every token picking every held expert it can).
+    """
+    return min(_round_up(-(-2 * T * k * n // E), HELD_ROWS_ALIGN),
+               T * min(k, n))
+
+
 def _moe_held(p, x: Array, expert_idx: Array, gate_vals: Array,
-              first_expert: int) -> Array:
-    """The held experts' part of the layer, dropless: (B, S, D) float32.
+              first_expert: int, token_mask: Array | None = None):
+    """The held experts' part of the layer, dropless: ((B, S, D) float32,
+    int32 fallback).
 
     Every (token, choice) pair routed to a held expert is computed.  The
     pairs are sorted by expert and run through grouped matrix products
-    (``lax.ragged_dot``) over the held experts' weights.  A token picks
-    each expert at most once, so at most ``T * min(k, n)`` pairs can land
-    here: that is the static size of the sorted buffer, and rows past the
-    pairs routed here are masked out.
+    (``lax.ragged_dot``) over the held experts' weights.  A pair of a
+    token outside ``token_mask`` sorts with the pairs routed to experts
+    not held here, past the rest, and is never computed.
+
+    The sorted buffer has :func:`held_rows` rows, a size fixed by the
+    shape, whenever the routed pairs fit; rows past them are masked out.
+    When they do not fit (``fallback`` 1), the same computation runs on
+    the worst case, ``T * min(k, n)`` rows: a token picks each expert at
+    most once, so every pair fits there and none is ever dropped.  The
+    device picks the branch (``lax.cond``) from the count it routed.
     """
     B, S, D = x.shape
     k = expert_idx.shape[-1]
     n = p["w_gate"].shape[0]
+    E = p["router"].shape[-1]
     T = B * S
     local = expert_idx.reshape(T * k) - first_expert
-    key = jnp.where((local >= 0) & (local < n), local, n)        # n = absent
+    here = (local >= 0) & (local < n)
+    if token_mask is not None:
+        here &= jnp.repeat(token_mask.reshape(T), k)
+    key = jnp.where(here, local, n)                              # n = absent
     order = jnp.argsort(key, stable=True)
-    rows = order[:T * min(k, n)]
-    tok = rows // k
     sizes = jnp.zeros((n + 1,), jnp.int32).at[key].add(1)[:n]
-    valid = jnp.arange(rows.shape[0]) < sizes.sum()
+    routed = sizes.sum()
     cd = COMPUTE_DTYPE
-    xs = x.reshape(T, D).astype(cd)[tok]
-    g = jax.lax.ragged_dot(xs, p["w_gate"].astype(cd), sizes,
-                           preferred_element_type=jnp.float32)
-    u = jax.lax.ragged_dot(xs, p["w_up"].astype(cd), sizes,
-                           preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(g) * u).astype(cd)
-    ye = jax.lax.ragged_dot(h, p["w_down"].astype(cd), sizes,
-                            preferred_element_type=jnp.float32)
-    w = jnp.where(valid, gate_vals.reshape(T * k)[rows], 0.0)
-    ye = jnp.where(valid[:, None], ye, 0.0) * w[:, None]
-    y = jnp.zeros((T, D), jnp.float32).at[tok].add(ye)
-    return y.reshape(B, S, D)
+    xt = x.reshape(T, D).astype(cd)
+    gates = gate_vals.reshape(T * k)
+
+    def run(m: int) -> Array:
+        # the held experts on the first m rows of the sorted pairs
+        rows = order[:m]
+        tok = rows // k
+        valid = jnp.arange(m) < routed
+        xs = xt[tok]
+        g = jax.lax.ragged_dot(xs, p["w_gate"].astype(cd), sizes,
+                               preferred_element_type=jnp.float32)
+        u = jax.lax.ragged_dot(xs, p["w_up"].astype(cd), sizes,
+                               preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(g) * u).astype(cd)
+        ye = jax.lax.ragged_dot(h, p["w_down"].astype(cd), sizes,
+                                preferred_element_type=jnp.float32)
+        w = jnp.where(valid, gates[rows], 0.0)
+        ye = jnp.where(valid[:, None], ye, 0.0) * w[:, None]
+        return jnp.zeros((T, D), jnp.float32).at[tok].add(ye)
+
+    worst = T * min(k, n)
+    m = held_rows(T, k, n, E)
+    if m == worst:
+        return run(worst).reshape(B, S, D), jnp.zeros((), jnp.int32)
+    fallback = routed > m
+    y = jax.lax.cond(fallback, lambda: run(worst), lambda: run(m))
+    return y.reshape(B, S, D), fallback.astype(jnp.int32)
 
 
 def _gather_slots(src: Array, idx: Array) -> Array:

@@ -185,6 +185,8 @@ class ServingEngine:
         self._async_counters: dict = {}
         self.head_artifact = None
         self.head_bit_exact: bool | None = None
+        #: MoE layer calls per classify step on the held-experts path
+        self._moe_layers = 0
         #: report()'s "steps" block covers the steps recorded from here on
         self._step_mark = steplog.mark()
         if self.family == "dwn":
@@ -409,20 +411,28 @@ class ServingEngine:
         head_fn = get_backend(art.spec.datapath).make_step(
             art.serving_model())
         self._classify_compiles = 0
+        self._moe_layers = api.held_moe_layers(cfg)
 
         def traced_classify(params, tokens, lengths):
             # the python bodies run once per XLA trace: count them
             self._classify_compiles += 1
-            cols = api.logit_columns(params, cfg, tokens, FEATS, tp=tp)
-            return pool_features(cols, lengths)
+            # only real tokens route through the experts; the padding on
+            # their right reaches no real position in a causal backbone
+            real = jnp.arange(tokens.shape[1]) < lengths[:, None]
+            cols, fallbacks = api.logit_columns_and_fallbacks(
+                params, cfg, tokens, FEATS, tp=tp, token_mask=real)
+            return pool_features(cols, lengths), fallbacks
 
-        def classify_head(feats):
+        def classify_head(feats, fallbacks):
             self._classify_compiles += 1
             with jax.named_scope("dwn_head"):
                 counts, pred = head_fn(feats)
             self._head_answer = (counts.shape[-1], counts.dtype, pred.dtype,
-                                 ((FEATS, feats.dtype),))
-            return pack_answer(counts, pred, feats)
+                                 ((FEATS, feats.dtype), (1, jnp.int32)))
+            # the step's fallback count rides its one copy back, as one
+            # more block (the count in every row)
+            return pack_answer(counts, pred, feats, jnp.broadcast_to(
+                fallbacks, (feats.shape[0], 1)))
 
         # Two programs a step: the backbone (module "jit_traced_classify",
         # as a profiler trace names it) takes every weight as an argument,
@@ -451,18 +461,24 @@ class ServingEngine:
         """The classify step's first half on right-padded ``tokens (B, L)``
         with real ``lengths (B,)``: copy in and dispatch backbone ->
         pooled features -> DWN head, with the answer's one copy back
-        issued."""
+        issued.  Its collect strips the step's count of MoE layer calls
+        on the worst-case buffer into the step's record."""
         with steplog.phase("h2d"):
             tokens_d, lengths_d = jnp.asarray(tokens), jnp.asarray(lengths)
         with steplog.phase("dispatch"):
             before = self._classify_compiles
             answer = self._jhead(
-                self._jclassify(self.params, tokens_d, lengths_d))
+                *self._jclassify(self.params, tokens_d, lengths_d))
             answer.copy_to_host_async()
             steplog.add_compiles(self._classify_compiles - before)
             steplog.add_d2h_copies(1)
         head = self._head_answer
-        return Launched(answer, lambda buf: unpack_answer(buf, 1, *head))
+
+        def unpack(buf):
+            counts, pred, feats, fallbacks = unpack_answer(buf, 1, *head)
+            steplog.add_moe_fallbacks(int(fallbacks[0, 0]))
+            return counts, pred, feats
+        return Launched(answer, unpack)
 
     def _classify_step(self, tokens: np.ndarray, lengths: np.ndarray):
         """One blocking classify step: per-row ``(counts, pred,
@@ -687,7 +703,7 @@ class ServingEngine:
         """``steplog``'s summary of the steps served in this process since
         the engine was built (as many of them as its ring still holds)."""
         n = min(steplog.mark() - self._step_mark, steplog.RING.capacity)
-        return steplog.last(n).summary()
+        return steplog.last(n).summary(self._moe_layers)
 
     def report(self) -> dict:
         """JSON-able serving report over everything served so far.
@@ -702,7 +718,9 @@ class ServingEngine:
         ``steps`` summarises the per-step phase record (``steplog``): mean
         and p99 ms of each phase, occupancy in %, the share of steps
         launched while another was in flight (``overlap_pct``), compiles,
-        and the count of steps it covers.
+        and the count of steps it covers; with a MoE backbone under a DWN
+        head, ``moe_fallbacks`` and ``moe_fallback_pct``, the MoE layer
+        calls whose held experts ran on the worst-case buffer.
         """
         async_all = list(self._async_done)
         async_counters = dict(self._async_counters)

@@ -42,10 +42,13 @@ requests in the batch, the compiles its dispatch triggered,
 ``d2h_copies``, the device-to-host transfers the step issued (one),
 for a step over token sequences (the DWN head's classify step),
 ``tokens`` (real prompt tokens) and ``bucket_tokens`` (batch x length of
-the padded step), both 0 for a step over feature rows, and
+the padded step), both 0 for a step over feature rows,
 ``overlapped``: 1 where the step was launched while its predecessor was
-still uncollected.  ``last(n)`` reads the newest ``n`` records;
-``mark()`` / ``since(mark)`` read what came after a point.  Recording is
+still uncollected, and ``moe_fallbacks``: the MoE layer calls of a
+classify step whose held experts ran on the worst-case sorted buffer
+(``models.layers.held_rows``), 0 for a DWN step.  ``last(n)`` reads the
+newest ``n`` records; ``mark()`` / ``since(mark)`` read what came after
+a point.  Recording is
 always on and writes nothing to disk.
 """
 
@@ -60,7 +63,7 @@ from jax.profiler import TraceAnnotation
 
 #: the phases of a step, in order; span ``serve.<phase>``
 PHASES = ("batch", "h2d", "dispatch", "device", "d2h", "resolve")
-#: steps the process-wide ring holds (about 6.8 MB)
+#: steps the process-wide ring holds (about 8.4 MB)
 CAPACITY = 65536
 
 _INDEX = {p: i for i, p in enumerate(PHASES)}
@@ -87,6 +90,7 @@ class Records:
     tokens: np.ndarray        # real tokens of a step over sequences
     bucket_tokens: np.ndarray  # its padded batch x length
     overlapped: np.ndarray    # 1: launched before its predecessor's collect
+    moe_fallbacks: np.ndarray  # MoE layer calls on the worst-case buffer
 
     def __len__(self) -> int:
         return len(self.step_ns)
@@ -110,13 +114,24 @@ class Records:
         percent."""
         return float(self.overlapped.mean() * 100.0)
 
-    def summary(self) -> dict:
+    def moe_fallback_pct(self, moe_layers: int) -> float | None:
+        """MoE layer calls on the worst-case buffer over all of them, in
+        percent, for steps over token sequences that each run
+        ``moe_layers`` such calls; None where there are none."""
+        calls = int((self.bucket_tokens > 0).sum()) * moe_layers
+        return (float(self.moe_fallbacks.sum() / calls * 100.0) if calls
+                else None)
+
+    def summary(self, moe_layers: int = 0) -> dict:
         """Mean and p99 milliseconds of the step and of each phase, the
         occupancy (and the token occupancy of steps over sequences), the
         share of steps launched while another was in flight, the compiles,
-        the device-to-host copies and the count of steps covered."""
+        the device-to-host copies and the count of steps covered; with
+        ``moe_layers`` (MoE layer calls per step over sequences), the
+        share of them that fell back to the worst-case buffer."""
         if not len(self):
             return {"count": 0}
+        fallback = self.moe_fallback_pct(moe_layers)
         ms = {"step": self.step_ns / 1e6}
         ms.update((p, self.phase_ms(p)) for p in PHASES)
         return {
@@ -129,7 +144,10 @@ class Records:
             "compiles": int(self.compiles.sum()),
             "d2h_copies": int(self.d2h_copies.sum()),
         } | ({} if self.token_occupancy_pct() is None else
-             {"token_occupancy_pct": round(self.token_occupancy_pct(), 3)})
+             {"token_occupancy_pct": round(self.token_occupancy_pct(), 3)}
+             ) | ({} if fallback is None else
+                  {"moe_fallbacks": int(self.moe_fallbacks.sum()),
+                   "moe_fallback_pct": round(fallback, 3)})
 
 
 class Ring:
@@ -139,22 +157,23 @@ class Ring:
         self.capacity = capacity
         #: per step: the phases' ns, the step's ns, rows, bucket,
         #: requests, compiles, d2h_copies, tokens, bucket_tokens,
-        #: overlapped
-        self._data = np.zeros((capacity, len(PHASES) + 9), np.int64)
+        #: overlapped, moe_fallbacks
+        self._data = np.zeros((capacity, len(PHASES) + 10), np.int64)
         self._n = 0
         self._lock = threading.Lock()
 
     def record(self, phase_ns, step_ns: int, rows: int, bucket: int,
                requests: int, compiles: int, d2h_copies: int = 0,
                tokens: int = 0, bucket_tokens: int = 0,
-               overlapped: int = 0) -> int:
+               overlapped: int = 0, moe_fallbacks: int = 0) -> int:
         """Store one step; returns its sequence number."""
         with self._lock:
             seq = self._n
             self._data[seq % self.capacity] = (*phase_ns, step_ns, rows,
                                                bucket, requests, compiles,
                                                d2h_copies, tokens,
-                                               bucket_tokens, overlapped)
+                                               bucket_tokens, overlapped,
+                                               moe_fallbacks)
             self._n = seq + 1
         return seq
 
@@ -249,19 +268,21 @@ class Step(span):
     each piece of work of the step, so that two steps in flight on one
     thread each keep their own phases.  The scheduler sets ``rows``,
     ``bucket``, ``requests``, ``overlapped`` and, for token sequences,
-    ``tokens`` and ``bucket_tokens``; :meth:`close` stores the record in
-    the ring unless the step launched no rows or failed.  Its step time is
-    the sum of its phases: the thread's time spent on this step."""
+    ``tokens`` and ``bucket_tokens``; the step's collect adds its
+    ``moe_fallbacks`` (:func:`add_moe_fallbacks`); :meth:`close` stores
+    the record in the ring unless the step launched no rows or failed.
+    Its step time is the sum of its phases: the thread's time spent on
+    this step."""
 
     __slots__ = ("rows", "bucket", "requests", "compiles", "d2h_copies",
-                 "tokens", "bucket_tokens", "overlapped", "phase_ns",
-                 "_prev")
+                 "tokens", "bucket_tokens", "overlapped", "moe_fallbacks",
+                 "phase_ns", "_prev")
 
     def __init__(self):
         self.name = "serve.step"
         self.rows = self.bucket = self.requests = self.compiles = 0
         self.d2h_copies = self.tokens = self.bucket_tokens = 0
-        self.overlapped = 0
+        self.overlapped = self.moe_fallbacks = 0
         self.phase_ns = [0] * len(PHASES)
         super().__enter__()
 
@@ -278,7 +299,8 @@ class Step(span):
             seq = RING.record(self.phase_ns, sum(self.phase_ns), self.rows,
                               self.bucket, self.requests, self.compiles,
                               self.d2h_copies, self.tokens,
-                              self.bucket_tokens, self.overlapped)
+                              self.bucket_tokens, self.overlapped,
+                              self.moe_fallbacks)
             if self._tm is not None:
                 self._tm.set_metadata(step=seq, rows=self.rows,
                                       bucket=self.bucket,
@@ -301,6 +323,14 @@ def add_d2h_copies(n: int) -> None:
         rec.d2h_copies += n
 
 
+def add_moe_fallbacks(n: int) -> None:
+    """Count ``n`` MoE layer calls on the worst-case buffer against the
+    step current on this thread."""
+    rec = _local.step
+    if rec is not None:
+        rec.moe_fallbacks += n
+
+
 __all__ = ["CAPACITY", "PHASES", "RING", "Records", "Ring", "Step",
-           "add_compiles", "add_d2h_copies", "last", "mark", "phase",
-           "since", "span"]
+           "add_compiles", "add_d2h_copies", "add_moe_fallbacks", "last",
+           "mark", "phase", "since", "span"]

@@ -86,7 +86,7 @@ def test_moe_and_shared_expert_match_reference():
     h = _normed_input(seed=1)
 
     def program(p, x):
-        y, _ = L.moe_apply(p["moe"], x, top_k=2, first_expert=0)
+        y, _, _ = L.moe_apply(p["moe"], x, top_k=2, first_expert=0)
         return y + L.swiglu(p["shared"], x)
 
     ref = Reference(CFG)
@@ -117,9 +117,9 @@ def test_backbone_features_match_reference(engine):
     assert feature_error(got, want) < FEATURE_TOL
 
 
-def _moe_params(E=8, seed=0):
+def _moe_params(E=8, seed=0, S=16):
     p = L.init_moe(jax.random.PRNGKey(seed), 32, 24, E)
-    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, 16, 32))
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, S, 32))
     return p, x
 
 
@@ -135,7 +135,7 @@ def test_expert_shares_sum_to_the_whole_layer(held):
     p, x = _moe_params()
     shared = L.init_swiglu(jax.random.PRNGKey(5), 32, 40)
     moe = jax.jit(L.moe_apply, static_argnames=("top_k",))
-    whole, _ = moe(p, x, top_k=3, first_expert=0)
+    whole, _, _ = moe(p, x, top_k=3, first_expert=0)
     parts = sum(moe(_share(p, e0, e0 + held), x, top_k=3,
                     first_expert=e0)[0].astype(jnp.float32)
                 for e0 in range(0, 8, held))
@@ -149,11 +149,15 @@ def test_expert_shares_sum_to_the_whole_layer(held):
     assert _close(whole, want, LAYER_TOL)
 
 
-def test_no_token_dropped_under_skewed_routing():
-    """Every token routed to expert 0: the capacity path drops most of
-    them, the held-experts path computes them all."""
-    p, x = _moe_params()
-    bias = jnp.zeros((32, 8)).at[:, 0].set(50.0)
+@pytest.mark.parametrize("S,favoured,fallback", [(16, (0,), 0),
+                                                  (512, (0, 1), 1)])
+def test_no_token_dropped_under_skewed_routing(S, favoured, fallback):
+    """Every token routed to expert 0 (and at S=512 to expert 1 too, so
+    the 2,048 pairs overflow the sized buffer of 1,024 rows): the
+    capacity path drops most of them, the held-experts path computes them
+    all, on the worst-case buffer where they overflow, and counts that."""
+    p, x = _moe_params(S=S)
+    bias = jnp.zeros((32, 8)).at[:, favoured].set(50.0)
     p = dict(p, router=p["router"] + bias)
     x = jnp.abs(x) + 0.1                  # router logits favour expert 0
     ref = Reference({"num_experts_per_tok": 2, "num_local_experts": 2,
@@ -162,14 +166,73 @@ def test_no_token_dropped_under_skewed_routing():
     want = jax.jit(ref._moe)(_f32(held), x.reshape(-1, 32)).reshape(x.shape)
     moe = jax.jit(L.moe_apply, static_argnames=("top_k", "capacity_factor",
                                                 "first_expert"))
-    got, _ = moe(held, x, top_k=2, first_expert=0)
+    got, _, fell_back = moe(held, x, top_k=2, first_expert=0)
     assert _close(got, want, LAYER_TOL)
+    assert int(fell_back) == fallback
+    if fallback:
+        assert L.held_rows(2 * S, 2, 2, 8) < 2 * S * len(favoured)
     capped, _ = moe(p, x, top_k=2, capacity_factor=1.0)
     assert not _close(capped, want, 0.2)
 
 
-@pytest.fixture(scope="module")
-def engine():
+@pytest.mark.parametrize("B,S,k,n,E", [(2, 512, 2, 2, 8), (1, 1024, 3, 4, 16),
+                                       (4, 512, 4, 2, 32), (2, 16, 2, 2, 8)])
+def test_sized_buffer_equals_the_worst_case(monkeypatch, B, S, k, n, E):
+    """On random routing the pairs fit the sized buffer, and the layer
+    gives what it gives on the worst-case buffer of ``T * min(k, n)``
+    rows (at S=16 the sized buffer is the worst case)."""
+    T = B * S
+    sized = L.held_rows(T, k, n, E)
+    assert sized < T * min(k, n) or S == 16
+    p = L.init_moe(jax.random.PRNGKey(k), 32, 24, E)
+    p = _share(p, 0, n)
+    x = jax.random.normal(jax.random.PRNGKey(E), (B, S, 32))
+
+    def run():
+        return jax.jit(lambda p, x: L.moe_apply(p, x, top_k=k,
+                                                first_expert=0))(p, x)
+    got, _, fell_back = run()
+    monkeypatch.setattr(L, "held_rows", lambda T, k, n, E: T * min(k, n))
+    want, _, worst_fell_back = run()
+    assert int(fell_back) == int(worst_fell_back) == 0
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def test_padding_mask_is_exact():
+    """Right-padded prompts with a token mask: the hidden states and the
+    pooled features at real positions are those without the mask, and
+    the MoE gives 0 at every padding position."""
+    from repro.workloads.lm_head import pool_features
+    params = granite_weights.model(CFG, SEED)
+    rng = np.random.default_rng(4)
+    lengths = np.array([21, 32, 9], np.int32)
+    tokens = np.zeros((3, 32), np.int32)
+    for row, n in enumerate(lengths):
+        tokens[row, :n] = rng.integers(1, 251, n)
+    real = np.arange(32)[None] < lengths[:, None]
+    h_all, _, _ = granite.hidden(params, ARCH, tokens, tp=1)
+    h_real, _, fallbacks = granite.hidden(params, ARCH, tokens, tp=1,
+                                          token_mask=jnp.asarray(real))
+    assert int(fallbacks) == 0
+    np.testing.assert_array_equal(np.asarray(h_real, np.float32)[real],
+                                  np.asarray(h_all, np.float32)[real])
+    pooled = [pool_features(granite.logit_columns(
+        params, ARCH, tokens, 16, tp=1, token_mask=m)[0], lengths)
+        for m in (None, jnp.asarray(real))]
+    np.testing.assert_array_equal(np.asarray(pooled[1]),
+                                  np.asarray(pooled[0]))
+    lp = params["layers"][0]
+    x = jax.random.normal(jax.random.PRNGKey(3), (3, 32, 64))
+    y_all, _, _ = L.moe_apply(lp["moe"], x, top_k=2, first_expert=0)
+    y_real, _, _ = L.moe_apply(lp["moe"], x, top_k=2, first_expert=0,
+                               token_mask=jnp.asarray(real))
+    y_all, y_real = (np.asarray(y, np.float32) for y in (y_all, y_real))
+    assert (y_real[~real] == 0).all() and (y_all[~real] != 0).any()
+    np.testing.assert_array_equal(y_real[real], y_all[real])
+
+
+def _engine(**kwargs):
     from repro.core.model import FrozenDWN
     from repro.dwn import DWNArtifact, get_spec
     from repro.serving import ServingEngine
@@ -181,7 +244,12 @@ def engine():
                                              [mapping], [tables], None))
     return ServingEngine(ARCH, params=granite_weights.model(CFG, SEED),
                          dwn_head=art, min_bucket=16, max_bucket=32,
-                         step_tokens=64, seed=SEED)
+                         step_tokens=64, seed=SEED, **kwargs)
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return _engine()
 
 
 def test_pooled_features_do_not_change_with_the_padding_bucket(engine):
@@ -248,3 +316,36 @@ def test_classify_needs_the_continuous_loop(engine):
     with pytest.raises(ValueError, match="continuous loop"):
         engine.submit({"tokens": np.ones((1, 4), np.int32),
                        "classify": True})
+
+
+@pytest.mark.parametrize("forced", [False, True])
+def test_fallback_counter_rides_the_step_answer(engine, monkeypatch, request,
+                                                forced):
+    """The step's count of MoE layer calls on the worst-case buffer comes
+    back in its one copy and lands in the ring: 0 on ordinary steps, every
+    layer on steps whose sized buffer is forced down to 8 rows.  Results
+    keep their (counts, pred, features) shape."""
+    from repro.serving import steplog
+    if forced:
+        # granite.hidden is jitted at module level: drop its traces with
+        # the rule forced, and again after, so no other test reuses them
+        monkeypatch.setattr(L, "held_rows", lambda T, k, n, E: 8)
+        jax.clear_caches()
+        request.addfinalizer(jax.clear_caches)
+        engine = _engine(verify=False)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 251, (1, n)).astype(np.int32)
+               for n in rng.integers(8, 33, 6)]
+    mark = steplog.mark()
+    with engine.serve():
+        results = [r.future.result() for r in
+                   [engine.submit_async(p) for p in prompts]]
+    recs = steplog.since(mark)
+    assert all(r.ok for r in results)
+    for r in results:
+        assert [v.shape for v in r.value] == [(1, 5), (1,), (1, 16)]
+    assert len(recs) and (recs.d2h_copies == 1).all()
+    layers = ARCH.num_layers if forced else 0
+    np.testing.assert_array_equal(recs.moe_fallbacks, layers)
+    steps = engine.report()["steps"]
+    assert steps["moe_fallback_pct"] == (100.0 if forced else 0.0)

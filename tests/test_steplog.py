@@ -75,6 +75,7 @@ def test_every_phase_recorded_and_summing_to_the_step(engine, path):
     np.testing.assert_array_equal(recs.phase_ns.sum(axis=1), recs.step_ns)
     np.testing.assert_array_equal(recs.rows, [128, 64, 128])
     np.testing.assert_array_equal(recs.requests, [1, 1, 1])
+    np.testing.assert_array_equal(recs.moe_fallbacks, 0)
 
 
 @pytest.mark.parametrize("path", PATHS)

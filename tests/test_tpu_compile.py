@@ -6,7 +6,8 @@ fit VMEM.  These tests compile the served fused kernel and the
 packed-xla step for a described v5e chip that is not attached, at the
 width of every registered DWN preset — JSC (F=16, T=200, C=5), MNIST
 (F=196, C=10) and the LM head (F=16, T=64, C=5), all fan-in 6 — and at
-a two-layer stack.
+a two-layer stack; and granite-4.0-h-small's held experts at their
+published widths, with both sizes of their sorted buffer.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -129,3 +130,26 @@ def test_packed_answer_is_linear_on_v5e(one_chip):
                  if l.startswith("ENTRY"))
     assert entry.rstrip(" {").endswith("-> s32[24576]"), entry
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_held_experts_compile_for_v5e(one_chip):
+    """granite-4.0-h-small's MoE share on one chip (9 of 72 experts, top
+    10, d_model 4096, width 768) over an 8 x 1024 step with a token mask:
+    one program holding the sized buffer (20,480 rows) and the worst case
+    (73,728), chosen on the device."""
+    from repro.models import layers as L
+    D, F, E, n, k = 4096, 768, 72, 9, 10
+
+    def arg(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    p = {"router": arg((D, E)), "w_gate": arg((n, D, F)),
+         "w_up": arg((n, D, F)), "w_down": arg((n, F, D))}
+    assert L.held_rows(8 * 1024, k, n, E) == 20480
+    compiled = jax.jit(lambda p, x, m: L.moe_apply(
+        p, x, top_k=k, first_expert=0, token_mask=m)).lower(
+        p, arg((8, 1024, D), jnp.bfloat16), arg((8, 1024), jnp.bool_)
+    ).compile()
+    text = compiled.as_text()
+    assert "conditional(" in text
+    assert "f32[20480,4096]" in text and "f32[73728,4096]" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
