@@ -21,7 +21,7 @@ def round_up(x: int, m: int) -> int:
 class ArchConfig:
     name: str
     family: str                      # dense | moe | ssm | hybrid | encdec |
-                                     # vlm | ssm_moe
+                                     # vlm | ssm_moe | kda_mla_moe
     num_layers: int
     d_model: int
     num_heads: int
@@ -62,6 +62,19 @@ class ArchConfig:
     residual_multiplier: float = 1.0  # scales each branch before its add
     logits_scaling: float = 1.0       # logits are divided by it
     attention_multiplier: float = 0.0  # softmax scale (0 -> head_dim^-0.5)
+
+    # --- KDA + MLA with sigmoid-routed MoE FFNs (family="kda_mla_moe",
+    #     Kimi Linear); layer_types holds "kda" | "mla" per layer ---
+    kda_heads: int = 0               # KDA heads (head size head_dim)
+    kda_gate_rank: int = 0           # rank of the decay and output gates
+    kv_lora_rank: int = 0            # MLA latent
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0        # MLA's shared key part (NoPE here)
+    v_head_dim: int = 0
+    router: str = "softmax"          # "softmax" | "sigmoid" expert scores
+    routed_scaling: float = 1.0      # routed experts' weights scale
+    first_dense_layers: int = 0      # leading layers with a dense SwiGLU
+    dense_ff: int = 0                # ... of this width
 
     # --- hybrid (RecurrentGemma: RG-LRU + local attention, 1 attn : 2 rec) ---
     lru_width: int = 0
@@ -183,6 +196,17 @@ class ArchConfig:
                 d_ff=32, shared_ff=48, vocab_size=251, num_experts=4,
                 top_k=2, experts_held=0, ssm_state=16, ssm_headdim=16,
                 ssm_chunk=8, attn_chunk=16, attention_multiplier=1 / 16)
+        if self.family == "kda_mla_moe":
+            # the leading dense layer, a KDA and an MLA layer, 8 routed
+            # experts and a shared one, at CPU widths
+            return dataclasses.replace(
+                self, name=self.name + "-reduced", num_layers=3,
+                layer_types=("kda", "kda", "mla"), d_model=64, num_heads=4,
+                num_kv_heads=4, head_dim=16, kda_heads=4, kda_gate_rank=8,
+                kv_lora_rank=16, qk_nope_head_dim=16, qk_rope_head_dim=8,
+                v_head_dim=16, d_ff=32, shared_ff=32, dense_ff=96,
+                vocab_size=251, num_experts=8, top_k=2, experts_held=0,
+                attn_chunk=16)
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",

@@ -42,5 +42,6 @@ def _load_all():
     from . import (granite_moe_3b_a800m, mixtral_8x7b, whisper_large_v3,  # noqa
                    mamba2_1_3b, qwen3_8b, phi3_mini_3_8b, qwen2_7b,
                    qwen3_14b, recurrentgemma_2b, llava_next_34b, dwn_jsc,
-                   dwn_mnist, dwn_lm_head, granite_4_0_h_small)
+                   dwn_mnist, dwn_lm_head, granite_4_0_h_small,
+                   kimi_linear_48b_a3b)
     _LOADED = True

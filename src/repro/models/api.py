@@ -23,7 +23,8 @@ import jax.numpy as jnp
 from ..configs.base import ArchConfig, ShapeConfig
 from ..optim.adam import Adam
 from ..optim.grad import clip_by_global_norm
-from . import transformer, mamba2, rglru, whisper, dwn_arch, granite
+from . import (transformer, mamba2, rglru, whisper, dwn_arch, granite,
+               kimi_linear)
 from . import layers as L
 
 MODULES = {
@@ -35,6 +36,7 @@ MODULES = {
     "encdec": whisper,
     "dwn": dwn_arch,
     "ssm_moe": granite,
+    "kda_mla_moe": kimi_linear,
 }
 
 
@@ -218,9 +220,12 @@ def logit_columns_and_fallbacks(params, cfg: ArchConfig, tokens, cols: int,
 
 def held_moe_layers(cfg: ArchConfig) -> int:
     """MoE layer calls of one forward on the held-experts path, those
-    :func:`logit_columns_and_fallbacks` counts: every layer of the
-    ``ssm_moe`` family, none elsewhere."""
-    return cfg.num_layers if cfg.family == "ssm_moe" else 0
+    :func:`logit_columns_and_fallbacks` counts: in a family whose module
+    computes the columns itself, every layer after the leading dense
+    ones; none elsewhere."""
+    if not hasattr(module_for(cfg), "logit_columns"):
+        return 0
+    return cfg.num_layers - cfg.first_dense_layers
 
 
 def make_decode_step(cfg: ArchConfig, tp: int = 16):

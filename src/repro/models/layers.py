@@ -267,13 +267,15 @@ def attention_chunked(q: Array, k: Array, v: Array, layout: HeadLayout, *,
                       scale: float | None = None) -> Array:
     """Online-softmax flash attention, pure JAX.
 
-    q: (B, Sq, Hp, hd); k/v: (B, Skv, Kp, hd)  (already padded layout).
+    q: (B, Sq, Hp, hd); k: (B, Skv, Kp, hd); v: (B, Skv, Kp, dv)
+    (already padded layout; dv may differ from hd, as in MLA).
     window: sliding-window size (None = unbounded).
     kv_len: optional (B,) valid kv length (decode against partial cache).
     scale: softmax scale of the scores (None = hd^-0.5).
-    Returns (B, Sq, Hp, hd).
+    Returns (B, Sq, Hp, dv).
     """
     B, Sq, Hp, hd = q.shape
+    dv = v.shape[-1]
     Skv = k.shape[1]
     Kp = layout.kv_padded
     g = Hp // Kp
@@ -284,7 +286,7 @@ def attention_chunked(q: Array, k: Array, v: Array, layout: HeadLayout, *,
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     kc = k.reshape(B, nchunk, kv_chunk, Kp, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, nchunk, kv_chunk, Kp, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, nchunk, kv_chunk, Kp, dv).transpose(1, 0, 2, 3, 4)
 
     qg = q.reshape(B, Sq, Kp, g, hd).astype(COMPUTE_DTYPE)
     q_pos = jnp.asarray(q_offset) + jnp.arange(Sq)              # (Sq,)
@@ -323,13 +325,13 @@ def attention_chunked(q: Array, k: Array, v: Array, layout: HeadLayout, *,
             preferred_element_type=jnp.float32)
         return (o, m_new, l), None
 
-    o0 = jnp.zeros((B, Sq, Kp, g, hd), jnp.float32)
+    o0 = jnp.zeros((B, Sq, Kp, g, dv), jnp.float32)
     m0 = jnp.full((B, Sq, Kp, g), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((B, Sq, Kp, g), jnp.float32)
     (o, m, l), _ = jax.lax.scan(
         body, (o0, m0, l0), (kc, vc, jnp.arange(nchunk)))
     o = o / jnp.maximum(l[..., None], 1e-30)
-    return o.reshape(B, Sq, Hp, hd).astype(COMPUTE_DTYPE)
+    return o.reshape(B, Sq, Hp, dv).astype(COMPUTE_DTYPE)
 
 
 def _attn_parts(q: Array, k: Array, v: Array, layout: HeadLayout, *,
@@ -577,13 +579,18 @@ def axes_moe(*, ep: bool = False):
 def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
               min_capacity: int = 4, num_real_experts: int = 0,
               ep: bool = False, first_expert: int | None = None,
-              token_mask: Array | None = None):
+              token_mask: Array | None = None, router: str = "softmax",
+              routed_scaling: float = 1.0):
     """Token-choice top-k MoE.  Returns (y, aux_loss), and with
     ``first_expert`` (y, aux_loss, fallback).
 
-    x: (B, S, D).  The router scores every expert; each token keeps its
-    ``top_k`` and their softmax weights renormalised over the k (the same
-    as a softmax over the top-k logits).
+    x: (B, S, D).  The router scores every expert, in float32; each token
+    keeps ``top_k`` of them.  ``router="softmax"``: the k largest softmax
+    scores, renormalised over the k (the same as a softmax over the
+    top-k logits).  ``router="sigmoid"``: scores ``s = sigmoid(logit)``;
+    the k largest of ``s + p["score_bias"]`` (the correction bias only
+    selects) are kept with weights ``s`` renormalised over the k.  Either
+    way the weights are then multiplied by ``routed_scaling``.
 
     ``first_expert=None``: per-row capacity routing that drops overflow.
     Routing/dispatch is independent per batch row, so with batch-sharded
@@ -617,10 +624,16 @@ def moe_apply(p, x: Array, *, top_k: int, capacity_factor: float = 1.25,
                         p["router"].astype(jnp.float32))
     if E_real < E:
         logits = jnp.where(jnp.arange(E) < E_real, logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)                      # (B,S,E)
-    gate_vals, expert_idx = jax.lax.top_k(probs, top_k)          # (B,S,k)
+    if router == "sigmoid":
+        probs = jax.nn.sigmoid(logits)                           # (B,S,E)
+        choice = probs + p["score_bias"].astype(jnp.float32)
+        _, expert_idx = jax.lax.top_k(choice, top_k)             # (B,S,k)
+        gate_vals = jnp.take_along_axis(probs, expert_idx, -1)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)                  # (B,S,E)
+        gate_vals, expert_idx = jax.lax.top_k(probs, top_k)      # (B,S,k)
     gate_vals = gate_vals / jnp.maximum(
-        gate_vals.sum(-1, keepdims=True), 1e-9)
+        gate_vals.sum(-1, keepdims=True), 1e-9) * routed_scaling
     frac_probs = jnp.mean(probs, axis=(0, 1))
     if first_expert is not None:
         y, fallback = _moe_held(p, x, expert_idx, gate_vals, first_expert,

@@ -204,15 +204,18 @@ def _ssd_chunked(x: Array, dt: Array, A: Array, B: Array, C: Array,
     return y.astype(cd), h_last
 
 
-def _causal_conv(xbc: Array, w: Array, b: Array) -> Array:
-    """Depthwise causal conv along S.  xbc (Bt,S,C), w (K,C)."""
+def _causal_conv(xbc: Array, w: Array, b: Array | None) -> Array:
+    """Depthwise causal conv along S.  xbc (Bt,S,C), w (K,C), b (C,) or
+    None (no bias)."""
     K = w.shape[0]
     pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
     out = jnp.zeros_like(xbc, dtype=jnp.float32)
     for i in range(K):                                    # K is 4: unrolled
         out = out + pad[:, i:i + xbc.shape[1]].astype(jnp.float32) \
             * w[K - 1 - i].astype(jnp.float32)
-    return (out + b.astype(jnp.float32)).astype(xbc.dtype)
+    if b is not None:
+        out = out + b.astype(jnp.float32)
+    return out.astype(xbc.dtype)
 
 
 def _block_apply(lp, cfg: ArchConfig, x: Array, *, state=None,

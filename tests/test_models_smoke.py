@@ -15,10 +15,11 @@ from repro.models import api
 ARCHS = ["granite-moe-3b-a800m", "mixtral-8x7b", "whisper-large-v3",
          "mamba2-1.3b", "qwen3-8b", "phi3-mini-3.8b", "qwen2-7b",
          "qwen3-14b", "recurrentgemma-2b", "llava-next-34b",
-         "granite-4.0-h-small"]
-#: archs with a decode cache (granite-4.0-h-small serves whole prompts to
-#: the DWN head; it has no prefill/decode path)
-DECODE_ARCHS = [a for a in ARCHS if a != "granite-4.0-h-small"]
+         "granite-4.0-h-small", "kimi-linear-48b-a3b-instruct"]
+#: archs with a decode cache (granite-4.0-h-small and Kimi Linear serve
+#: whole prompts to the DWN head; they have no prefill/decode path)
+DECODE_ARCHS = [a for a in ARCHS if a not in (
+    "granite-4.0-h-small", "kimi-linear-48b-a3b-instruct")]
 
 
 def _batch(cfg, B=2, S=24, key=None):
@@ -54,6 +55,7 @@ def test_full_config_matches_assignment(arch):
         "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
         "llava-next-34b": (60, 7168, 56, 8, 20480, 64000),
         "granite-4.0-h-small": (40, 4096, 32, 8, 768, 100352),
+        "kimi-linear-48b-a3b-instruct": (27, 2304, 32, 32, 1024, 163840),
     }[arch]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -102,11 +104,12 @@ def test_long_500k_skips_documented():
     skipped = [a for a in ARCHS
                if not cell_supported(get_arch(a), SHAPES["long_500k"])[0]]
     # the archs with full-attention layers skip (granite-4.0-h-small's 4
-    # of 40 keep a dense KV cache); SSM/hybrid/SWA run
+    # of 40 and Kimi Linear's 7 MLA layers of 27 keep a dense KV cache);
+    # SSM/hybrid/SWA run
     assert sorted(skipped) == sorted([
         "granite-moe-3b-a800m", "whisper-large-v3", "qwen3-8b",
         "phi3-mini-3.8b", "qwen2-7b", "qwen3-14b", "llava-next-34b",
-        "granite-4.0-h-small"])
+        "granite-4.0-h-small", "kimi-linear-48b-a3b-instruct"])
     runnable = sorted(set(ARCHS) - set(skipped))
     assert runnable == sorted(["mixtral-8x7b", "mamba2-1.3b",
                                "recurrentgemma-2b"])

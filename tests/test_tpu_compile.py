@@ -6,8 +6,10 @@ fit VMEM.  These tests compile the served fused kernel and the
 packed-xla step for a described v5e chip that is not attached, at the
 width of every registered DWN preset — JSC (F=16, T=200, C=5), MNIST
 (F=196, C=10) and the LM head (F=16, T=64, C=5), all fan-in 6 — and at
-a two-layer stack; and granite-4.0-h-small's held experts at their
-published widths, with both sizes of their sorted buffer.
+a two-layer stack; granite-4.0-h-small's held experts at their
+published widths, with both sizes of their sorted buffer; and the
+``kda_chunk`` kernel and the whole Kimi Linear backbone step of the
+``kimi-linear.classify`` cell at its four step shapes.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and every test worker imports this
@@ -153,3 +155,57 @@ def test_held_experts_compile_for_v5e(one_chip):
     assert "conditional(" in text
     assert "f32[20480,4096]" in text and "f32[73728,4096]" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_kda_chunk_compiles_for_v5e(one_chip):
+    """The KDA kernel at Kimi Linear's widths (32 heads of 128) on a
+    2 x 4096 step, four heads a grid step."""
+    from repro.kernels.kda import kernel as K
+    B, S, H, d = 2, 4096, 32, 128
+
+    def arg(dtype):
+        return jax.ShapeDtypeStruct((B, S, H * d), dtype, sharding=one_chip)
+    compiled = jax.jit(lambda *x: K.kda_chunk(
+        *x, heads=H, chunk=64, head_block=4)).lower(
+        *[arg(jnp.bfloat16)] * 4, arg(jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("batch,length", [(16, 512), (8, 1024), (4, 2048),
+                                          (2, 4096)])
+def test_kimi_backbone_step_compiles_for_v5e(one_chip, monkeypatch, batch,
+                                             length):
+    """The served backbone step of the Kimi Linear cell (8 layers at the
+    published widths, 32 of 256 experts held, bf16 weights, a token
+    mask): KDA in the ``kda_chunk`` kernel, and weights plus temporaries
+    inside one chip's 16 GB."""
+    import dataclasses
+    from repro.configs import get_arch
+    from repro.models import api
+    from repro.workloads.lm_head import FEATS, pool_features
+    # the kernel's wrapper picks interpret mode off the TPU: compile what
+    # the chip runs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    arch = get_arch("kimi-linear-48b-a3b-instruct")
+    cfg = dataclasses.replace(arch, num_layers=8,
+                              layer_types=arch.layer_types[:8],
+                              experts_held=32)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16,
+                                       sharding=one_chip),
+        api.abstract_params(cfg, tp=1))
+
+    def step(p, tokens, lengths):
+        real = jnp.arange(tokens.shape[1]) < lengths[:, None]
+        cols, fallbacks = api.logit_columns_and_fallbacks(
+            p, cfg, tokens, FEATS, tp=1, token_mask=real)
+        return pool_features(cols, lengths), fallbacks
+
+    compiled = jax.jit(step).lower(
+        params, jax.ShapeDtypeStruct((batch, length), jnp.int32,
+                                     sharding=one_chip),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip)
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
